@@ -67,12 +67,11 @@ def main(argv=None) -> int:
                          "(e.g. :9100); off by default")
     sp.add_argument("--platform", default="auto",
                     choices=("auto", "tpu", "cpu"),
-                    help="device backend: auto probes under a hard "
-                         "timeout and degrades to cpu instead of hanging "
-                         "at first device use; cpu skips the probe")
-    sp.add_argument("--probe-timeout", type=float, default=None,
-                    help="seconds to wait for the device probe "
-                         "(default $IG_PLATFORM_PROBE_TIMEOUT or 20)")
+                    help="device backend: tpu fails at startup unless the "
+                         "first device is a TPU; cpu pins the CPU backend; "
+                         "auto takes what JAX reports (the CPU only when "
+                         "JAX finds no accelerator). One process owns a "
+                         "chip: run one tpu agent per chip")
     sp.add_argument("--flight-record-path", default="",
                     help="dump the flight recorder (recent spans/logs/"
                          "errors) here on SIGTERM/crash; default "
@@ -157,16 +156,19 @@ def main(argv=None) -> int:
     if args.cmd == "serve":
         if args.watch_traces and not args.kube_api:
             ap.error("--watch-traces requires --kube-api")
-        # bounded device acquisition BEFORE first device use (VERDICT hole
-        # #1: the PJRT plugin can hang forever in backend init) — a failed
-        # or timed-out probe pins this process to CPU, logged + counted
-        from ..utils.platform_probe import DEFAULT_PROBE_TIMEOUT, acquire_platform
-        acq = acquire_platform(
-            args.platform,
-            timeout=(args.probe_timeout if args.probe_timeout is not None
-                     else DEFAULT_PROBE_TIMEOUT))
-        print(f"device platform: {acq['platform']}"
-              + (f" (degraded: {acq['detail']})" if acq["degraded"] else ""),
+        # device acquisition BEFORE first device use, in this process (it
+        # will own the chip): asking for a TPU that is not there is a
+        # startup failure, never a quiet CPU agent
+        from ..utils.compile_cache import ensure_compile_cache
+        from ..utils.platform_probe import (PlatformUnavailable,
+                                            acquire_platform)
+        ensure_compile_cache()
+        try:
+            acq = acquire_platform(args.platform)
+        except PlatformUnavailable as e:
+            print(f"error: {e}", file=sys.stderr, flush=True)
+            return 1
+        print(f"device platform: {acq['platform']} ({acq['detail']})",
               flush=True)
         # entrypoint-analogue environment probe (ref: entrypoint.sh:21-120
         # detects OS/kernel/runtime before starting the daemon): report

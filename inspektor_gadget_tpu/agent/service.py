@@ -86,7 +86,10 @@ def handlers_for(gadget_type, outputs, on_event, on_event_array):
     the client watched an empty stream end cleanly (VERDICT Weak #7 —
     the advise/traceloop mislabel rode exactly that hole)."""
     if gadget_type == GadgetType.TRACE:
-        return on_event, None
+        # rows are decoded per event, in Python, only for a subscriber
+        # that asked for them: a summary- or batch-only run must not pay
+        # a 65536-object decode per batch to throw the rows away
+        return (on_event if "json" in outputs else None), None
     if gadget_type == GadgetType.TRACE_INTERVALS:
         return None, on_event_array
     if gadget_type == GadgetType.ONE_SHOT:

@@ -118,8 +118,8 @@ def build_manifest(*, journal_id: str = "", node: str = "", gadget: str = "",
                    run_id: str = "", params: dict[str, str] | None = None,
                    extra: dict | None = None) -> dict:
     """Provenance block every journal carries: git sha, node id, gadget
-    id, resolved params, and the platform/degraded outcome of the PR-2
-    probe — a journal read months later still answers 'what produced
+    id, resolved params, and the acquired device platform — a journal
+    read months later still answers 'what produced
     this' without trusting surrounding prose."""
     from ..perf.provenance import git_provenance, host_fingerprint
     from ..utils.platform_probe import last_acquire
@@ -135,8 +135,7 @@ def build_manifest(*, journal_id: str = "", node: str = "", gadget: str = "",
         "git_sha": sha,
         "git_dirty": dirty,
         "host": host_fingerprint(),
-        "platform": acq.get("platform", "unprobed"),
-        "degraded": bool(acq.get("degraded", False)),
+        "platform": acq.get("platform", "not acquired"),
         "params": dict(params or {}),
         **(extra or {}),
     }

@@ -37,13 +37,10 @@ def add_bench_parser(sub) -> None:
                     help="harness config (e2e, e2e-prod, tiny)")
     rp.add_argument("--platform", default="auto",
                     choices=["auto", "tpu", "cpu"],
-                    help="device acquisition (bounded probe with retries)")
+                    help="device acquisition, in this process: tpu "
+                         "fails unless the first device is a TPU")
     rp.add_argument("--seconds", type=float, default=None,
                     help="override the config's measurement window")
-    rp.add_argument("--probe-timeout", type=float, default=None)
-    rp.add_argument("--probe-attempts", type=int, default=None)
-    rp.add_argument("--probe-horizon", type=float, default=None,
-                    help="seconds the probe retries are spread over")
     rp.add_argument("--trace-out", default="",
                     help="also write a Chrome trace of the run here")
     rp.add_argument("--replay", default="",
@@ -115,12 +112,10 @@ def add_bench_parser(sub) -> None:
 
 def cmd_bench_run(args) -> int:
     from ..perf import append_record, ledger_path, run_harness
+    from ..utils.platform_probe import PlatformUnavailable
     try:
         rec = run_harness(
             args.config, platform=args.platform, seconds=args.seconds,
-            probe_timeout=args.probe_timeout,
-            probe_attempts=args.probe_attempts,
-            probe_horizon=args.probe_horizon,
             trace_out=args.trace_out or None,
             replay=args.replay or None,
             pipeline=args.pipeline,
@@ -130,6 +125,9 @@ def cmd_bench_run(args) -> int:
     except (ValueError, FileNotFoundError) as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
+    except PlatformUnavailable as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 1
     if not args.no_ledger:
         path = append_record(rec, args.ledger)
         print(f"appended to {path}", file=sys.stderr)
@@ -165,10 +163,8 @@ def cmd_bench_run(args) -> int:
     if args.output == "json":
         print(json.dumps(rec, sort_keys=True))
     else:
-        prov = rec["provenance"]
         print(f"{rec['config']}: {rec['value']:,.1f} {rec['unit']} on "
-              f"{prov['platform']}"
-              + (" (DEGRADED)" if prov["degraded"] else ""))
+              f"{rec['provenance']['platform']}")
         for name, st in rec["stages"].items():
             desc = ", ".join(f"{k}={v:,}" for k, v in st.items())
             print(f"  {name:14s} {desc}")

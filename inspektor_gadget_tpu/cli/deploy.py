@@ -101,11 +101,43 @@ def _alive(pid: int) -> bool:
         return False
 
 
-def deploy_local(n: int, base_port: int = 50151) -> dict[str, str]:
-    """Start n local agent daemons (subprocesses); returns node→target."""
-    import json
-    import subprocess
+# what a host with TPU chips shows in /dev: accel nodes (v2-v4) or
+# numbered vfio groups (v5e and later)
+TPU_DEVICE_GLOBS = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
+
+
+def local_agent_platforms(n: int) -> list[str]:
+    """The explicit `--platform` of each agent of a local fleet. A chip
+    belongs to ONE process, so at most one agent may ask for the
+    accelerator: node-0 gets `tpu` when this host shows TPU device nodes
+    (TPU_DEVICE_GLOBS — looking initialises nothing, so the deploying
+    process never holds the chip) and JAX is not pinned elsewhere from
+    outside; every other agent is pinned to `cpu`. An agent told `tpu`
+    that finds none exits 1 and says so in its log."""
+    import glob
+    import os
+
+    pinned = os.environ.get("JAX_PLATFORMS", "")
+    chips = any(glob.glob(g) for g in TPU_DEVICE_GLOBS)
+    first = "tpu" if chips and (not pinned or "tpu" in pinned) else "cpu"
+    return [first] + ["cpu"] * (n - 1)
+
+
+def local_agent_argv(node: str, target: str, platform: str) -> list[str]:
     import sys
+    return [sys.executable, "-m", "inspektor_gadget_tpu.agent.main", "serve",
+            "--listen", target, "--node-name", node, "--platform", platform]
+
+
+def deploy_local(n: int, base_port: int = 50151) -> dict[str, str]:
+    """Start n local agent daemons (subprocesses); returns node→target.
+    Each agent's output goes to <log dir>/<node>.log, the log dir a fresh
+    private directory under the temp dir (TMPDIR); it and each agent's
+    platform (see local_agent_platforms) land in the state file."""
+    import json
+    import os
+    import subprocess
+    import tempfile
 
     # refuse to orphan a live fleet: a second deploy would fail port-bind
     # and overwrite the only record of the running agents
@@ -121,27 +153,48 @@ def deploy_local(n: int, base_port: int = 50151) -> dict[str, str]:
 
     targets = {}
     pids = {}
-    for i in range(n):
-        port = base_port + i
-        p = subprocess.Popen(
-            [sys.executable, "-m", "inspektor_gadget_tpu.agent.main", "serve",
-             "--listen", f"127.0.0.1:{port}", "--node-name", f"node-{i}"],
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-        )
-        targets[f"node-{i}"] = f"127.0.0.1:{port}"
-        pids[f"node-{i}"] = p.pid
+    platforms = {}
+    # mkdtemp: mode 0700 and a name nobody could plant beforehand; the
+    # logs inside are created exclusively and never through a symlink
+    log_dir = tempfile.mkdtemp(prefix="ig-tpu-agents-")
+    for i, platform in enumerate(local_agent_platforms(n)):
+        node, target = f"node-{i}", f"127.0.0.1:{base_port + i}"
+        fd = os.open(os.path.join(log_dir, f"{node}.log"),
+                     os.O_WRONLY | os.O_CREAT | os.O_EXCL | os.O_NOFOLLOW,
+                     0o600)
+        try:
+            p = subprocess.Popen(local_agent_argv(node, target, platform),
+                                 stdout=fd, stderr=subprocess.STDOUT)
+        finally:
+            os.close(fd)
+        targets[node] = target
+        pids[node] = p.pid
+        platforms[node] = platform
     with open(STATE_FILE, "w") as f:
-        json.dump({"targets": targets, "pids": pids}, f)
+        json.dump({"targets": targets, "pids": pids,
+                   "platforms": platforms, "log_dir": log_dir}, f)
     return targets
 
 
-def local_targets() -> dict[str, str]:
+def _local_state(field: str, default):
     import json
     try:
         with open(STATE_FILE) as f:
-            return json.load(f)["targets"]
+            return json.load(f)[field]
     except (OSError, ValueError, KeyError):
-        return {}
+        return default
+
+
+def local_platforms() -> dict[str, str]:
+    return _local_state("platforms", {})
+
+
+def local_log_dir() -> str:
+    return _local_state("log_dir", "")
+
+
+def local_targets() -> dict[str, str]:
+    return _local_state("targets", {})
 
 
 def undeploy_local() -> list[str]:

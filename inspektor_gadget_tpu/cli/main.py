@@ -249,8 +249,8 @@ def cmd_doctor(args) -> int:
         print(json.dumps({
             "windows": {k: dc.asdict(w) for k, w in windows.items()},
             "gadgets": [dc.asdict(g) for g in gadgets],
-            # device-plane acquisition outcome (agents probe at startup)
-            "platform": last_acquire() or {"platform": "unprobed"},
+            # device-plane acquisition outcome (agents acquire at startup)
+            "platform": last_acquire() or {"platform": "not acquired"},
             # the probed facts double as registry gauges; the snapshot ties
             # this report to the same plane bench/agents expose
             "telemetry": snapshot(),
@@ -273,7 +273,8 @@ def _version() -> str:
 
 
 def cmd_deploy(args) -> int:
-    from .deploy import AGENT_IMAGE, deploy_local, render_manifests
+    from .deploy import (AGENT_IMAGE, deploy_local, local_log_dir,
+                         local_platforms, render_manifests)
     if args.render:
         print(render_manifests(image=args.image or AGENT_IMAGE))
         return 0
@@ -297,7 +298,9 @@ def cmd_deploy(args) -> int:
             print(f"error: {e}", file=sys.stderr)
             return 2
         spec = ",".join(f"{k}={v}" for k, v in targets.items())
-        print(f"started {args.local} agents; use: --remote {spec}")
+        plats = ", ".join(f"{k}: {v}" for k, v in local_platforms().items())
+        print(f"started {args.local} agents ({plats}; one process per "
+              f"chip; logs in {local_log_dir()}); use: --remote {spec}")
         return 0
     print("use --render or --local N", file=sys.stderr)
     return 2
@@ -737,6 +740,9 @@ def main(argv: list[str] | None = None) -> int:
     if not hasattr(args, "func"):
         ap.print_help()
         return 0
+    # a local gadget run compiles its ingest step in this process
+    from ..utils.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     return args.func(args)
 
 

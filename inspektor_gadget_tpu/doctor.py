@@ -876,14 +876,14 @@ def render_report(windows: dict[str, Window] | None = None,
         counts[g.status] = counts.get(g.status, 0) + 1
     lines.append("")
     # device-plane acquisition outcome (set by acquire_platform — the
-    # agent probes at startup; standalone doctor shows "unprobed")
+    # agent acquires at startup; standalone doctor never touches the
+    # device and shows "not acquired")
     from .utils.platform_probe import last_acquire
     acq = last_acquire()
     if acq is not None:
-        mark = "degraded " if acq["degraded"] else ""
-        lines.append(f"PLATFORM {mark}{acq['platform']} ({acq['detail']})")
+        lines.append(f"PLATFORM {acq['platform']} ({acq['detail']})")
     else:
-        lines.append("PLATFORM unprobed (agents probe at startup; "
+        lines.append("PLATFORM not acquired (agents acquire at startup; "
                      "see --platform)")
     lines.append("")
     lines.append("SUMMARY " + "  ".join(

@@ -24,11 +24,11 @@ item (a degraded/cpu run may not read as a TPU result).
 from __future__ import annotations
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.sketches import SketchBundle
 from ..parallel.cluster import cluster_merge
-from ..parallel.compat import shard_map
 from ..parallel.mesh import NODE_AXIS
 
 

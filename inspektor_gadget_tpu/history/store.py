@@ -9,8 +9,8 @@ node killed mid-seal leaves exactly one torn window at the active
 segment's tail — dropped-and-accounted on read, never half-decoded.
 Size/age rotation seals segments into index.jsonl; retention GC deletes
 the oldest sealed segments and never the active one; the manifest
-stamps the same provenance (git sha, resolved params, platform/degraded
-probe outcome) a capture journal carries.
+stamps the same provenance (git sha, resolved params, acquired device
+platform) a capture journal carries.
 
 The history-specific additions on top of the journal machinery:
 

@@ -31,10 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..parallel.compat import shard_map
 
 from ..parallel.flash_attention import flash_attention
 from ..parallel.ring_attention import (

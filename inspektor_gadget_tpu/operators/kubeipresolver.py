@@ -78,7 +78,10 @@ class KubeIPResolver(Operator):
     def __init__(self, inventory_fn: Callable[[], dict] | None = None):
         self._inventory_fn = inventory_fn or hosts_inventory
         self._cache: dict[str, tuple[str, str]] = {}
-        self._last = 0.0
+        # "never refreshed": not 0.0 — time.monotonic() counts from an
+        # arbitrary origin (boot), and a host up for less than the refresh
+        # interval would otherwise never claim its first poll
+        self._last = float("-inf")
         self._mu = threading.Lock()
         self.refresh_interval = REFRESH_INTERVAL
 
@@ -89,7 +92,7 @@ class KubeIPResolver(Operator):
         with self._mu:
             self._inventory_fn = kube_inventory(client)
             self._cache = {}
-            self._last = 0.0
+            self._last = float("-inf")
             if refresh_interval is not None:
                 self.refresh_interval = refresh_interval
 

@@ -27,6 +27,18 @@ from jax.experimental.pallas import tpu as pltpu
 N_CHUNK = 256    # batch rows per MXU matmul step
 W_TILE = 1024    # histogram buckets per grid step, laid out as (8, 128)
 
+# stable kernel names: a lowered step carries `kernel_name = "<name>"` on
+# its tpu_custom_call, which is how a caller (chip_smoke.py, the compile
+# tests, a trace reduction) tells the fused update from the reference path
+# — that one runs the entropy histogram kernel on a TPU too
+FUSED_KERNEL_NAME = "fused_sketch_planes"
+HIST_KERNEL_NAME = "sketch_histogram"
+
+
+def kernel_in_lowered(text: str, name: str) -> bool:
+    """Whether `jitted.lower(...).as_text()` holds the named kernel."""
+    return "tpu_custom_call" in text and f'kernel_name = "{name}"' in text
+
 
 def _fmix32(h):
     h = h ^ (h >> 16)
@@ -34,6 +46,17 @@ def _fmix32(h):
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     return h ^ (h >> 16)
+
+
+def _u32_to_f32(v):
+    """uint32 -> float32 without the direct convert (the TPU compiler has
+    none in either direction): values below 2^31 go through int32; above,
+    halve with a sticky low bit so the round-to-nearest-even result equals
+    the direct convert's bit for bit."""
+    lo = jax.lax.bitcast_convert_type(v, jnp.int32)
+    half = jax.lax.bitcast_convert_type((v >> 1) | (v & 1), jnp.int32)
+    return jnp.where(lo >= 0, lo.astype(jnp.float32),
+                     half.astype(jnp.float32) * 2.0)
 
 
 def _hist_kernel(keys_ref, w_ref, out_ref, *, log2_width: int, mult: int,
@@ -82,6 +105,7 @@ def pallas_histogram(keys: jnp.ndarray, weights: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, 8, 128), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((width // W_TILE, 8, 128), jnp.float32),
+        name=HIST_KERNEL_NAME,
     )(keys2, w2)
     return out.reshape(width)
 
@@ -115,9 +139,10 @@ def xla_histogram(keys: jnp.ndarray, weights: jnp.ndarray, *,
 #   plane depth+1      HLL:        h = fmix32(distinct); value = rank,
 #                                  combined by MAX instead of ADD
 #   plane depth+2+3r+l invertible row r, lane l ∈ {count, keysum,
-#                                  fpsum}: uint32 accumulation (wraps
-#                                  mod 2^32 — the invertible algebra),
-#                                  bitcast to f32 bits for the output
+#                                  fpsum}: 32-bit integer accumulation
+#                                  (wraps mod 2^32 — the invertible
+#                                  algebra), bitcast to f32 bits for the
+#                                  output
 #   last plane         quantiles:  bucket = ceil(log_gamma(value)) (no
 #                                  hashing — DDSketch's log-spaced bins),
 #                                  one-hot histogram of the value lane;
@@ -131,11 +156,14 @@ def xla_histogram(keys: jnp.ndarray, weights: jnp.ndarray, *,
 # Histogram accumulation is f32 — exact for per-batch bucket deltas
 # < 2^24 (the staged batch is <= 2^17 rows), so casting the deltas back
 # to the sketches' int32 state is bit-identical to the reference scatter
-# path. The invertible lanes accumulate IN uint32 on the VPU (key*weight
-# products overflow f32's 24-bit mantissa, and mod-2^32 wrap is the
-# semantics, not an error), so they are bit-identical by construction;
-# the parity tier in tests/test_sketches.py holds every path to that
-# contract.
+# path. The invertible lanes accumulate in 32-bit INTEGERS on the VPU
+# (key*weight products overflow f32's 24-bit mantissa, and mod-2^32 wrap is
+# the semantics, not an error): uint32 products, summed on their int32 view
+# because the TPU compiler has no unsigned reduction — the same bits. So
+# they are bit-identical by construction; the parity tier in
+# tests/test_sketches.py holds every path to that contract. The compiler
+# also has no uint32<->float32 convert in either direction: the weights
+# lane goes through int32 and the value lane through _u32_to_f32.
 # ---------------------------------------------------------------------------
 
 
@@ -218,7 +246,7 @@ def _fused_kernel(hh_ref, distinct_ref, dist_ref, w_ref, *rest,
         # Zero-valued rows weigh 0 here; the wrapper accounts them in the
         # sketch's zero bucket (dd_update's is_zero term).
         def qt_body(c, acc):
-            vals = values_ref[c, :].astype(jnp.float32)
+            vals = _u32_to_f32(values_ref[c, :])
             wk = w_ref[c, :]
             v = jnp.maximum(vals, qt_min_value)
             idx = jnp.ceil(jnp.log(v) * qt_inv_log_gamma - qt_offset)
@@ -237,8 +265,8 @@ def _fused_kernel(hh_ref, distinct_ref, dist_ref, w_ref, *rest,
     if inv_rows:
         # invertible planes: bucket-hash parameters per ROW (3 planes
         # share a row), the lane kind (count/keysum/fpsum) selected by
-        # plane id mod 3; all arithmetic uint32 so the mod-2^32 wrap the
-        # decode inverts happens natively, then the accumulator's bits
+        # plane id mod 3; all arithmetic 32-bit integer so the mod-2^32 wrap
+        # the decode inverts happens natively, then the accumulator's bits
         # ride the f32 output via bitcast (memory moves only — no f32
         # arithmetic ever touches them)
         from .invertible import FP_SALT, INV_ROW_OFFSET
@@ -259,21 +287,24 @@ def _fused_kernel(hh_ref, distinct_ref, dist_ref, w_ref, *rest,
 
         def inv_body(c, acc):
             keys = hh_ref[c, :].astype(jnp.uint32)
-            wu = w_ref[c, :].astype(jnp.uint32)
+            wu = w_ref[c, :].astype(jnp.int32).astype(jnp.uint32)
             h = _fmix32(keys * imult + isalt)
             idx = (h >> (32 - inv_log2_buckets)).astype(jnp.int32)
             local = idx - tile * W_TILE
             fpv = _fmix32(keys ^ jnp.uint32(FP_SALT))
             val = jnp.where(lane == 0, wu,
                             jnp.where(lane == 1, keys * wu, fpv * wu))
-            contrib = jnp.where(local[:, None] == iota, val[:, None],
-                                jnp.uint32(0))
+            # the column sum runs on the int32 view: the TPU compiler has
+            # no unsigned reduction, and two's-complement adds wrap mod
+            # 2^32 exactly as the uint32 ones do
+            val = jax.lax.bitcast_convert_type(val, jnp.int32)
+            contrib = jnp.where(local[:, None] == iota, val[:, None], 0)
             return acc + contrib.sum(axis=0, keepdims=True)
 
         def run_inv():
-            acc_u = jax.lax.fori_loop(
-                0, n_chunks, inv_body, jnp.zeros((1, W_TILE), jnp.uint32))
-            return jax.lax.bitcast_convert_type(acc_u, jnp.float32)
+            acc_i = jax.lax.fori_loop(
+                0, n_chunks, inv_body, jnp.zeros((1, W_TILE), jnp.int32))
+            return jax.lax.bitcast_convert_type(acc_i, jnp.float32)
 
         def inv_dispatch():
             return jax.lax.cond(plane >= inv_base, run_inv, base_dispatch)
@@ -351,6 +382,7 @@ def fused_sketch_planes(hh_keys: jnp.ndarray, distinct_keys: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((n_planes, tiles, 8, 128),
                                        jnp.float32),
         interpret=interpret,
+        name=FUSED_KERNEL_NAME,
     )(*operands)
     out = out.reshape(n_planes, wmax)
     inv_delta = None

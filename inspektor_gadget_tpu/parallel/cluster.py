@@ -23,6 +23,7 @@ from __future__ import annotations
 import flax.struct
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.autoencoder import (
@@ -39,7 +40,6 @@ from ..ops.invertible import inv_psum
 from ..ops.quantiles import dd_psum
 from ..ops.sketches import SketchBundle, bundle_init, bundle_update
 from ..ops.topk import topk_gather_merge
-from .compat import shard_map
 from .mesh import MODEL_AXIS, NODE_AXIS
 
 

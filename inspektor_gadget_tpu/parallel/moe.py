@@ -30,10 +30,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import shard_map
 
 EXPERT_AXIS = "expert"
 

@@ -27,10 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import pcast_varying, shard_map
 
 STAGE_AXIS = "stage"
 
@@ -73,9 +71,9 @@ def make_pp_forward(mesh: Mesh, axis: str = STAGE_AXIS):
     s = mesh.shape[axis]
     perm = [(i, (i + 1) % s) for i in range(s)]
 
-    # check_vma=False: the scan carry's varying-type bookkeeping differs
-    # between the 0.4 check_rep checker and the new vma one; the schedule
-    # itself is checked by the numerics tests (pp_forward == sequential)
+    # check_vma=False: the scan carry mixes varying and replicated
+    # values; the schedule itself is checked by the numerics tests
+    # (pp_forward == sequential)
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(pp_pspecs(axis), P()), out_specs=P(),
                        check_vma=False)
@@ -100,7 +98,7 @@ def make_pp_forward(mesh: Mesh, axis: str = STAGE_AXIS):
             return (act, outbuf), None
 
         init = jax.tree.map(
-            lambda a: pcast_varying(a, (axis,)),
+            lambda a: lax.pcast(a, (axis,), to="varying"),
             (jnp.zeros((mb, d), jnp.float32), jnp.zeros_like(x)))
         (_, outbuf), _ = lax.scan(tick, init, jnp.arange(m + s - 1))
         # only the last stage holds real outputs; broadcast via masked psum

@@ -18,13 +18,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from .compat import axis_size
+from jax import lax
 
 
 def ring_psum(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """All-reduce via N-1 ring hops of the full tensor (exact for ints)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -45,7 +44,7 @@ def ring_psum_chunked(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     2(N-1)/N of the naive ring): the tensor is split into N chunks; each
     rank reduces one chunk over N-1 hops, then the reduced chunks ride
     N-1 more hops to every rank."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     orig_shape = x.shape

@@ -32,10 +32,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .compat import axis_size, pcast_varying, shard_map
 
 _NEG = jnp.float32(-1e30)  # finite "-inf": keeps exp() exact-zero without NaNs
 
@@ -122,7 +120,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
     Exact: produces bitwise the softmax of the full sequence up to f32
     accumulation order.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     scale = scale or d ** -0.5
@@ -142,7 +140,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
 
     # accumulators start replicated but the loop makes them device-varying;
     # pvary tells shard_map's vma type system up front
-    vary = lambda x: pcast_varying(x, (axis_name,))
+    vary = lambda x: lax.pcast(x, (axis_name,), to="varying")
     o0 = vary(jnp.zeros((b, h, t, d), jnp.float32))
     m0 = vary(jnp.full((b, h, t), _NEG))
     l0 = vary(jnp.zeros((b, h, t), jnp.float32))
@@ -164,7 +162,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = True,
     rotating KV when heads are plentiful and N is small.
     """
     h = q.shape[2]
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert h % n == 0, f"heads {h} not divisible by axis size {n}"
     a2a = functools.partial(lax.all_to_all, axis_name=axis_name,
                             split_axis=2, concat_axis=1, tiled=True)

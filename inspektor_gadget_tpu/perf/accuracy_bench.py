@@ -193,15 +193,10 @@ def publish(*, batch: int = 1 << 14, capacity: int = 1024,
             ledger: str | None = None) -> list[dict]:
     """Measure all three series and append the records to the ledger;
     returns the records (schema-validated by the append path)."""
-    from ..utils.platform_probe import acquire_platform_with_retry
     from .ledger import append_record
-    from .provenance import build_provenance, probe_block
+    from .provenance import acquire_provenance
 
-    acquired = acquire_platform_with_retry("auto")
-    import jax
-    actual = jax.devices()[0].platform
-    prov = build_provenance(actual, bool(acquired.get("degraded")),
-                            probe=probe_block(acquired))
+    prov = acquire_provenance("auto")
     feed = measure_feed(batch=batch, capacity=capacity, seconds=seconds)
     err = measure_observed_err(events=events, capacity=capacity)
     records = [feed_record(feed, prov), overhead_record(feed, prov),
@@ -226,16 +221,17 @@ def main(argv=None) -> int:
                        seconds=args.seconds, events=args.events,
                        ledger=args.ledger):
         e = rec["extra"]
+        plat = rec["provenance"]["platform"]
         if rec["config"] == "accuracy-audit":
-            print(f"accuracy-audit: {rec['value']:,.0f} ev/s with the "
+            print(f"accuracy-audit [{plat}]: {rec['value']:,.0f} ev/s with the "
                   f"shadow feed (batch {e['batch']}, capacity "
                   f"{e['capacity']}, overhead {e['audit_overhead']:.1%})")
         elif rec["config"] == "accuracy-overhead":
-            print(f"accuracy-overhead: {rec['value']:.4f} "
+            print(f"accuracy-overhead [{plat}]: {rec['value']:.4f} "
                   f"({e['base_ev_per_s']:,.0f} -> {e['fed_ev_per_s']:,.0f} "
                   "ev/s)")
         else:
-            print(f"accuracy-observed-err: {rec['value']:.5f}% observed "
+            print(f"accuracy-observed-err [{plat}]: {rec['value']:.5f}% observed "
                   f"vs {e['bound_pct']:.5f}% bound ({e['audited_keys']} "
                   f"key(s) audited over {e['events']:,} events)")
     return 0

@@ -104,7 +104,7 @@ def publish(ks=(1, 16), *, messages: int = 20000,
     from .ledger import append_record
     from .provenance import build_provenance
 
-    prov = build_provenance("cpu", False)
+    prov = build_provenance("cpu")
     records = []
     for k in ks:
         rec = fanout_record(measure_fanout(k, messages=messages), prov)

@@ -121,7 +121,7 @@ def publish(*, fleets: tuple[int, ...] = FLEETS,
     from .ledger import append_record
     from .provenance import build_provenance
 
-    prov = build_provenance("cpu", False)
+    prov = build_provenance("cpu")
     records = []
     for n in fleets:
         records.extend(fleet_records(measure_fleet(n), prov))

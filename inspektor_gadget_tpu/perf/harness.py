@@ -31,9 +31,10 @@ Instrumentation reuses the existing telemetry plane end to end:
 - the finished record embeds `telemetry.snapshot()` and, when asked, a
   Perfetto-loadable Chrome trace of the run.
 
-The platform is acquired FIRST through the bounded, retrying probe
-(utils/platform_probe.acquire_platform_with_retry) and the whole probe
-trail lands in the record's provenance — a degraded run says so in data.
+The platform is acquired FIRST, in this process
+(utils/platform_probe.acquire_platform): a run asked for the TPU fails
+when it does not get one, and the record's provenance names the platform
+that ran.
 
 Both pipelines prefer the seeded NATIVE synthetic source (classic pops
 Event structs and pays the Python decode+fold, fused drains the folded
@@ -53,7 +54,8 @@ import numpy as np
 from ..telemetry import counter, histogram, snapshot
 from ..telemetry.tracing import TRACER, export_chrome
 from ..utils.logger import get_logger
-from ..utils.platform_probe import acquire_platform_with_retry
+from ..utils.compile_cache import ensure_compile_cache
+from ..utils.platform_probe import acquire_platform
 from .provenance import build_provenance, probe_block
 from .schema import STAGES, make_record
 
@@ -64,7 +66,7 @@ SPAN_BATCHES = 64
 
 HARNESS_CONFIGS: dict[str, dict] = {
     # balanced default: big enough to exercise the device plane, small
-    # enough to finish on a CPU fallback without scaled-down shapes
+    # enough to finish on the CPU backend without scaled-down shapes
     "e2e": dict(batch=1 << 16, depth=4, log2_width=14, hll_p=12,
                 entropy_log2_width=10, k=64, seconds=2.0,
                 harvest_every=16, sync_every=4, merges=20),
@@ -135,16 +137,12 @@ def _fold32(keys64: np.ndarray) -> np.ndarray:
 
 def run_harness(config: str = "e2e", *, platform: str = "auto",
                 seconds: float | None = None,
-                probe_timeout: float | None = None,
-                probe_attempts: int | None = None,
-                probe_horizon: float | None = None,
                 trace_out: str | None = None,
                 replay: str | None = None,
                 pipeline: str = "fused",
                 chips: int = 1,
                 invertible: bool = False,
-                quantiles: bool = False,
-                extra_provenance_probe: dict | None = None) -> dict:
+                quantiles: bool = False) -> dict:
     """Run one harness config; returns a validated PerfRecord dict.
 
     `replay` points the host side at a capture journal instead of the
@@ -205,13 +203,10 @@ def run_harness(config: str = "e2e", *, platform: str = "auto",
     _tm_runs.labels(config=config).inc()
     window = cfg["seconds"] if seconds is None else float(seconds)
 
-    kw = {}
-    if probe_timeout is not None:
-        kw["timeout"] = probe_timeout
-    acquired = acquire_platform_with_retry(
-        platform, attempts=probe_attempts, horizon=probe_horizon, **kw)
+    ensure_compile_cache()
+    # raises PlatformUnavailable when the TPU was asked for and is absent
+    acquired = acquire_platform(platform)
 
-    # jax only after acquisition: the probe contract (bench.py's dance)
     import jax
     import jax.numpy as jnp
 
@@ -219,11 +214,11 @@ def run_harness(config: str = "e2e", *, platform: str = "auto",
     from ..ops.sketches import bundle_ingest_jit, bundle_init, bundle_update_jit
     from ..sources.synthetic import PySyntheticSource
 
-    actual = jax.devices()[0].platform
+    actual = acquired["platform"]
 
     if pipeline == "sharded":
         return _run_sharded(config, cfg, window, chips, acquired, actual,
-                            platform, trace_out, extra_provenance_probe)
+                            platform, trace_out)
 
     batch_n = cfg["batch"]
     replay_src = None
@@ -538,11 +533,7 @@ def run_harness(config: str = "e2e", *, platform: str = "auto",
             f.write(_json.dumps(doc, default=str))
         trace_file = trace_out
 
-    probe = probe_block(acquired)
-    if extra_provenance_probe:
-        probe.update(extra_provenance_probe)
-    prov = build_provenance(actual, bool(acquired.get("degraded")),
-                            probe=probe)
+    prov = build_provenance(actual, probe_block(acquired))
     extra_fields: dict = {}
     # pipeline provenance: the stage list names the shape that ran, and
     # the host-plane aggregate is the acceptance comparison's numerator
@@ -599,16 +590,14 @@ def run_harness(config: str = "e2e", *, platform: str = "auto",
                "requested_platform": platform, **extra_fields},
         trace_file=trace_file,
     )
-    log.info("harness %s: %.1f ev/s on %s%s (%d events, %d steps)",
-             config, value, actual,
-             " DEGRADED" if prov["degraded"] else "", events, steps)
+    log.info("harness %s: %.1f ev/s on %s (%d events, %d steps)",
+             config, value, actual, events, steps)
     return rec
 
 
 def _run_sharded(config: str, cfg: dict, window: float, chips: int,
                  acquired: dict, actual: str, platform: str,
-                 trace_out: str | None,
-                 extra_provenance_probe: dict | None) -> dict:
+                 trace_out: str | None) -> dict:
     """The ISSUE-14 chips-scaling arm: pop_folded → h2d_lanes →
     sharded_update over a (node) mesh of `chips` local devices. The
     config batch SPLITS across lanes (lane batch = batch/chips, loudly
@@ -835,11 +824,7 @@ def _run_sharded(config: str, cfg: dict, window: float, chips: int,
             f.write(_json.dumps(doc, default=str))
         trace_file = trace_out
 
-    probe = probe_block(acquired)
-    if extra_provenance_probe:
-        probe.update(extra_provenance_probe)
-    prov = build_provenance(actual, bool(acquired.get("degraded")),
-                            probe=probe)
+    prov = build_provenance(actual, probe_block(acquired))
     rec = make_record(
         config=f"harness.{config}",
         metric="sketch_ingest_device_plane_aggregate",
@@ -867,7 +852,6 @@ def _run_sharded(config: str, cfg: dict, window: float, chips: int,
         trace_file=trace_file,
     )
     log.info("harness %s sharded x%d: %.1f ev/s aggregate (%.1f/chip, "
-             "wall %.1f) on %s%s", config, chips, aggregate, per_chip,
-             device_wall, actual,
-             " DEGRADED" if prov["degraded"] else "")
+             "wall %.1f) on %s", config, chips, aggregate, per_chip,
+             device_wall, actual)
     return rec

@@ -161,7 +161,7 @@ def publish(*, n_windows: int = 256,
     from .ledger import append_record
     from .provenance import build_provenance
 
-    prov = build_provenance("cpu", False)
+    prov = build_provenance("cpu")
     records = [compaction_record(measure_compaction(n_windows), prov),
                pushdown_record(measure_pushdown(n_windows), prov)]
     for rec in records:

@@ -134,15 +134,10 @@ def publish(*, batch: int = 1 << 15, n_keys: int = 2048,
             seconds: float = 1.0, ledger: str | None = None) -> list[dict]:
     """Measure both series and append the records to the ledger;
     returns the records (schema-validated by the append path)."""
-    from ..utils.platform_probe import acquire_platform_with_retry
     from .ledger import append_record
-    from .provenance import build_provenance, probe_block
+    from .provenance import acquire_provenance
 
-    acquired = acquire_platform_with_retry("auto")
-    import jax
-    actual = jax.devices()[0].platform
-    prov = build_provenance(actual, bool(acquired.get("degraded")),
-                            probe=probe_block(acquired))
+    prov = acquire_provenance("auto")
     records = [
         update_record(measure_update(batch=batch, rows=rows,
                                      log2_buckets=log2_buckets,
@@ -170,11 +165,12 @@ def main(argv=None) -> int:
                        log2_buckets=args.log2_buckets,
                        seconds=args.seconds, ledger=args.ledger):
         e = rec["extra"]
+        plat = rec["provenance"]["platform"]
         if rec["config"] == "inv-update":
-            print(f"inv-update: {rec['value']:,.0f} ev/s "
+            print(f"inv-update [{plat}]: {rec['value']:,.0f} ev/s "
                   f"(batch {e['batch']}, {e['rows']}x2^{e['log2_buckets']})")
         else:
-            print(f"inv-decode: {rec['value']:,.0f} keys/s "
+            print(f"inv-decode [{plat}]: {rec['value']:,.0f} keys/s "
                   f"({e['keys']} keys, capacity {e['capacity']}, "
                   "complete)")
     return 0

@@ -1,9 +1,8 @@
 """Provenance stamping: who/where/what produced a perf number.
 
-Every PerfRecord carries the git sha (+dirty flag), a host fingerprint,
-the acquired platform with its degraded flag, and the full probe trail —
-so a record read months later still answers "was this a real TPU run?"
-without trusting surrounding prose (the round-5 VERDICT failure mode).
+Every PerfRecord carries the git sha (+dirty flag), a host fingerprint
+and the acquired platform — so a record read months later still answers
+"was this a real TPU run?" without trusting surrounding prose.
 """
 
 from __future__ import annotations
@@ -46,36 +45,44 @@ def host_fingerprint() -> dict:
     }
 
 
-def build_provenance(platform: str, degraded: bool,
-                     probe: dict | None = None,
+def build_provenance(platform: str, probe: dict | None = None,
                      cwd: str | None = None) -> dict:
-    """Assemble the provenance block from an acquire_platform-style
-    outcome dict (utils/platform_probe) plus repo + host facts."""
+    """Assemble the provenance block from the platform the run got, the
+    probe block of its acquisition (probe_block) and repo + host facts."""
     sha, dirty = git_provenance(cwd)
-    probe = dict(probe or {})
-    probe.setdefault("outcome", "unprobed")
-    probe.setdefault("attempts", [])
     return {
         "git_sha": sha,
         "git_dirty": dirty,
         "host": host_fingerprint(),
         "platform": platform if platform in ("tpu", "cpu", "gpu", "none")
         else "unknown",
-        "degraded": bool(degraded),
-        "probe": probe,
+        # a run that does not get the platform it asked for fails
+        # (utils/platform_probe), so nothing stamped here is degraded;
+        # the schema keeps the field for the imported records that are
+        "degraded": False,
+        "probe": probe or probe_block(None),
     }
 
 
 def probe_block(acquired: dict | None) -> dict:
-    """Normalize an acquire_platform(+retry) outcome into the record's
-    provenance.probe block."""
+    """The record's provenance.probe block from an acquire_platform
+    outcome (None: the run acquired no device of its own)."""
     if not acquired:
-        return {"outcome": "unprobed", "attempts": []}
-    outcome = "degraded" if acquired.get("degraded") else "ok"
+        return {"outcome": "unprobed"}
     return {
-        "outcome": outcome,
+        "outcome": "ok",
         "requested": acquired.get("requested", ""),
         "detail": acquired.get("detail", ""),
         "elapsed_s": round(float(acquired.get("elapsed", 0.0)), 3),
-        "attempts": list(acquired.get("attempts", [])),
     }
+
+
+def acquire_provenance(requested: str = "auto") -> dict:
+    """Acquire the device in this process (utils/platform_probe: a TPU
+    asked for and absent raises) and stamp the provenance block every
+    device-touching micro-bench shares."""
+    from ..utils.compile_cache import ensure_compile_cache
+    from ..utils.platform_probe import acquire_platform
+    ensure_compile_cache()
+    acquired = acquire_platform(requested)
+    return build_provenance(acquired["platform"], probe_block(acquired))

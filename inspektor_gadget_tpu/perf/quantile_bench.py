@@ -126,15 +126,10 @@ def publish(*, batch: int = 1 << 15, n_buckets: int = 2048,
             ledger: str | None = None) -> list[dict]:
     """Measure both series and append the records to the ledger;
     returns the records (schema-validated by the append path)."""
-    from ..utils.platform_probe import acquire_platform_with_retry
     from .ledger import append_record
-    from .provenance import build_provenance, probe_block
+    from .provenance import acquire_provenance
 
-    acquired = acquire_platform_with_retry("auto")
-    import jax
-    actual = jax.devices()[0].platform
-    prov = build_provenance(actual, bool(acquired.get("degraded")),
-                            probe=probe_block(acquired))
+    prov = acquire_provenance("auto")
     records = [
         update_record(measure_update(batch=batch, n_buckets=n_buckets,
                                      alpha=alpha, seconds=seconds), prov),
@@ -160,12 +155,13 @@ def main(argv=None) -> int:
                        alpha=args.alpha, seconds=args.seconds,
                        ledger=args.ledger):
         e = rec["extra"]
+        plat = rec["provenance"]["platform"]
         if rec["config"] == "quantile-update":
-            print(f"quantile-update: {rec['value']:,.0f} ev/s "
+            print(f"quantile-update [{plat}]: {rec['value']:,.0f} ev/s "
                   f"(batch {e['batch']}, {e['n_buckets']} buckets, "
                   f"alpha {e['alpha']:g})")
         else:
-            print(f"quantile-merge: {rec['value']:,.0f} merges/s "
+            print(f"quantile-merge [{plat}]: {rec['value']:,.0f} merges/s "
                   f"({e['n_buckets']} buckets)")
     return 0
 

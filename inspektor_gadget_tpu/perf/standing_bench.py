@@ -184,15 +184,10 @@ def publish(*, range_small: int = 16, range_large: int = 256,
             steps: int = 256, ledger: str | None = None) -> list[dict]:
     """Measure all three series and append the records to the ledger;
     returns the records (schema-validated by the append path)."""
-    from ..utils.platform_probe import acquire_platform_with_retry
     from .ledger import append_record
-    from .provenance import build_provenance, probe_block
+    from .provenance import acquire_provenance
 
-    acquired = acquire_platform_with_retry("auto")
-    import jax
-    actual = jax.devices()[0].platform
-    prov = build_provenance(actual, bool(acquired.get("degraded")),
-                            probe=probe_block(acquired))
+    prov = acquire_provenance("auto")
     windows = make_windows(range_large + steps)
     refresh = [measure_refresh(range_windows=r, windows=windows,
                                steps=steps)
@@ -226,23 +221,24 @@ def main(argv=None) -> int:
                        range_large=args.range_large,
                        steps=args.steps, ledger=args.ledger):
         e = rec["extra"]
+        plat = rec["provenance"]["platform"]
         if rec["config"] == "standing-refresh":
             grow = e["range_large"] / e["range_small"]
             cost = 1.0 / max(e["large_over_small"], 1e-9)
-            print(f"standing-refresh: {e['refresh_per_s_small']:,.0f} "
+            print(f"standing-refresh [{plat}]: {e['refresh_per_s_small']:,.0f} "
                   f"refreshes/s @ {e['range_small']}w vs "
                   f"{e['refresh_per_s_large']:,.0f} @ {e['range_large']}w "
                   f"({grow:.0f}x the range costs {cost:.1f}x per refresh)")
         elif rec["config"] == "standing-recompute":
             grow = e["range_large"] / e["range_small"]
             cost = 1.0 / max(e["large_over_small"], 1e-9)
-            print(f"standing-recompute: {e['recompute_per_s_small']:,.0f} "
+            print(f"standing-recompute [{plat}]: {e['recompute_per_s_small']:,.0f} "
                   f"recomputes/s @ {e['range_small']}w vs "
                   f"{e['recompute_per_s_large']:,.0f} @ "
                   f"{e['range_large']}w ({grow:.0f}x the range costs "
                   f"{cost:.1f}x per recompute)")
         else:
-            print(f"standing-cache-hit: {rec['value']:,.0f} reads/s "
+            print(f"standing-cache-hit [{plat}]: {rec['value']:,.0f} reads/s "
                   f"({e['folds_during_reads']} window folds during the "
                   "read loop)")
     return 0

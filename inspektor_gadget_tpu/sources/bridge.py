@@ -1,6 +1,7 @@
 """ctypes bridge to libigcapture.so — the cgo analogue.
 
-Loads (building on demand) the native capture library and exposes sources
+Loads the native capture library (`make` decides whether it must be
+built or rebuilt first, see _make_native) and exposes sources
 that pop struct-of-arrays EventBatches with zero per-event Python work:
 numpy buffers are handed to C++ which fills them directly.
 
@@ -75,29 +76,43 @@ def _load():
     if _lib is not None or _lib_err is not None:
         return _lib
     try:
-        lib = _load_and_bind(rebuild=not _LIB_PATH.exists())
-    except AttributeError:
-        # a stale libigcapture.so from before a symbol was added: force a
-        # rebuild once, then rebind — else every native call would crash
-        # instead of degrading
-        try:
-            lib = _load_and_bind(rebuild=True)
-        except (OSError, subprocess.CalledProcessError, AttributeError) as e:
-            _lib_err = str(e)
-            return None
-    except (OSError, subprocess.CalledProcessError) as e:
+        _lib = _load_and_bind()
+    except (OSError, AttributeError) as e:
+        # recorded, not swallowed: native_available() answers False and
+        # every path that ASKED for the native source raises with this
         _lib_err = str(e)
-        return None
-    _lib = lib
-    return lib
+    return _lib
 
 
-def _load_and_bind(rebuild: bool):
-    if rebuild:
-        subprocess.run(
-            ["make", "-C", str(_NATIVE_DIR), "-B"],
-            check=True, capture_output=True, text=True,
-        )
+def _make_native() -> None:
+    """Let make decide whether libigcapture.so is current: a plain
+    `make -C native` is a no-op when the library is newer than its
+    sources and rebuilds a stale one — the library is not committed, so
+    what git would check out always builds here. A lock serialises the
+    builders (test workers, agents started together). Only a host with
+    no `make` at all loads an existing library unchecked."""
+    import fcntl
+    import os
+    import shutil
+    if shutil.which("make") is None:
+        if _LIB_PATH.exists():
+            return
+        raise OSError(f"{_LIB_PATH.name} is not built and this host has "
+                      "no `make` to build it")
+    lock = os.open(_NATIVE_DIR, os.O_RDONLY)  # flock on the directory
+    try:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        r = subprocess.run(["make", "-C", str(_NATIVE_DIR)],
+                           capture_output=True, text=True)
+    finally:
+        os.close(lock)
+    if r.returncode != 0:
+        raise OSError(f"make -C {_NATIVE_DIR} failed (rc={r.returncode}): "
+                      + (r.stderr or r.stdout).strip()[-400:])
+
+
+def _load_and_bind():
+    _make_native()
     lib = ctypes.CDLL(str(_LIB_PATH))
 
     u64, u32, i64, f64 = (ctypes.c_uint64, ctypes.c_uint32, ctypes.c_int64,

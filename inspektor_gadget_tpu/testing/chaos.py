@@ -236,8 +236,8 @@ class AgentProcess:
         self.checkpoint_dir = checkpoint_dir
         self.extra_args = tuple(extra_args)
         self.env = dict(os.environ)
-        # agents probe their own platform; chaos fleets pin CPU so a
-        # respawn never hangs in device acquisition (VERDICT Weak #1)
+        # a chip belongs to one process: chaos fleets (many agents on one
+        # host, killed and respawned) are pinned to the CPU
         self.env["JAX_PLATFORMS"] = "cpu"
         # the package may be running from a source checkout that is not
         # installed: make `-m inspektor_gadget_tpu...` resolvable in the

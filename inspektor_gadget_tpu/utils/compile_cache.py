@@ -1,0 +1,40 @@
+"""One persistent XLA compilation cache for every entry point.
+
+A whole ingest step costs most of a minute to compile for the chip and a
+run meets several pad shapes, so every process that will touch the device
+(CLI, `agent.main serve`, the perf harness, bench.py, chip_smoke.py) calls
+`ensure_compile_cache()` before its first compile. Where
+`JAX_COMPILATION_CACHE_DIR` is set JAX already uses that directory and
+no directory is set in code. Otherwise the cache is ONE fixed directory
+inside the checkout — the path is part of the cache key's locality, so
+never a temporary name, a pid or a time.
+
+The key must also survive an edit. JAX strips source locations from a
+program before hashing it, but a Pallas kernel's body travels inside the
+program as an opaque serialized string that keeps its own locations, and
+with full tracebacks those name every Python frame above the kernel. So
+one shifted line in ANY caller (the operator, the smoke) changed the key
+of every program that holds a kernel — both update paths do on a TPU —
+and each chip run recompiled them all at about 28 s apiece (PR 21: five
+calls in a row hit nothing, two calls of an unchanged tree hit
+everything). Locations therefore carry the innermost frame only.
+"""
+
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def ensure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory and
+    return it. Idempotent; call before the first compile."""
+    import jax
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        return outside
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)

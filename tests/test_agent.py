@@ -64,7 +64,11 @@ def test_single_node_stream_with_seq(agents):
     # the server's drop count. Drops past the last delivered message (tail
     # eviction while the run winds down) legitimately show no gap, so
     # dropped > 0 with gaps == 0 is valid — the reverse is not.
-    assert res["gaps"] <= res["dropped"], "seq gaps exceed drop accounting"
+    # The drop count rides the CONTROL_ACK trailer, and that trailer and the
+    # end-of-stream sentinel after it are FORCED onto a possibly full queue:
+    # each may evict one more queued record after the count was written
+    # into the ACK's header, so the client may see up to two gaps more.
+    assert res["gaps"] <= res["dropped"] + 2, "seq gaps exceed drop accounting"
     client.close()
 
 
@@ -257,20 +261,34 @@ def test_node_failure_isolated(agents):
     desc = get("trace", "exec")
     params = desc.params().to_params()
     params.set("source", "pysynthetic")
-    params.set("rate", "3000")
-    ctx = GadgetContext(desc, gadget_params=params, timeout=2.0)
+    # a light stream: four in-process agents and the client share one
+    # interpreter, and a client that falls behind is evicted as stalled
+    params.set("rate", "300")
+    params.set("batch-size", "64")
+    # the timeout is a ceiling for a loaded machine (under the agents'
+    # 10 s stall eviction); the run ends as soon as every healthy node
+    # has streamed AFTER the kill
+    ctx = GadgetContext(desc, gadget_params=params, timeout=8.0)
     runtime = GrpcRuntime(targets)
-    events = []
+    healthy = {"node-0", "node-1", "node-2"}
+    killed = threading.Event()
+    after_kill = set()
 
     def killer():
         time.sleep(0.6)
-        doomed_server.stop(grace=0)
+        doomed_server.stop(grace=0).wait()
+        killed.set()
+
+    def on_event(e):
+        if killed.is_set():
+            after_kill.add(e.node)
+            if healthy <= after_kill:
+                ctx.cancel()
 
     threading.Thread(target=killer, daemon=True).start()
-    result = runtime.run_gadget(ctx, on_event=events.append)
+    result = runtime.run_gadget(ctx, on_event=on_event)
     runtime.close()
-    healthy = {"node-0", "node-1", "node-2"}
     assert healthy <= set(result.keys())
     for n in healthy:
         assert result[n].error is None, result[n].error
-    assert {e.node for e in events} >= healthy
+    assert after_kill >= healthy

@@ -1045,7 +1045,7 @@ def test_soak_fleet_chaos_invariants_and_scaling(tmp_path_factory):
         merge_s = max(time.perf_counter() - m0, 1e-9)
         assert merged.events > 0
         ledger = str(tmp_path_factory.mktemp("soak-ledger") / "PERF.jsonl")
-        prov = build_provenance("cpu", False)
+        prov = build_provenance("cpu")
         ingest_rec = make_record(
             config=f"soak-fleet-{n_nodes}node", metric="fleet_ingest",
             unit="ev/s", value=len(events) / duration,

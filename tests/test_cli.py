@@ -2,10 +2,13 @@
 integration/ig/* runs the built binary and matches output)."""
 
 import json
+import os
 import subprocess
 import sys
 
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CLI = [sys.executable, "-m", "inspektor_gadget_tpu.cli.main"]
 
@@ -61,9 +64,10 @@ def test_cli_traces_lifecycle_against_live_daemon(tmp_path):
     remote = f"n0={addr}"
     daemon = subprocess.Popen(
         [sys.executable, "-m", "inspektor_gadget_tpu.agent.main", "serve",
-         "--listen", addr, "--node-name", "n0", "--no-doctor"],
+         "--listen", addr, "--node-name", "n0", "--no-doctor",
+         "--platform", "cpu"],
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-        cwd="/root/repo")
+        cwd=REPO)
     try:
         deadline = time.time() + 120
         up = False

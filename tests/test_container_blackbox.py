@@ -20,6 +20,8 @@ import pytest
 
 from inspektor_gadget_tpu.sources.bridge import native_available
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 NEEDS = pytest.mark.skipif(
     not native_available() or os.geteuid() != 0
     or not shutil.which("unshare"),
@@ -46,7 +48,7 @@ def test_trace_open_containername_black_box(tmp_path):
             [sys.executable, "-m", "inspektor_gadget_tpu.cli.main",
              "trace", "open", "--localmanager-containername", COMM,
              "--timeout", "5", "-o", "json"],
-            capture_output=True, text=True, cwd="/root/repo", timeout=240)
+            capture_output=True, text=True, cwd=REPO, timeout=240)
     finally:
         child.kill()
         child.wait()
@@ -85,7 +87,7 @@ def test_trace_open_wrong_containername_sees_nothing(tmp_path):
             [sys.executable, "-m", "inspektor_gadget_tpu.cli.main",
              "trace", "open", "--localmanager-containername", "no-such-ctr",
              "--timeout", "3", "-o", "json"],
-            capture_output=True, text=True, cwd="/root/repo", timeout=240)
+            capture_output=True, text=True, cwd=REPO, timeout=240)
     finally:
         child.kill()
         child.wait()

@@ -127,9 +127,22 @@ def test_exec_tunnel_end_to_end_and_subprocess_reap(agent_addr):
         dialer.close()
 
 
-def test_exec_tunnel_raw_churn_reaps_and_survives(agent_addr):
+def test_exec_tunnel_raw_churn_reaps_and_survives(agent_addr, monkeypatch):
     """Raw connection churn (no gRPC): 10 open/close cycles against the
     tunnel listener; all subprocesses reaped, listener still serving."""
+    import grpc
+
+    from inspektor_gadget_tpu.agent import dialer as dialer_mod
+
+    spawned = []
+    real_popen = dialer_mod.subprocess.Popen
+
+    def counting_popen(*a, **kw):
+        p = real_popen(*a, **kw)
+        spawned.append(p)
+        return p
+
+    monkeypatch.setattr(dialer_mod.subprocess, "Popen", counting_popen)
     sock_path = agent_addr[len("unix://"):]
     dialer = ExecTunnelDialer([sys.executable, "-c", _BRIDGE, sock_path])
     try:
@@ -137,13 +150,20 @@ def test_exec_tunnel_raw_churn_reaps_and_survives(agent_addr):
             s = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
             s.connect(dialer._path)
             s.close()
-        deadline = time.monotonic() + 15.0
-        while time.monotonic() < deadline and dialer._procs:
-            time.sleep(0.2)
+        # wait on the condition, not the clock: the accept loop spawns one
+        # tunnel per queued connection (slowly, under load), so "no live
+        # procs" only means "reaped" once all 10 were spawned
+        deadline = time.monotonic() + 60.0
+        while time.monotonic() < deadline and (
+                len(spawned) < 10 or dialer._procs):
+            time.sleep(0.1)
+        assert len(spawned) == 10, f"only {len(spawned)}/10 tunnels spawned"
         assert not dialer._procs, "churned tunnels not reaped"
+        assert all(p.returncode is not None for p in spawned)
         # the listener is still alive: one more real roundtrip works
         client = AgentClient(agent_addr, "tunnel2", dialer=dialer)
         client.dialer = DirectDialer()
+        grpc.channel_ready_future(client.channel).result(timeout=60.0)
         assert client.get_catalog(use_cache_on_error=False)["gadgets"]
         client.close()
     finally:

@@ -77,7 +77,19 @@ def test_flash_gradients_odd_length():
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_flash_training_end_to_end():
+@pytest.fixture
+def interpreted_flash(monkeypatch):
+    """seqmodel's attn='flash' backend calls the kernel with the library
+    default (compile for the TPU); on the CPU the test steers it into the
+    interpreter explicitly."""
+    import functools
+
+    from inspektor_gadget_tpu.models import seqmodel
+    monkeypatch.setattr(seqmodel, "flash_attention", functools.partial(
+        flash_attention, interpret=True))
+
+
+def test_flash_training_end_to_end(interpreted_flash):
     """seq_train_step(attn='flash') learns: fused forward + recompute
     backward through the whole model."""
     from inspektor_gadget_tpu.models.seqmodel import (
@@ -96,7 +108,7 @@ def test_flash_training_end_to_end():
     assert losses[-1] < losses[0] * 0.8
 
 
-def test_seqmodel_flash_backend():
+def test_seqmodel_flash_backend(interpreted_flash):
     """attn='flash' scores through the kernel and matches the full-attention
     backend (the per-container NLL hot loop)."""
     from inspektor_gadget_tpu.models.seqmodel import (

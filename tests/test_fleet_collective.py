@@ -7,6 +7,7 @@ halves of a simulated two-host world."""
 from __future__ import annotations
 
 import json
+import os
 import socket
 import subprocess
 import sys
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from inspektor_gadget_tpu.fleet.collective import (
     bundle_digest,
@@ -25,8 +27,9 @@ from inspektor_gadget_tpu.fleet.collective import (
 )
 from inspektor_gadget_tpu.ops import bundle_init, bundle_update
 from inspektor_gadget_tpu.parallel import make_mesh
-from inspektor_gadget_tpu.parallel.compat import shard_map
 from inspektor_gadget_tpu.parallel.mesh import NODE_AXIS
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 N_NODES = 8
 BATCH = 256
@@ -168,7 +171,7 @@ def test_two_process_fleet_merge_digests_match(tmp_path):
         subprocess.Popen(
             [sys.executable, str(script), coord, str(i)],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            cwd="/root/repo")
+            cwd=REPO)
         for i in range(2)
     ]
     outs = []

@@ -61,6 +61,17 @@ def test_one_shot_combiner_gating():
     assert (ev, arr) == (None, None)
 
 
+def test_trace_rows_are_decoded_only_for_a_json_subscriber():
+    """A summary- or batch-only run gets no per-event handler: wiring one
+    makes the run loop decode every event of every batch into a Python
+    row that nobody receives (on the chip that held a summary-only agent
+    run to a seventh of the LocalRuntime run's step rate, PR 21)."""
+    assert handlers_for(GadgetType.TRACE, {"json", "summary"},
+                        "E", "A") == ("E", None)
+    assert handlers_for(GadgetType.TRACE, {"summary", "batch"},
+                        "E", "A") == (None, None)
+
+
 def test_result_typed_gadgets_implement_run_with_result():
     """Every PROFILE/START_STOP gadget class must expose run_with_result
     — the local runtime now refuses to run one that doesn't (the caller

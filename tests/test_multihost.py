@@ -6,12 +6,15 @@ for multi-host TPU pods on CPU devices.
 """
 
 import json
+import os
 import socket
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 WORKER = textwrap.dedent("""
     import json, os, sys
@@ -22,9 +25,7 @@ WORKER = textwrap.dedent("""
     jax.config.update("jax_platforms", "cpu")
 
     coord, pid = sys.argv[1], int(sys.argv[2])
-    # version drift (shard_map home + check flag) is resolved in ONE
-    # place now: the parallel.compat shim (ISSUE 14 satellite)
-    from inspektor_gadget_tpu.parallel.compat import shard_map
+    from jax import shard_map
     _smkw = {"check_vma": False}
     from inspektor_gadget_tpu.parallel.distributed import (
         init_distributed, make_multihost_mesh, world_size,
@@ -109,9 +110,7 @@ ELASTIC_WORKER = textwrap.dedent("""
 
     coord_a, coord_b, pid, tmpdir = (
         sys.argv[1], sys.argv[2], int(sys.argv[3]), sys.argv[4])
-    # version drift (shard_map home + check flag) is resolved in ONE
-    # place now: the parallel.compat shim (ISSUE 14 satellite)
-    from inspektor_gadget_tpu.parallel.compat import shard_map
+    from jax import shard_map
     _smkw = {"check_vma": False}
     from inspektor_gadget_tpu.parallel.distributed import (
         init_distributed, make_multihost_mesh, world_size,
@@ -231,6 +230,7 @@ ELASTIC_WORKER = textwrap.dedent("""
     jax.distributed.shutdown()
     import jax.extend.backend as jeb
     jeb.clear_backends()
+    print(json.dumps({"phase": "left-world-1", "pid": pid}), flush=True)
 
     # keep ingesting (host-side) while waiting; the kill lands here
     go2 = os.path.join(tmpdir, "phase2_go")
@@ -264,7 +264,7 @@ def test_two_process_sketch_merge(tmp_path):
         subprocess.Popen(
             [sys.executable, str(script), coord, str(i)],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            cwd="/root/repo")
+            cwd=REPO)
         for i in range(2)
     ]
     outs = []
@@ -307,7 +307,7 @@ def test_four_process_kill_one_and_remerge(tmp_path):
             [sys.executable, str(script), coord_a, coord_b, str(i),
              str(tmp_path)],
             stdout=subprocess.PIPE, stderr=err_files[i], text=True,
-            cwd="/root/repo")
+            cwd=REPO)
         for i in range(4)
     ]
 
@@ -324,15 +324,19 @@ def test_four_process_kill_one_and_remerge(tmp_path):
 
     try:
         # wait for phase 1 from every worker (read incrementally so the
-        # pipes don't fill)
+        # pipes don't fill), and until every worker has LEFT the first
+        # world: its shutdown is a barrier over all four, so a SIGKILL
+        # that lands on a worker still on its way there takes the
+        # survivors down too
         phase1 = {}
+        left = set()
         deadline = time.time() + 360
         import selectors
         sel = selectors.DefaultSelector()
         for i, p in enumerate(procs):
             os.set_blocking(p.stdout.fileno(), False)
             sel.register(p.stdout, selectors.EVENT_READ, i)
-        while len(phase1) < 4 and time.time() < deadline:
+        while len(left) < 4 and time.time() < deadline:
             for key, _ in sel.select(timeout=1.0):
                 chunk = key.fileobj.readline()
                 while chunk:
@@ -340,6 +344,10 @@ def test_four_process_kill_one_and_remerge(tmp_path):
                         rec = _json.loads(chunk)
                         if rec.get("phase") == 1:
                             phase1[key.data] = rec
+                            if "skip" in rec:
+                                left.add(key.data)   # it exits, clean
+                        elif rec.get("phase") == "left-world-1":
+                            left.add(key.data)
                     chunk = key.fileobj.readline()
             check_alive({0, 1, 2, 3})
         skips = [r for r in phase1.values() if "skip" in r]
@@ -347,6 +355,7 @@ def test_four_process_kill_one_and_remerge(tmp_path):
             pytest.skip(f"backend cannot run multiprocess collectives: "
                         f"{skips[0]['skip']}")
         assert len(phase1) == 4, f"phase1 incomplete: {phase1}"
+        assert len(left) == 4, f"workers still in the first world: {left}"
         # 4 procs x 512 keys each, merged across the world
         for rec in phase1.values():
             assert rec["merged_events"] == 4 * 512, rec

@@ -95,11 +95,37 @@ def test_pop_folded_roundtrips_one_batch():
 
 
 @needs_toolchain
-def test_stale_library_rebuilds_for_new_symbol(tmp_path):
-    """The bridge must rebuild a stale .so that predates
-    ig_source_pop_folded instead of crashing on the missing symbol (the
-    AttributeError → rebuild path in sources.bridge._load)."""
-    import ctypes
+def test_bridge_lets_make_decide(monkeypatch):
+    """The library is not committed and may be older than its sources, so
+    the bridge runs a plain `make -C native` (no -B: a no-op when the
+    library is current) even when libigcapture.so already exists."""
+    from inspektor_gadget_tpu.sources import bridge
 
-    lib = ctypes.CDLL(str(NATIVE / "libigcapture.so"))
-    assert hasattr(lib, "ig_source_pop_folded")
+    assert (NATIVE / "libigcapture.so").exists()
+    calls = []
+    real_run = subprocess.run
+
+    def spy(cmd, **kw):
+        calls.append(list(cmd))
+        return real_run(cmd, **kw)
+
+    monkeypatch.setattr(bridge.subprocess, "run", spy)
+    bridge._make_native()
+    assert calls == [["make", "-C", str(NATIVE)]]
+
+
+def test_bridge_build_failure_is_loud(monkeypatch):
+    """A failed build is never a quietly-loaded stale library: the reason
+    (make's own stderr) reaches whoever asked for the native source."""
+    from inspektor_gadget_tpu.sources import bridge
+
+    def failing(cmd, **kw):
+        return subprocess.CompletedProcess(cmd, 2, "", "g++: not found")
+
+    monkeypatch.setattr(shutil, "which", lambda name: "/usr/bin/make")
+    monkeypatch.setattr(bridge.subprocess, "run", failing)
+    monkeypatch.setattr(bridge, "_lib", None)
+    monkeypatch.setattr(bridge, "_lib_err", None)
+    assert not bridge.native_available()
+    with pytest.raises(RuntimeError, match="g\\+\\+: not found"):
+        bridge.NativeCapture(bridge.SRC_SYNTH_EXEC)

@@ -145,7 +145,7 @@ def test_tp_autoencoder_matches_replicated():
 
     mesh = make_mesh(n_nodes=4, n_model=2)
     specs = scorer_pspecs(scorer)
-    from inspektor_gadget_tpu.parallel.compat import shard_map
+    from jax import shard_map
     tp_fn = jax.jit(shard_map(
         lambda p, xx: ae_apply_tp(p, xx, cfg, model_axis="model"),
         mesh=mesh,
@@ -183,7 +183,7 @@ def test_ring_psum_variants_match_allreduce():
     """Ring all-reduce (ppermute hops) and the reduce-scatter/all-gather
     ring must equal lax.psum exactly on integer tables."""
     from jax.sharding import PartitionSpec as P
-    from inspektor_gadget_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from inspektor_gadget_tpu.parallel.ring import ring_psum, ring_psum_chunked
 
     mesh = make_mesh(n_nodes=8)
@@ -214,34 +214,24 @@ def test_vae_trains_and_scores_anomalies():
     assert float(vae_score(scorer, weird).mean()) > normal
 
 
-def test_compat_shim_resolves_this_jax():
-    """The ISSUE-14 version-drift shim: drift_notes names how THIS jax
-    spells each shimmed symbol, shard_map accepts the new keyword surface
-    (check_vma) on every supported jax, axis_size is a static int inside
-    the mapped body, and the Pallas TPU compiler-params constructor
-    resolves across the rename."""
+def test_axis_size_is_static_under_shard_map():
+    """The ring schedules build their permutation lists from
+    `lax.axis_size`, so it must be a static Python int inside a
+    `jax.shard_map` body on the installed jax."""
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
-
-    from inspektor_gadget_tpu.parallel import compat
-
-    notes = compat.drift_notes()
-    assert set(notes) >= {"jax", "shard_map", "check_flag",
-                          "compiler_params", "varying_cast"}
 
     mesh = make_mesh(n_nodes=4, n_model=1)
 
     def body(x):
-        n = compat.axis_size("node")
+        n = lax.axis_size("node")
         assert isinstance(n, int) and n == 4
         return (x[0] * 2)[None]
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P("node"),),
-                                 out_specs=P("node"), check_vma=False))
+    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("node"),),
+                          out_specs=P("node"), check_vma=False))
     x = jnp.arange(8, dtype=jnp.int32).reshape(4, 2)
     np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x) * 2)
-
-    assert compat.tpu_compiler_params(
-        dimension_semantics=("parallel",)) is not None
 
 
 def test_ingest_mesh_shape_and_validation():

@@ -40,6 +40,12 @@ def _release_instances():
     and unregister BOTH stats sources so no gauge residue leaks into
     other test files."""
     from inspektor_gadget_tpu.operators import tpusketch
+    # these tests assert an EMPTY live registry after their own runs, so
+    # orphans of earlier files on this worker (instances that never saw
+    # post_gadget_run) go first — which files share a worker is the
+    # scheduler's business
+    for orphan in live_stats():
+        orphan.unregister()
     before = set(tpusketch._live)
     yield
     with tpusketch._live_mu:

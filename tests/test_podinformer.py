@@ -128,7 +128,8 @@ def test_agent_serve_with_pod_manifest(tmp_path):
     proc = subprocess.Popen(
         [sys.executable, "-m", "inspektor_gadget_tpu.agent.main", "serve",
          "--listen", sock, "--node-name", "node-a",
-         "--pod-manifest", str(manifest), "--informer-interval", "0.2"],
+         "--pod-manifest", str(manifest), "--informer-interval", "0.2",
+         "--platform", "cpu"],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
     try:
         deadline = time.time() + 60

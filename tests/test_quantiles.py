@@ -65,7 +65,7 @@ def test_cluster_psum_merge_over_mesh():
         sk = dd_update(dd_init(), v)
         return dd_psum(sk, "node")
 
-    from inspektor_gadget_tpu.parallel.compat import shard_map
+    from jax import shard_map
     merged = jax.jit(shard_map(
         update_and_merge, mesh=mesh, in_specs=P("node"),
         out_specs=P(), check_vma=False))(jnp.asarray(vals))
@@ -137,7 +137,7 @@ def test_psum_equals_pairwise_merge():
     rng = np.random.default_rng(6)
     vals = rng.lognormal(-6.0, 1.5, (8, 512)).astype(np.float32)
     mesh = Mesh(np.array(jax.devices()[:8]), ("node",))
-    from inspektor_gadget_tpu.parallel.compat import shard_map
+    from jax import shard_map
     merged = jax.jit(shard_map(
         lambda v: dd_psum(dd_update(dd_init(), v), "node"),
         mesh=mesh, in_specs=P("node"), out_specs=P(),

@@ -125,8 +125,12 @@ def test_shared_fleet_run_one_gadget_k_subscribers(shared_agents):
     desc = get("trace", "exec")
     params = desc.params().to_params()
     params.set("source", "pysynthetic")
-    params.set("rate", "2000")
-    params.set("batch-size", "128")
+    # a light stream: this test asserts EXACT accounting (no subscriber
+    # drops on six concurrent streams), which a loaded CI host can only
+    # honour when the per-event JSON fan-out stays far under what the
+    # Python consumers drain — overload behaviour has its own tests below
+    params.set("rate", "300")
+    params.set("batch-size", "64")
     op_params = Collection()
     sp = op_registry.get("tpusketch").instance_params().to_params()
     for k, v in (("enable", "true"), ("log2-width", "10"),
@@ -660,17 +664,24 @@ def test_summary_tier_gets_summaries_never_batches(shared_agents):
     runtime = GrpcRuntime({"shnode-0": target})
     summaries: list = []
     windows: list = []
-    kinds: list = []
     sub_stop = threading.Event()
-    threading.Timer(4.0, sub_stop.set).start()
-    client_kinds_seen = kinds.append
+    # subscribed until a summary AND a window announcement arrived (the
+    # first harvest compiles; under six workers that can take seconds),
+    # the timer only a ceiling
+    ceiling = threading.Timer(30.0, sub_stop.set)
+    ceiling.start()
+
+    def seen(into: list, item) -> None:
+        into.append(item)
+        if summaries and windows:
+            sub_stop.set()
+
     res = runtime.subscribe_summaries(
         gadget="trace/exec",
-        on_summary=lambda n, s: (summaries.append(s),
-                                 client_kinds_seen(wire.EV_SUMMARY)),
-        on_window=lambda n, w: (windows.append(w),
-                                client_kinds_seen(wire.EV_WINDOW)),
+        on_summary=lambda n, s: seen(summaries, s),
+        on_window=lambda n, w: seen(windows, w),
         stop_event=sub_stop)
+    ceiling.cancel()
     runtime.close()
     out = res["shnode-0"]
     assert out.get("error") is None, out

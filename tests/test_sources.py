@@ -175,21 +175,24 @@ def test_packet_sniffer_flow_edges():
 
     src = NativeCapture(SRC_PKT_FLOW, ring_pow2=12)
     src.start()
-    time.sleep(0.4)
     s = pysock.socket(pysock.AF_INET, pysock.SOCK_DGRAM)
-    for port in (9901, 9902, 9903):
-        s.sendto(b"x", ("127.0.0.1", port))
-    s.close()
-    deadline = time.time() + 3.0
+    want = {9901, 9902, 9903}
+    # wait for OUR edges (other tests' loopback traffic makes edges too),
+    # and keep sending until the sniffer — however late it attached — has
+    # seen them
+    deadline = time.time() + 20.0
     edges = set()
-    while time.time() < deadline and len(edges) < 3:
+    while time.time() < deadline and not want <= edges:
+        for port in want:
+            s.sendto(b"x", ("127.0.0.1", port))
+        time.sleep(0.05)
         b = src.pop()
         for i in range(b.count):
             if b.cols["kind"][i] == 17:  # EV_NET_GRAPH
                 edges.add(int(b.cols["aux2"][i]) & 0xFFFF)
-        time.sleep(0.05)
+    s.close()
     src.stop(); src.close()
-    assert {9901, 9902, 9903} <= edges
+    assert want <= edges
 
 
 def _has_ipv6_loopback() -> bool:
@@ -292,17 +295,19 @@ def test_trace_network_decodes_real_protocol():
     g.set_event_handler(events.append)
 
     def traffic():
-        time.sleep(0.8)
-        s = pysock.socket(pysock.AF_INET, pysock.SOCK_DGRAM)
-        s.sendto(b"x", ("127.0.0.1", 9942))  # UDP to an EVEN port
-        s.close()
-        t = pysock.socket()
-        t.settimeout(0.5)
-        try:
-            t.connect(("127.0.0.1", 9943))   # TCP to an ODD port
-        except OSError:
-            pass
-        t.close()
+        # until the run ends, not once after a fixed head start: under
+        # load the packet socket may open later than any fixed delay
+        while not ctx.sleep_or_done(0.3):
+            s = pysock.socket(pysock.AF_INET, pysock.SOCK_DGRAM)
+            s.sendto(b"x", ("127.0.0.1", 9942))  # UDP to an EVEN port
+            s.close()
+            t = pysock.socket()
+            t.settimeout(0.5)
+            try:
+                t.connect(("127.0.0.1", 9943))   # TCP to an ODD port
+            except OSError:
+                pass
+            t.close()
 
     threading.Thread(target=traffic, daemon=True).start()
     threading.Thread(target=ctx.wait_for_timeout_or_done,

@@ -496,7 +496,7 @@ def test_sharded_lane_pool_telemetry_and_gauge_drain():
     p.set("shard-ingest", "true")
     p.set("chips", "2")
     inst = op.instantiate(ctx, None, p)
-    assert inst._shard_on
+    assert inst.device_view()["lanes"] == 2
 
     base = {k: (staging._tm_pool_hits.labels(lane=str(k)).value,
                 staging._tm_pool_misses.labels(lane=str(k)).value)
