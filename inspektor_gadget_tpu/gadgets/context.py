@@ -12,6 +12,7 @@ import uuid
 from typing import Any
 
 from ..params import Collection, Params
+from ..telemetry.pipeline import TurnClock
 from .interface import GadgetDesc
 
 
@@ -38,6 +39,9 @@ class GadgetContext:
         self.extra = extra or {}
         self.columns = desc.columns()
         self._stop = threading.Event()
+        # turn accounting of this run: the source gadget's loop and the
+        # operators time their stages into this one accumulator
+        self.turn = TurnClock()
         self.result: Any = None
         self.error: Exception | None = None
 

@@ -456,44 +456,60 @@ class SourceTraceGadget:
     def run(self, ctx: GadgetContext) -> None:
         self.source = self._make_source()
         deadline_hit = False
+        # the turn's stages (telemetry/pipeline.py TURN_STAGES): siblings
+        # that tile the loop from one publication to the next
+        turn = ctx.turn
+        st_wait, st_pop, st_filter, st_deliver = (
+            turn.stage(n) for n in ("source_wait", "source_pop",
+                                    "source_filter", "runtime_deliver"))
+        turn.begin()
         try:
             while not ctx.done and not deadline_hit:
                 got = 0
                 for src in self._active_sources():
                     self._current_source = src
-                    batch = src.pop()
+                    with st_pop:
+                        batch = src.pop()
                     if batch.count == 0:
                         continue
-                    got += batch.count
-                    popped = batch.count
-                    self._m_batches.inc()
-                    self._m_events.inc(popped)
-                    self._m_queue.set(popped)
-                    # baseline lives ON the source (a dict keyed by id(src)
-                    # would survive the source and alias a recycled id)
-                    prev_drops = getattr(src, "_tm_drops_seen", 0)
-                    if batch.drops > prev_drops:
-                        self._m_dropped.inc(batch.drops - prev_drops)
-                        src._tm_drops_seen = batch.drops
-                    self._apply_kind_filter(batch)
-                    self._apply_filter(batch)
-                    if batch.count != popped:
-                        self._m_filtered.inc(popped - batch.count)
-                    if batch.count:
-                        self.process_batch(batch)
+                    with st_filter:
+                        got += batch.count
+                        popped = batch.count
+                        self._m_batches.inc()
+                        self._m_events.inc(popped)
+                        self._m_queue.set(popped)
+                        # baseline lives ON the source (a dict keyed by
+                        # id(src) would survive the source and alias a
+                        # recycled id)
+                        prev_drops = getattr(src, "_tm_drops_seen", 0)
+                        if batch.drops > prev_drops:
+                            self._m_dropped.inc(batch.drops - prev_drops)
+                            src._tm_drops_seen = batch.drops
+                        self._apply_kind_filter(batch)
+                        self._apply_filter(batch)
+                        if batch.count != popped:
+                            self._m_filtered.inc(popped - batch.count)
+                        if batch.count:
+                            self.process_batch(batch)
                     if batch.count and self._batch_handler is not None:
                         self._batch_handler(batch)
                     if batch.count and self._event_handler is not None:
-                        self._emit_display_rows(batch)
+                        with st_deliver:
+                            self._emit_display_rows(batch)
+                    turn.publish()
                 if got == 0:
                     if self._source_done():
                         break  # e.g. traced command exited, ring drained
-                    if ctx.sleep_or_done(0.01):
+                    with st_wait:
+                        done = ctx.sleep_or_done(0.01)
+                    if done:
                         break
                     continue
                 if not self._threaded:
                     # pysynthetic generates instantly; pace by rate
-                    if ctx.sleep_or_done(got / max(self._rate, 1.0)):
+                    with st_wait:
+                        done = ctx.sleep_or_done(got / max(self._rate, 1.0))
+                    if done:
                         break
         finally:
             with self._attach_lock:

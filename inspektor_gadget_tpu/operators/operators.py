@@ -13,6 +13,7 @@ columnar hot path; the per-event enrich() remains for the formatter path.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Iterable
@@ -62,6 +63,11 @@ class Operator:
 
 
 class OperatorInstance:
+    # True for an instance that times the stages of its own enrich_batch
+    # into the run's turn (telemetry/pipeline.py TurnClock); the chain
+    # wraps every other instance in the sibling stage `operator_other`
+    times_own_stages = False
+
     def __init__(self, name: str):
         self.name = name
 
@@ -101,10 +107,13 @@ class Operators(list):
             inst.enrich(event)
         return event
 
-    def _spans(self) -> list[tuple[Any, Any]]:
+    def _spans(self) -> list[tuple[Any, Any, Any]]:
         spans = getattr(self, "_tm_spans", None)
         if spans is None or len(spans) != len(self):
-            spans = [(inst, _enrich_seconds.labels(operator=inst.name))
+            other = getattr(self, "stage_other", None)
+            spans = [(inst, _enrich_seconds.labels(operator=inst.name),
+                      contextlib.nullcontext()
+                      if other is None or inst.times_own_stages else other)
                      for inst in self]
             self._tm_spans = spans
         return spans
@@ -115,9 +124,9 @@ class Operators(list):
         # span places THIS batch's enrich on the run's timeline
         parent = getattr(self, "trace_parent", None)
         n = batch.count
-        for inst, hist in self._spans():
-            with TRACER.span(f"op/{inst.name}", parent=parent,
-                             attrs={"events": n}):
+        for inst, hist, stage in self._spans():
+            with stage, TRACER.span(f"op/{inst.name}", parent=parent,
+                                    attrs={"events": n}):
                 t0 = time.perf_counter()
                 inst.enrich_batch(batch)
                 hist.observe(time.perf_counter() - t0)
@@ -247,6 +256,7 @@ def install_operators(
     # spans parent to it even from source/drain threads, where the
     # tracer's contextvar is empty
     instances.trace_parent = ctx.extra.get("trace_ctx")
+    instances.stage_other = ctx.turn.stage("operator_other")
     for op in ops:
         with _init_lock:
             if op.name not in _initialized:

@@ -522,6 +522,16 @@ class TpuSketchInstance(OperatorInstance):
         self._m_update = _tm_update.labels(gadget=g)
         self._m_harvest_s = _tm_harvest_s.labels(gadget=g)
         self._m_qt_events = _tm_qt_events.labels(gadget=g)
+        # the stages of the batch turn this instance times itself
+        # (telemetry/pipeline.py TURN_STAGES): sibling `ig:` annotations
+        # on the profiler's clock, seconds into the run's TurnClock
+        self.times_own_stages = True
+        self._turn = turn = ctx.turn
+        (self._st_fold, self._st_h2d, self._st_update, self._st_planes,
+         self._st_slices, self._st_inv, self._st_post, self._st_seal,
+         self._st_harvest) = (turn.stage("tpusketch_" + n) for n in (
+             "fold", "h2d", "update", "window_planes", "slices",
+             "inv_classes", "post", "seal", "harvest"))
         # -- invertible heavy-key plane + priority classes (ISSUE 15) ----
         # All validation answers a typed ParamError HERE, before the
         # first batch: classes without the plane, and class geometries
@@ -724,6 +734,7 @@ class TpuSketchInstance(OperatorInstance):
         from ..telemetry.pipeline import PipelineStats
         self._pstats = PipelineStats(ctx.run_id, ctx.desc.full_name)
         self._pstats.register()
+        turn.attach(self._pstats)
         if self._astats is not None:
             # registered only when the audit plane is on: a plane-off
             # run must leave no accuracy gauges or live rows behind
@@ -1227,52 +1238,56 @@ class TpuSketchInstance(OperatorInstance):
 
         t0 = time.perf_counter()
         with self._span("tpusketch/h2d", events=n, pad=pad):
-            pool, stager = (self._lane_staging(pad) if self._shard_on
-                            else self._staging_for(pad))
-            block = pool.get()
-            lanes: dict[str, np.ndarray] = {}
+            with self._st_fold:
+                pool, stager = (self._lane_staging(pad) if self._shard_on
+                                else self._staging_for(pad))
+                block = pool.get()
+                lanes: dict[str, np.ndarray] = {}
 
-            def keys_for(colname: str) -> np.ndarray:
-                lane = lanes.get(colname)
-                if lane is None:
-                    lane = block[len(lanes)]
-                    a = batch.cols[colname][:n]
-                    if a.dtype == np.uint64:
-                        lane[:n] = fold64_to_32(a)
-                    else:
-                        lane[:n] = a
-                    lane[n:] = 0
-                    lanes[colname] = lane
-                return lane
+                def keys_for(colname: str) -> np.ndarray:
+                    lane = lanes.get(colname)
+                    if lane is None:
+                        lane = block[len(lanes)]
+                        a = batch.cols[colname][:n]
+                        if a.dtype == np.uint64:
+                            lane[:n] = fold64_to_32(a)
+                        else:
+                            lane[:n] = a
+                        lane[n:] = 0
+                        lanes[colname] = lane
+                    return lane
 
-            hh = keys_for(self.hh_col)
-            distinct = keys_for(self.distinct_col)
-            dist = keys_for(self.dist_col)
-            w = block[3]
-            w[:n] = 1
-            w[n:] = 0
-            vals = (self._qt_value_lane(batch, block, n)
-                    if self._qt_on else None)
-            new_drops = batch.drops - self._drops_seen
-            self._drops_seen = batch.drops
+                hh = keys_for(self.hh_col)
+                distinct = keys_for(self.distinct_col)
+                dist = keys_for(self.dist_col)
+                w = block[3]
+                w[:n] = 1
+                w[n:] = 0
+                vals = (self._qt_value_lane(batch, block, n)
+                        if self._qt_on else None)
+                new_drops = batch.drops - self._drops_seen
+                self._drops_seen = batch.drops
             # ONE async device put per distinct lane (shared columns stage
             # once); the transfer of this batch overlaps device compute of
             # the previous one — the block returns to the pool only after
             # the consumer fence below completes
-            uniq = list(lanes.values())
-            staged = stager.stage(
-                block, uniq + [w] + ([vals] if vals is not None else []))
-            staged_slot = stager.last_slot
-            nk = len(lanes)
-            by_col = dict(zip(lanes.keys(), staged[:nk]))
-            hh_d = by_col[self.hh_col]
-            distinct_d = by_col[self.distinct_col]
-            dist_d = by_col[self.dist_col]
-            w_d = staged[nk]
-            v_d = staged[nk + 1] if vals is not None else None
+            with self._st_h2d:
+                uniq = list(lanes.values())
+                staged = stager.stage(
+                    block, uniq + [w] + ([vals] if vals is not None else []))
+                staged_slot = stager.last_slot
+                nk = len(lanes)
+                by_col = dict(zip(lanes.keys(), staged[:nk]))
+                hh_d = by_col[self.hh_col]
+                distinct_d = by_col[self.distinct_col]
+                dist_d = by_col[self.dist_col]
+                w_d = staged[nk]
+                v_d = staged[nk + 1] if vals is not None else None
         t1 = time.perf_counter()
-        with self._span("tpusketch/update", events=n), \
-                device_annotation("ig:tpusketch_update"):
+        # the stages below are siblings: `ig:tpusketch_update` covers the
+        # step's dispatch only, so an idle gap under the window planes or
+        # the slices is named after them and not after the update
+        with self._span("tpusketch/update", events=n):
             if self._shard_on:
                 window_tokens = []
                 if self._hist_on:
@@ -1282,25 +1297,27 @@ class TpuSketchInstance(OperatorInstance):
                     # on the default device; their tokens join the lane's
                     # round fence because on CPU PJRT these asarrays may
                     # alias the pinned block
-                    self._wcms, wtok = _wcms_ingest_jit(
-                        self._wcms, jnp.asarray(hh),
-                        jnp.asarray(w).astype(jnp.int32))
-                    self._win_hll, htok = _hll_ingest_jit(
-                        self._win_hll, jnp.asarray(distinct),
-                        jnp.asarray(w) > 0)
-                    self._accumulate_slices(batch, n, hh, distinct, dist)
+                    with self._st_planes:
+                        self._wcms, wtok = _wcms_ingest_jit(
+                            self._wcms, jnp.asarray(hh),
+                            jnp.asarray(w).astype(jnp.int32))
+                        self._win_hll, htok = _hll_ingest_jit(
+                            self._win_hll, jnp.asarray(distinct),
+                            jnp.asarray(w) > 0)
+                    with self._st_slices:
+                        self._accumulate_slices(batch, n, hh, distinct, dist)
                     window_tokens = [wtok, htok]
                 if self._inv_classes:
-                    with self._bundle_mu:
+                    with self._st_inv, self._bundle_mu:
                         window_tokens += self._inv_class_absorb(
                             hh, self._padded_mntns(batch, n, len(hh)), w)
-                with self._bundle_mu:
+                with self._st_update, self._bundle_mu:
                     self._shard_absorb_locked(
                         hh_d, distinct_d, dist_d, w_d,
                         float(max(new_drops, 0)), window_tokens,
                         staged_slot, values_d=v_d)
             else:
-                with self._bundle_mu:
+                with self._st_update, self._bundle_mu:
                     if self._qt_on:
                         self.bundle, tok = _ingest_jit(
                             self.bundle, hh_d, distinct_d, dist_d, w_d,
@@ -1317,12 +1334,13 @@ class TpuSketchInstance(OperatorInstance):
                     # arrays: the WindowedCMS current slot and the
                     # per-window HLL absorb the batch so a seal reads
                     # window-only state
-                    self._wcms, wtok = _wcms_ingest_jit(self._wcms, hh_d,
-                                                        w_d.astype(jnp.int32))
-                    self._win_hll, htok = _hll_ingest_jit(self._win_hll,
-                                                          distinct_d,
-                                                          w_d > 0)
-                    self._accumulate_slices(batch, n, hh, distinct, dist)
+                    with self._st_planes:
+                        self._wcms, wtok = _wcms_ingest_jit(
+                            self._wcms, hh_d, w_d.astype(jnp.int32))
+                        self._win_hll, htok = _hll_ingest_jit(
+                            self._win_hll, distinct_d, w_d > 0)
+                    with self._st_slices:
+                        self._accumulate_slices(batch, n, hh, distinct, dist)
                     fence += [wtok, htok]
                 if self._inv_classes:
                     # the keys are already staged on the device (hh_d):
@@ -1331,44 +1349,46 @@ class TpuSketchInstance(OperatorInstance):
                     # Under _bundle_mu: _inv_class_jit donates, and the
                     # checkpointer thread snapshots class state under
                     # the same lock
-                    with self._bundle_mu:
+                    with self._st_inv, self._bundle_mu:
                         fence += self._inv_class_absorb(
                             hh_d, self._padded_mntns(batch, n, len(hh)), w)
                 # every consumer of the staged arrays is in the fence: the
                 # pinned block is reused only once they all completed (on
                 # CPU PJRT the device arrays may alias the host block, so
                 # transfer-complete alone is not enough)
-                stager.fence(tuple(fence))
+                with self._st_post:
+                    stager.fence(tuple(fence))
         t2 = time.perf_counter()
-        self._m_h2d.observe(t1 - t0)
-        self._m_update.observe(t2 - t1)
-        self._m_events.inc(n)
-        self._m_steps.inc()
-        self._qt_count(vals, n)
-        if new_drops > 0:
-            self._m_drops.inc(new_drops)
-        self._stats.steps += 1
-        self._stats.events += n
-        self._stats.drops = batch.drops
-        # pipeline watermarks: prefer the batch's stamped fields; an
-        # unstamped batch with a real ts column recovers the oldest
-        # event from it (one vectorized min)
-        oldest = batch.oldest_ts
-        if oldest <= 0.0:
-            tmin = float(batch.cols["ts"][:n].min())
-            if tmin > 0.0:
-                oldest = tmin / 1e9
-        self._note_watermarks(batch.pop_ts, oldest, lane)
-        # accuracy audit plane: the heavy-hitter key lane's real rows
-        # feed the shadow sample host-side (weight 1 per event, matching
-        # the staged weight lane)
-        self._shadow_feed(hh[:n])
-        # late enrichment (display-only work off the ingest path): two
-        # vectorized slice writes park a small (k64, k32, comm) sample in
-        # the rolling ring; name resolution happens at harvest/seal time
-        self._label_sample(batch, hh, n)
-        if self.anomaly_on:
-            self._accumulate_container_dists(batch, n)
+        with self._st_post:
+            self._m_h2d.observe(t1 - t0)
+            self._m_update.observe(t2 - t1)
+            self._m_events.inc(n)
+            self._m_steps.inc()
+            self._qt_count(vals, n)
+            if new_drops > 0:
+                self._m_drops.inc(new_drops)
+            self._stats.steps += 1
+            self._stats.events += n
+            self._stats.drops = batch.drops
+            # pipeline watermarks: prefer the batch's stamped fields; an
+            # unstamped batch with a real ts column recovers the oldest
+            # event from it (one vectorized min)
+            oldest = batch.oldest_ts
+            if oldest <= 0.0:
+                tmin = float(batch.cols["ts"][:n].min())
+                if tmin > 0.0:
+                    oldest = tmin / 1e9
+            self._note_watermarks(batch.pop_ts, oldest, lane)
+            # accuracy audit plane: the heavy-hitter key lane's real rows
+            # feed the shadow sample host-side (weight 1 per event, matching
+            # the staged weight lane)
+            self._shadow_feed(hh[:n])
+            # late enrichment (display-only work off the ingest path): two
+            # vectorized slice writes park a small (k64, k32, comm) sample in
+            # the rolling ring; name resolution happens at harvest/seal time
+            self._label_sample(batch, hh, n)
+            if self.anomaly_on:
+                self._accumulate_container_dists(batch, n)
         if self._hist_on and self._hist_interval > 0 and \
                 self._hist_clock() - self._win_start >= self._hist_interval:
             self.seal_window()
@@ -1394,7 +1414,9 @@ class TpuSketchInstance(OperatorInstance):
         n = fb.count
         lane = self._next_lane if self._shard_on else 0
         t0 = time.perf_counter()
-        with self._span("tpusketch/h2d", events=n, pad=fb.capacity):
+        # the same sibling stages as enrich_batch, minus fold and slices
+        with self._span("tpusketch/h2d", events=n, pad=fb.capacity), \
+                self._st_h2d:
             _pool, stager = (self._lane_staging(fb.capacity)
                              if self._shard_on
                              else self._staging_for(fb.capacity))
@@ -1417,31 +1439,31 @@ class TpuSketchInstance(OperatorInstance):
                 v_d = None
             staged_slot = stager.last_slot
         t1 = time.perf_counter()
-        with self._span("tpusketch/update", events=n), \
-                device_annotation("ig:tpusketch_update"):
+        with self._span("tpusketch/update", events=n):
             if self._shard_on:
                 window_tokens = []
                 if self._hist_on:
                     # single-chip window plane, restaged host views (see
                     # enrich_batch) — sealed windows stay correct under
                     # sharding, still minus slices on the folded path
-                    self._wcms, wtok = _wcms_ingest_jit(
-                        self._wcms, jnp.asarray(fb.keys),
-                        jnp.asarray(fb.weights).astype(jnp.int32))
-                    self._win_hll, htok = _hll_ingest_jit(
-                        self._win_hll, jnp.asarray(fb.keys),
-                        jnp.asarray(fb.weights) > 0)
+                    with self._st_planes:
+                        self._wcms, wtok = _wcms_ingest_jit(
+                            self._wcms, jnp.asarray(fb.keys),
+                            jnp.asarray(fb.weights).astype(jnp.int32))
+                        self._win_hll, htok = _hll_ingest_jit(
+                            self._win_hll, jnp.asarray(fb.keys),
+                            jnp.asarray(fb.weights) > 0)
                     window_tokens = [wtok, htok]
                 if self._inv_classes:
-                    with self._bundle_mu:
+                    with self._st_inv, self._bundle_mu:
                         window_tokens += self._inv_class_absorb(
                             fb.keys, fb.mntns, fb.weights)
-                with self._bundle_mu:
+                with self._st_update, self._bundle_mu:
                     self._shard_absorb_locked(
                         k_d, k_d, k_d, w_d, float(max(new_drops, 0)),
                         window_tokens, staged_slot, values_d=v_d)
             else:
-                with self._bundle_mu:
+                with self._st_update, self._bundle_mu:
                     if self._qt_on:
                         # v_d may be None (folded source with no value
                         # lane): the ingest step zero-fills — every
@@ -1459,33 +1481,36 @@ class TpuSketchInstance(OperatorInstance):
                     # WindowedCMS current slot and per-window HLL absorb
                     # the staged batch so interval seals read correct
                     # window-only state (minus slices — see the docstring)
-                    self._wcms, wtok = _wcms_ingest_jit(self._wcms, k_d,
-                                                        w_d.astype(jnp.int32))
-                    self._win_hll, htok = _hll_ingest_jit(self._win_hll, k_d,
-                                                          w_d > 0)
+                    with self._st_planes:
+                        self._wcms, wtok = _wcms_ingest_jit(
+                            self._wcms, k_d, w_d.astype(jnp.int32))
+                        self._win_hll, htok = _hll_ingest_jit(
+                            self._win_hll, k_d, w_d > 0)
                     fence += [wtok, htok]
                 if self._inv_classes:
                     # staged keys (k_d) reused — see enrich_batch; under
                     # _bundle_mu for the checkpointer snapshot
-                    with self._bundle_mu:
+                    with self._st_inv, self._bundle_mu:
                         fence += self._inv_class_absorb(k_d, fb.mntns,
                                                         fb.weights)
-                stager.fence(tuple(fence))
+                with self._st_post:
+                    stager.fence(tuple(fence))
         t2 = time.perf_counter()
-        self._m_h2d.observe(t1 - t0)
-        self._m_update.observe(t2 - t1)
-        self._m_events.inc(n)
-        self._m_steps.inc()
-        self._qt_count(fvals, n)
-        if new_drops > 0:
-            self._m_drops.inc(new_drops)
-        self._stats.steps += 1
-        self._stats.events += n
-        self._stats.drops = fb.drops
-        self._note_watermarks(fb.pop_ts, fb.oldest_ts, lane)
-        # accuracy audit plane: folded batches carry real integer
-        # weights — the shadow's ground-truth totals honor them
-        self._shadow_feed(fb.keys[:n], fb.weights[:n])
+        with self._st_post:
+            self._m_h2d.observe(t1 - t0)
+            self._m_update.observe(t2 - t1)
+            self._m_events.inc(n)
+            self._m_steps.inc()
+            self._qt_count(fvals, n)
+            if new_drops > 0:
+                self._m_drops.inc(new_drops)
+            self._stats.steps += 1
+            self._stats.events += n
+            self._stats.drops = fb.drops
+            self._note_watermarks(fb.pop_ts, fb.oldest_ts, lane)
+            # accuracy audit plane: folded batches carry real integer
+            # weights — the shadow's ground-truth totals honor them
+            self._shadow_feed(fb.keys[:n], fb.weights[:n])
         if self._hist_on and self._hist_interval > 0 and \
                 self._hist_clock() - self._win_start >= self._hist_interval:
             self.seal_window()
@@ -1653,6 +1678,10 @@ class TpuSketchInstance(OperatorInstance):
         the torn tail is dropped-and-accounted on read). Empty windows
         (no events since the last seal) are skipped — they carry no
         state and would bloat the range index."""
+        with self._st_seal:
+            self._seal_window()
+
+    def _seal_window(self) -> None:
         from ..history import HISTORY, SealedWindow, window_digest
         end = self._hist_clock()
         with self._bundle_mu:
@@ -1807,9 +1836,16 @@ class TpuSketchInstance(OperatorInstance):
     # harvest ---------------------------------------------------------------
 
     def harvest(self) -> SketchSummary:
-        with self._span("tpusketch/harvest", epoch=self._epoch + 1), \
-                device_annotation("ig:tpusketch_harvest"):
-            return self._harvest_traced()
+        with self._span("tpusketch/harvest", epoch=self._epoch + 1):
+            with self._st_harvest:
+                summary = self._harvest_traced()
+            if self._hist_on and self._hist_interval <= 0:
+                # history-interval 0: one sealed window per harvest — the
+                # deterministic-replay mode (harvest boundaries are
+                # recorded EV_SUMMARY records, so replay reseals identical
+                # windows). After the harvest stage, as its sibling
+                self.seal_window()
+        return summary
 
     def _harvest_traced(self) -> SketchSummary:
         t0 = time.perf_counter()
@@ -1836,8 +1872,11 @@ class TpuSketchInstance(OperatorInstance):
                 # donates these buffers); the quantile math runs on the
                 # host copies outside it
                 qt_now = self._qt_host(merged)
+        # the one blocking read of the tick, counted apart from the stage
+        t_wait = time.perf_counter_ns()
         events_f, drops_f, distinct, entropy_bits, approx, keys, counts = (
             decode_digest(digest))
+        self._turn.note_harvest_wait(time.perf_counter_ns() - t_wait)
         if approx and not self._overflow_counted:
             # count RUNS that crossed into approximation, not harvests:
             # the flag is latched, so one inc per instance is the honest
@@ -1997,11 +2036,6 @@ class TpuSketchInstance(OperatorInstance):
             cb(summary)
         self._m_harvests.inc()
         self._m_harvest_s.observe(time.perf_counter() - t0)
-        if self._hist_on and self._hist_interval <= 0:
-            # history-interval 0: one sealed window per harvest — the
-            # deterministic-replay mode (harvest boundaries are recorded
-            # EV_SUMMARY records, so replay reseals identical windows)
-            self.seal_window()
         return summary
 
     def post_gadget_run(self) -> None:

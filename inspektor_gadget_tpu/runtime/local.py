@@ -94,10 +94,13 @@ class LocalRuntime(Runtime):
             gadget.set_event_handler_array(handle_array)
 
         if isinstance(gadget, BatchHandlerSetter):
+            st_deliver = ctx.turn.stage("runtime_deliver")
+
             def handle_batch(batch):
                 instances.enrich_batch(batch)
                 if on_batch is not None:
-                    on_batch(batch)
+                    with st_deliver:
+                        on_batch(batch)
             gadget.set_batch_handler(handle_batch)
 
         if ctx.timeout > 0:

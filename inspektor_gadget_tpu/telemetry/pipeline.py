@@ -26,6 +26,12 @@ Vocabulary (docs/observability.md "Pipeline health & backpressure"):
   seconds are measured); the device is the bottleneck.
 - **starved_ratio** = starved / (starved + saturated).
 
+- **Turn**: one pass of a run's loop that popped a batch, from the
+  previous turn's end to this one's. The loop thread times each stage
+  of it (`TurnClock.stage`, `TURN_STAGES`) and publishes the turn once,
+  after the batch handler returned; the four longest turns are kept
+  with their stage split and the loop thread's CPU time.
+
 Lag *distributions* eat the quantile plane's own dogfood: each stage
 feeds a host-side DDSketch twin (`LagSketch`, same bucket math as
 `ops/quantiles.py`, pure numpy — this module must not import jax) so
@@ -36,10 +42,12 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 
 import numpy as np
 
 from .registry import counter, gauge
+from .tracing import annotation_class
 
 _tm_stage_lag = gauge(
     "ig_pipeline_stage_lag_seconds",
@@ -57,6 +65,27 @@ _tm_occupancy = gauge(
     "ig_pipeline_occupancy",
     "Occupied slots in a pipeline stage's ring",
     ("stage", "lane"))
+
+# the stages of a turn, in the order of the turn; each is also the host
+# annotation `ig:<name>` on the profiler's clock (siblings, never nested)
+TURN_STAGES = (
+    "source_wait", "source_pop", "source_filter", "operator_other",
+    "tpusketch_fold", "tpusketch_h2d", "tpusketch_update",
+    "tpusketch_window_planes", "tpusketch_slices", "tpusketch_inv_classes",
+    "tpusketch_post", "tpusketch_seal", "tpusketch_harvest",
+    "runtime_deliver")
+# the blocking read of the digest: a part of tpusketch_harvest counted
+# apart, so it rides the same array but is no stage (it tiles nothing)
+HARVEST_WAIT = "harvest_wait"
+_TURN_SLOTS = TURN_STAGES + (HARVEST_WAIT,)
+SLOW_TURNS = 4    # longest turns of a run kept with their stage split
+
+_tm_turn_seconds = counter(
+    "ig_pipeline_turn_seconds_total",
+    "Loop-thread seconds per stage of the batch turn (harvest_wait is "
+    "the blocking digest read inside tpusketch_harvest)", ("stage",))
+_tm_turns = counter(
+    "ig_pipeline_turns_total", "Batch turns a gadget run's loop published")
 
 
 class LagSketch:
@@ -131,6 +160,13 @@ class PipelineStats:
         self._backpressure: dict[str, int] = {}
         self._occupancy: dict[str, float] = {}
         self._occ_touched: set[tuple[str, str]] = set()
+        # turn accounting (TurnClock.publish): run totals in ns per slot
+        # of _TURN_SLOTS, and the SLOW_TURNS longest turns, longest first
+        self._turns = 0
+        self._turn_wall_ns = 0
+        self._turn_ns = [0] * len(_TURN_SLOTS)
+        self._slow: list[tuple[int, int, float, int, tuple[int, ...]]] = []
+        self._slow_floor = 0   # a turn must outlast this to enter _slow
 
     # -- observations (hot path: one lock + O(1) work per batch) ------------
 
@@ -185,6 +221,26 @@ class PipelineStats:
         with self._mu:
             self.rounds += 1
 
+    def note_turn(self, ns: list[int], wall_ns: int, cpu_ns: int,
+                  start: float, seq: int) -> None:
+        """One published turn: `ns` per slot of _TURN_SLOTS (the caller's
+        live array: copied only when the turn enters the longest four),
+        its wall and loop-thread CPU time, wall-clock start and batch
+        sequence number."""
+        with self._mu:
+            self._turns += 1
+            self._turn_wall_ns += wall_ns
+            tot = self._turn_ns
+            for i, v in enumerate(ns):
+                tot[i] += v
+            if wall_ns > self._slow_floor:
+                slow = self._slow
+                slow.append((wall_ns, cpu_ns, start, seq, tuple(ns)))
+                slow.sort(reverse=True)
+                del slow[SLOW_TURNS:]
+                if len(slow) == SLOW_TURNS:
+                    self._slow_floor = slow[-1][0]
+
     # -- reads --------------------------------------------------------------
 
     def snapshot(self) -> dict:
@@ -215,6 +271,19 @@ class PipelineStats:
                 "backpressure": dict(self._backpressure),
                 "occupancy": dict(self._occupancy),
                 "rounds": self.rounds,
+                "turn": {
+                    "turns": self._turns,
+                    "wall_s": self._turn_wall_ns * 1e-9,
+                    "stages": {n: v * 1e-9 for n, v in
+                               zip(TURN_STAGES, self._turn_ns)},
+                    "harvest_wait_s": self._turn_ns[-1] * 1e-9,
+                },
+                "slow_turns": [
+                    {"wall_s": wall * 1e-9, "cpu_s": cpu * 1e-9,
+                     "start": start, "seq": seq,
+                     "stages": {n: v * 1e-9 for n, v in
+                                zip(_TURN_SLOTS, ns) if v}}
+                    for wall, cpu, start, seq, ns in self._slow],
             }
 
     # -- lifecycle ----------------------------------------------------------
@@ -237,6 +306,88 @@ class PipelineStats:
         for stage, lane in occ:
             _tm_occupancy.labels(stage=stage, lane=lane).set(0.0)
         _tm_starved_ratio.set(0.0)
+
+
+class _Stage:
+    """One stage of one run's turn as a reusable context manager: two
+    clock reads, one add into the turn's array, and the stage's host
+    annotation on the profiler's clock. Loop thread only, no lock;
+    stages are siblings, so one object per stage is never re-entered."""
+
+    __slots__ = ("_ns", "_i", "_label", "_t0", "_ann")
+
+    def __init__(self, ns: list[int], i: int, label: str):
+        self._ns = ns
+        self._i = i
+        self._label = label
+
+    def __enter__(self) -> None:
+        self._t0 = time.perf_counter_ns()
+        # an annotation starts when it is made; outside a profiler
+        # session none is made (the check costs a tenth of making one)
+        cls = annotation_class()
+        self._ann = cls(self._label) if cls.is_enabled() else None
+
+    def __exit__(self, _type, _exc, _tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ns[self._i] += time.perf_counter_ns() - self._t0
+
+
+class TurnClock:
+    """Turn accounting of ONE gadget run, made with the run's
+    GadgetContext: the source gadget and the operators of the run time
+    their stages into the same array, on the loop thread, without a
+    lock; `publish()` closes the turn after the batch handler returned.
+    Nothing here reaches the tracer's ring."""
+
+    def __init__(self):
+        self._ns = [0] * len(_TURN_SLOTS)
+        self._stages = {name: _Stage(self._ns, i, "ig:" + name)
+                        for i, name in enumerate(TURN_STAGES)}
+        self._children = [_tm_turn_seconds.labels(stage=name)
+                          for name in _TURN_SLOTS]
+        self._stats: PipelineStats | None = None
+        self._seq = 0
+        self.begin()
+
+    def attach(self, stats: PipelineStats) -> None:
+        """Published turns also go to `stats` (the run's `pipeline`
+        block: run totals and the longest turns)."""
+        self._stats = stats
+
+    def stage(self, name: str) -> _Stage:
+        """The context manager of a stage of TURN_STAGES; bind it once,
+        enter it every turn."""
+        return self._stages[name]
+
+    def note_harvest_wait(self, ns: int) -> None:
+        self._ns[-1] += ns
+
+    def begin(self) -> None:
+        """The loop starts here: the first turn's wall runs from now."""
+        self._ns[:] = [0] * len(self._ns)
+        self._t_pub = time.perf_counter_ns()
+        self._cpu_pub = time.thread_time_ns()
+        self._wall_pub = time.time()
+
+    def publish(self) -> None:
+        """End of a turn: its wall is the time since the last
+        publication, so a pass that popped nothing (and its source_wait)
+        belongs to the turn that follows and the stages tile the turn."""
+        now, cpu, wall = (time.perf_counter_ns(), time.thread_time_ns(),
+                          time.time())
+        ns = self._ns
+        self._seq += 1
+        for child, v in zip(self._children, ns):
+            if v:
+                child.inc(v * 1e-9)
+        _tm_turns.inc()
+        if self._stats is not None:
+            self._stats.note_turn(ns, now - self._t_pub, cpu - self._cpu_pub,
+                                  self._wall_pub, self._seq)
+        ns[:] = [0] * len(ns)
+        self._t_pub, self._cpu_pub, self._wall_pub = now, cpu, wall
 
 
 _live_mu = threading.Lock()

@@ -278,16 +278,47 @@ def export_chrome(spans: Iterable[dict | SpanRecord],
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 
 
+class _NoAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation where JAX cannot be
+    imported: tracing must never require jax."""
+
+    def __init__(self, _name: str):
+        pass
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+    def __enter__(self) -> "_NoAnnotation":
+        return self
+
+    def __exit__(self, *_exc) -> None:
+        pass
+
+
+_annotation_cls: Any = None   # resolved at first use, then kept
+
+
+def annotation_class():
+    """jax.profiler.TraceAnnotation, or its no-op stand-in. An annotation
+    starts when it is made and ends at `__exit__`; `is_enabled()` says
+    whether a profiler session would keep it. Looked up once: the hot
+    loop asks a dozen times a batch."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation_cls = TraceAnnotation
+        except Exception:  # noqa: BLE001 — tracing must never require jax
+            _annotation_cls = _NoAnnotation
+    return _annotation_cls
+
+
 def device_annotation(name: str):
-    """jax.profiler.TraceAnnotation(name) when JAX is importable, so
-    device-plane spans line up with XLA activity in the same profiler
-    timeline; a no-op context manager otherwise."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — tracing must never require jax
-        import contextlib
-        return contextlib.nullcontext()
+    """A host annotation on the profiler's clock, so device-plane spans
+    line up with XLA activity in the same profiler timeline; a no-op
+    context manager without JAX."""
+    return annotation_class()(name)
 
 
 # ---------------------------------------------------------------------------

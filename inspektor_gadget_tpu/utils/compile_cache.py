@@ -23,15 +23,52 @@ everything). Locations therefore carry the innermost frame only.
 from __future__ import annotations
 
 import os
+import threading
 from pathlib import Path
 
+from ..telemetry import counter
+
 CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+# JAX's own monitoring events, counted where an operator of a live agent
+# can read them: a program that reaches the backend mid-run stalls the
+# loop on a thread whose CPU time the loop's own accounting cannot see
+_tm_compiles = counter(
+    "ig_jax_backend_compiles_total",
+    "programs handed to the backend compiler (a persistent-cache read "
+    "counts too: see ig_jax_compile_cache_hits_total)")
+_tm_compile_s = counter(
+    "ig_jax_backend_compile_seconds_total",
+    "seconds inside backend compiles, cache reads included")
+_tm_cache_hits = counter(
+    "ig_jax_compile_cache_hits_total",
+    "backend compiles answered by the persistent compilation cache")
+_listen_mu = threading.Lock()
+_listening = False
+
+
+def _on_duration(name: str, secs: float, **_kw) -> None:
+    if name == "/jax/core/compile/backend_compile_duration":
+        _tm_compiles.inc()
+        _tm_compile_s.inc(secs)
+
+
+def _on_event(name: str, **_kw) -> None:
+    if name == "/jax/compilation_cache/cache_hits":
+        _tm_cache_hits.inc()
 
 
 def ensure_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its directory and
-    return it. Idempotent; call before the first compile."""
+    return it; the first call also starts counting compiles. Idempotent;
+    call before the first compile."""
     import jax
+    global _listening
+    with _listen_mu:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if outside:

@@ -61,8 +61,11 @@ def _release_instances():
 
 
 def _pipeline_gauges() -> dict[str, float]:
+    # the plane's counters (backpressure, the turn's stage seconds and
+    # turns) only go up: teardown returns gauges to baseline, not those
     return {k: v for k, v in telemetry_registry.snapshot().items()
-            if k.startswith("ig_pipeline_") and "backpressure" not in k}
+            if k.startswith("ig_pipeline_")
+            and not k.split("{")[0].endswith("_total")}
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,238 @@
+"""Turn accounting (ISSUE 25): the batch turn timed stage by stage on the
+loop thread, from `src.pop()` to the seal.
+
+What is held here: the stages tile the turn (a), their `ig:` annotations
+are siblings that cover the loop thread's time (b), the slowest turns are
+kept with their stage split and the thread's CPU time (c), the helper
+costs next to nothing (d), nothing per stage reaches the tracer's ring
+(e), and backend compiles are counted (f).
+"""
+
+from __future__ import annotations
+
+import tempfile
+import threading
+import time
+
+import pytest
+
+import inspektor_gadget_tpu.all_gadgets  # noqa: F401
+from inspektor_gadget_tpu.gadgets import GadgetContext, get
+from inspektor_gadget_tpu.operators import tpusketch
+from inspektor_gadget_tpu.operators.operators import get as get_op
+from inspektor_gadget_tpu.params import Collection
+from inspektor_gadget_tpu.runtime.local import LocalRuntime
+from inspektor_gadget_tpu.telemetry import snapshot, tracing
+from inspektor_gadget_tpu.telemetry.pipeline import (
+    HARVEST_WAIT,
+    SLOW_TURNS,
+    TURN_STAGES,
+    PipelineStats,
+    TurnClock,
+)
+from inspektor_gadget_tpu.telemetry.tracing import TRACER
+
+STEPS = 'ig_tpusketch_steps_total{gadget="trace/exec"}'
+# what a run with history on and no priority classes must have timed
+TIMED = set(TURN_STAGES) - {"source_wait", "tpusketch_inv_classes"}
+
+
+class RecordingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: like it, an annotation
+    starts when it is made and ends at `__exit__`."""
+
+    log: list[tuple[str, int, int, int]] = []   # name, thread, open, close
+
+    def __init__(self, name: str):
+        self.name = name
+        self.thread = threading.get_ident()
+        self.opened = time.perf_counter_ns()
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return True
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *_exc):
+        self.log.append((self.name, self.thread, self.opened,
+                         time.perf_counter_ns()))
+
+
+def run_once(batches: int, history_dir: str):
+    """One local `trace exec` run with the native synthetic source and
+    history on, cancelled after `batches` batches. Returns the teardown
+    summary's `pipeline` block and the batches the caller's tap saw."""
+    desc = get("trace", "exec")
+    params = desc.params().to_params()
+    for k, v in (("source", "synthetic"), ("rate", "200000"),
+                 ("batch-size", "1024"), ("vocab", "500")):
+        params.set(k, v)
+    sp = get_op("tpusketch").instance_params().to_params()
+    for k, v in (("enable", "true"), ("depth", "2"), ("log2-width", "8"),
+                 ("hll-p", "6"), ("entropy-log2-width", "6"), ("topk", "8"),
+                 ("harvest-interval", "50ms"), ("history", "true"),
+                 ("history-interval", "120ms"), ("history-log2-width", "6"),
+                 ("history-dir", history_dir)):
+        sp.set(k, v)
+    ops = Collection()
+    ops["operator.tpusketch."] = sp
+    summaries: list = []
+    seen = [0]
+
+    def on_batch(_batch) -> None:
+        seen[0] += 1
+        if seen[0] >= batches:
+            ctx.cancel()
+
+    ctx = GadgetContext(desc, gadget_params=params, operator_params=ops,
+                        timeout=60.0,
+                        extra={"on_sketch_summary": summaries.append})
+    try:
+        result = LocalRuntime().run_gadget(ctx, on_batch=on_batch)
+    finally:
+        from inspektor_gadget_tpu.history import HISTORY
+        HISTORY.close_all()
+    assert not result.errors(), result.errors()
+    return summaries[-1].pipeline, seen[0]
+
+
+@pytest.fixture(scope="module")
+def recorded_run():
+    """One run under the recording annotation and an empty tracer ring."""
+    saved = tracing._annotation_cls
+    tracing._annotation_cls = RecordingAnnotation
+    RecordingAnnotation.log = []
+    TRACER.reset()
+    steps0 = snapshot().get(STEPS, 0.0)
+    try:
+        with tempfile.TemporaryDirectory(prefix="turn-hist-") as d:
+            pipe, batches = run_once(150, d)
+    finally:
+        tracing._annotation_cls = saved
+    return {"pipe": pipe, "batches": batches,
+            "steps": snapshot().get(STEPS, 0.0) - steps0,
+            "annotations": list(RecordingAnnotation.log),
+            "thread": threading.get_ident(),
+            "spans": TRACER.records()}
+
+
+def test_stages_tile_the_turn(recorded_run):
+    turn = recorded_run["pipe"]["turn"]
+    assert set(turn["stages"]) == set(TURN_STAGES)
+    for name in TIMED:
+        assert turn["stages"][name] > 0.0, name
+    assert turn["stages"]["tpusketch_inv_classes"] == 0.0
+    assert 0.0 < turn["harvest_wait_s"] < turn["stages"]["tpusketch_harvest"]
+    assert sum(turn["stages"].values()) >= 0.95 * turn["wall_s"]
+    assert sum(turn["stages"].values()) <= turn["wall_s"]
+    assert turn["turns"] == recorded_run["steps"] == recorded_run["batches"]
+
+
+def test_annotations_are_siblings_that_cover_the_loop(recorded_run):
+    loop = sorted((a, b, n) for n, th, a, b in recorded_run["annotations"]
+                  if th == recorded_run["thread"] and n.startswith("ig:"))
+    assert {n for _a, _b, n in loop} == {"ig:" + s for s in TIMED}
+    # every turn ends in runtime_deliver (the tap): first to last turn
+    ends = [b for _a, b, n in loop if n == "ig:runtime_deliver"]
+    inside = [(a, b, n) for a, b, n in loop if ends[0] <= a and b <= ends[-1]]
+    uncovered = 0
+    for (_a0, b0, n0), (a1, _b1, n1) in zip(inside, inside[1:]):
+        assert a1 >= b0, f"{n1} opened inside {n0}"
+        uncovered += a1 - b0
+    assert uncovered <= 0.05 * (ends[-1] - ends[0])
+
+
+def test_nothing_per_stage_reaches_the_tracer_ring(recorded_run):
+    names = [r.name for r in recorded_run["spans"]]
+    turns = recorded_run["pipe"]["turn"]["turns"]
+    assert not [n for n in names
+                if n.startswith("ig:") or n in TURN_STAGES
+                or n == HARVEST_WAIT]
+    # per batch what it was: one span an operator, and tpusketch's two
+    per_batch = [n for n in names if n.startswith("op/")
+                 or n in ("tpusketch/h2d", "tpusketch/update")]
+    chain = {n for n in names if n.startswith("op/")}
+    assert len(per_batch) == turns * (len(chain) + 2)
+    rest = set(names) - set(per_batch)
+    assert all(n.startswith(("run/", "tpusketch/harvest", "tpusketch/stage/",
+                             "tpusketch/seal-window")) for n in rest), rest
+
+
+def test_a_slow_stage_leads_the_slowest_turn(monkeypatch):
+    real = tpusketch.TpuSketchInstance._accumulate_slices
+    calls = [0]
+
+    def slow(self, *args):
+        calls[0] += 1
+        if calls[0] == 20:
+            time.sleep(0.2)
+        return real(self, *args)
+
+    monkeypatch.setattr(tpusketch.TpuSketchInstance, "_accumulate_slices",
+                        slow)
+    with tempfile.TemporaryDirectory(prefix="turn-hist-") as d:
+        pipe, batches = run_once(120, d)
+    assert batches >= 100 and pipe["turn"]["turns"] >= 100
+    slow_turns = pipe["slow_turns"]
+    assert len(slow_turns) == SLOW_TURNS
+    assert [t["wall_s"] for t in slow_turns] == sorted(
+        (t["wall_s"] for t in slow_turns), reverse=True)
+    first = slow_turns[0]
+    assert first["seq"] == 20 and first["wall_s"] >= 0.2
+    assert max(first["stages"], key=first["stages"].get) == "tpusketch_slices"
+    assert first["cpu_s"] < first["wall_s"] / 4     # it slept: no CPU
+    assert first["start"] <= time.time()
+
+
+def test_slow_turns_keep_the_longest_four():
+    stats = PipelineStats("run-turn-unit")
+    ns = [0] * (len(TURN_STAGES) + 1)
+    for seq, wall in enumerate([5, 1, 9, 3, 7, 2, 8, 4, 6], start=1):
+        ns[1] = wall
+        stats.note_turn(ns, wall, wall, float(seq), seq)
+    snap = stats.snapshot()
+    assert snap["turn"]["turns"] == 9
+    assert snap["turn"]["wall_s"] == pytest.approx(45e-9)
+    assert [t["seq"] for t in snap["slow_turns"]] == [3, 7, 5, 9]
+    # the record is a copy taken when the turn entered the four
+    assert snap["slow_turns"][0]["stages"] == {
+        "source_pop": pytest.approx(9e-9)}
+
+
+def test_the_helper_costs_under_two_microseconds():
+    assert not tracing.annotation_class().is_enabled()   # no session
+    stage = TurnClock().stage("source_pop")
+    pairs, best = 100_000, float("inf")
+    for _round in range(3):     # suite load: the best of three averages
+        t0 = time.perf_counter()
+        for _ in range(pairs):
+            with stage:
+                pass
+        best = min(best, (time.perf_counter() - t0) / pairs)
+    assert best < 2e-6, f"{best * 1e6:.2f} us a stage"
+
+
+def test_compiles_are_counted():
+    import jax
+    import jax.numpy as jnp
+    from inspektor_gadget_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
+    ensure_compile_cache()      # idempotent: one listener, not two
+    x = jnp.arange(7.0)
+    fresh = jax.jit(lambda v: v * 3.0 + 1.0)
+
+    def counts() -> tuple[float, float]:
+        snap = snapshot()
+        return (snap["ig_jax_backend_compiles_total"],
+                snap["ig_jax_backend_compile_seconds_total"])
+
+    n0, s0 = counts()
+    fresh(x).block_until_ready()
+    n1, s1 = counts()
+    assert n1 == n0 + 1 and s1 > s0
+    fresh(x).block_until_ready()
+    assert counts() == (n1, s1)
+    assert "ig_jax_compile_cache_hits_total" in snapshot()
