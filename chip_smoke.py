@@ -11,9 +11,11 @@ client on another thread, and nothing here starts a child that needs the
 device. Phases, one chip (each prints one JSON line; any failed check
 raises, so the exit code is non-zero and no result line is printed):
 
-  step-times     fused and scatter update step at the run's geometry:
-                 first-call seconds, ms/step, and that both leave
-                 bit-identical state behind
+  step-times     fused and scatter update step at the run's geometry and
+                 at a narrow one where the kernel measured faster: which
+                 arm the served path selects, first-call seconds, ms/step,
+                 that both leave bit-identical state behind, and that the
+                 selected arm is not the slower one by more than 10%
   local-runtime  `trace exec` through LocalRuntime.run_gadget: native
                  synthetic source -> pop -> operator chain -> tpusketch
                  (history on, one sealed window per 500 ms harvest), five
@@ -21,6 +23,8 @@ raises, so the exit code is non-zero and no result line is printed):
                  against an exact dict/set reference of the same stream
   invertible     a short run with the invertible plane: decode == exact
   quantiles      a short run with the DDSketch plane: p50..p99.9 vs exact
+  narrow         a short run at the narrow geometry: on a TPU the operator
+                 takes the kernel there, and its arm counter says so
   agent          one RunGadget from AgentClient against the agent service
                  (`agent.main serve`'s AgentServer) with --checkpoint-dir
                  semantics: the checkpointer thread reads device state
@@ -58,9 +62,12 @@ import numpy as np
 # never catches up, so it delivers well under it. 4M is meant to keep the
 # update step (not the source) the limit of the ingest loop, and a whole
 # run (five 500 ms windows, about 3 s) under the 2^24 events that the
-# float32 counters count exactly (check_counters).
+# float32 counters count exactly (check_counters). "narrow" overrides the
+# geometry with one where the fused kernel measured faster than the scatter
+# composition (PERF.md, PR 26): there the served path takes the kernel.
 SIZES = {
-    "tpu": dict(batch=65536, vocab=131072, geometry={}, windows=5,
+    "tpu": dict(batch=65536, vocab=131072, geometry={},
+                narrow={"log2-width": "12", "hll-p": "12"}, windows=5,
                 harvest="500ms", rate=4_000_000, deadline=420.0,
                 time_steps=8, short_windows=2, shard_batches=10),
     "cpu": dict(batch=2048, vocab=3000,
@@ -68,7 +75,8 @@ SIZES = {
                           "entropy-log2-width": "8", "topk": "32",
                           "inv-log2-buckets": "10",
                           "history-log2-width": "8"},
-                windows=5, harvest="100ms", rate=400_000, deadline=120.0,
+                narrow={"hll-p": "10"}, windows=5, harvest="100ms",
+                rate=400_000, deadline=120.0,
                 time_steps=4, short_windows=2, shard_batches=10),
 }
 ZIPF = 1.2
@@ -162,6 +170,16 @@ def metric(name: str) -> float:
     from inspektor_gadget_tpu.telemetry import snapshot
     return sum(v for k, v in snapshot().items()
                if k == name or k.startswith(name + "{"))
+
+
+def arm_steps() -> dict[str, float]:
+    """ig_tpusketch_update_arm_steps_total by its `arm` label."""
+    from inspektor_gadget_tpu.telemetry import snapshot
+    snap = snapshot()
+    return {arm: sum(v for k, v in snap.items()
+                     if k.startswith("ig_tpusketch_update_arm_steps_total{")
+                     and f'arm="{arm}"' in k)
+            for arm in ("fused", "scatter")}
 
 
 def update_path(jitted, args: tuple) -> str:
@@ -273,18 +291,21 @@ def leaf_platforms(tree) -> set[str]:
 # phase: step times (fused vs scatter at the run's geometry)
 # ---------------------------------------------------------------------------
 
-def phase_step_times(cfg: dict, platform: str, seed: int,
-                     clock: CompileClock) -> str:
+def time_arms(kw: dict, cfg: dict, platform: str, seed: int,
+              clock: CompileClock) -> dict:
+    """Both update arms as donating steps at the geometry `kw`: the arm
+    the served step selects, each arm's first-call seconds and ms/step,
+    and whether they left the same state behind."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
     from inspektor_gadget_tpu.ops.sketches import (
-        _bundle_update_pallas, bundle_ingest_jit, bundle_init, bundle_update)
+        _bundle_update_pallas, bundle_ingest_jit, bundle_init, bundle_update,
+        update_arm)
     from inspektor_gadget_tpu.sources.synthetic import PySyntheticSource
 
-    kw = bundle_geometry(cfg)
     n = cfg["batch"]
     src = PySyntheticSource(seed=seed, vocab=cfg["vocab"], zipf_s=ZIPF,
                             batch_size=n)
@@ -304,6 +325,9 @@ def phase_step_times(cfg: dict, platform: str, seed: int,
     # stands in for the kernel off-TPU — named as such)
     args = (bundle_init(**kw), keys[0], keys[0], keys[0], w, zero)
     served = update_path(bundle_ingest_jit, args)
+    selected = update_arm(args[0], n)
+    require(selected == served,
+            f"update_arm says {selected}, the step lowers to {served}")
     kernel_arm = "fused" if platform == "tpu" else "fused(interpret)"
     arms = ({"fused": bundle_ingest_jit, "scatter": with_token(bundle_update)}
             if served == "fused" else
@@ -333,9 +357,22 @@ def phase_step_times(cfg: dict, platform: str, seed: int,
     same = all(np.array_equal(x, y) for x, y in
                zip(jax.tree.leaves(a), jax.tree.leaves(b)))
     require(same, "fused and scatter steps left different state behind")
-    say(phase="step-times", batch=n, geometry=kw, served_path=served,
-        steps_timed=cfg["time_steps"], fused_equals_scatter=same, **out)
-    return served
+    (other,) = [name for name in out if name != served]
+    require(out[served]["ms_per_step"] <= 1.1 * out[other]["ms_per_step"],
+            f"the served path selects {served} at {kw}: "
+            f"{out[served]['ms_per_step']:.2f} ms/step against {other}'s "
+            f"{out[other]['ms_per_step']:.2f}")
+    return dict(geometry=kw, served_path=served, fused_equals_scatter=same,
+                **out)
+
+
+def phase_step_times(cfg: dict, platform: str, seed: int,
+                     clock: CompileClock) -> None:
+    narrow = dict(cfg, geometry={**cfg["geometry"], **cfg["narrow"]})
+    say(phase="step-times", batch=cfg["batch"], steps_timed=cfg["time_steps"],
+        **time_arms(bundle_geometry(cfg), cfg, platform, seed, clock),
+        narrow=time_arms(bundle_geometry(narrow), cfg, platform, seed,
+                         clock))
 
 
 # ---------------------------------------------------------------------------
@@ -415,6 +452,7 @@ def _local_once(cfg, platform, seed, windows, sketch_extra, vocab,
     sealed: list[dict] = []
     views: list[dict] = []
     steps0 = metric("ig_tpusketch_steps_total")
+    arms0 = arm_steps()
     seal_fail0 = metric("ig_history_drops_total")
 
     def on_summary(s) -> None:
@@ -441,9 +479,18 @@ def _local_once(cfg, platform, seed, windows, sketch_extra, vocab,
     facts = device_facts(views[0])
     require(facts["state_on"] == [platform],
             f"sketch state lives on {facts['state_on']}, not {platform}")
+    steps = int(metric("ig_tpusketch_steps_total") - steps0)
+    by_arm = {arm: int(v - arms0[arm])
+              for arm, v in arm_steps().items() if v > arms0[arm]}
+    require(by_arm == {facts["path"]: steps},
+            f"arm counter {by_arm}: the step lowers to {facts['path']} "
+            f"and ran {steps} times")
+    require(summaries[-1].pipeline["update_arm"] == facts["path"],
+            f"summary names {summaries[-1].pipeline['update_arm']}, the "
+            f"step lowers to {facts['path']}")
     facts.update(events_offered=exact.events + exact.drops,
                  events_absorbed=s["events"], drops=s["drops"],
-                 steps=int(metric("ig_tpusketch_steps_total") - steps0),
+                 steps=steps, arm_steps=by_arm,
                  harvests=len(summaries), run_s=round(seconds, 2),
                  generator="native C++ synthetic")
     if history_dir:
@@ -501,6 +548,18 @@ def phase_quantiles(cfg, platform, seed, clock) -> None:
                 f"{errs[name]:.4f} > alpha {q['alpha']}")
     say(phase="quantiles", **facts, **clock.take(), alpha=q["alpha"],
         rel_err={k: round(v, 5) for k, v in errs.items()},
+        reference=check_against_reference(s, exact))
+
+
+def phase_narrow(cfg, platform, seed, clock) -> None:
+    s, exact, facts = run_local(
+        cfg, platform, seed + 3, windows=cfg["short_windows"],
+        sketch_extra=cfg["narrow"], vocab=cfg["vocab"])
+    want = "fused" if platform == "tpu" else "scatter"
+    require(facts["path"] == want,
+            f"the operator took {facts['path']} at {cfg['narrow']}, "
+            f"expected {want}")
+    say(phase="narrow", geometry=cfg["narrow"], **facts, **clock.take(),
         reference=check_against_reference(s, exact))
 
 
@@ -774,6 +833,7 @@ def main(argv: list[str] | None = None) -> int:
         phase_local_runtime(cfg, platform, args.seed, clock)
         phase_invertible(cfg, platform, args.seed, clock)
         phase_quantiles(cfg, platform, args.seed, clock)
+        phase_narrow(cfg, platform, args.seed, clock)
         phase_agent(cfg, platform, args.seed, clock)
     say(phase="done", seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {

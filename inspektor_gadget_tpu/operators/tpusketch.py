@@ -42,7 +42,7 @@ from ..ops.invertible import (InvSketch, class_weights, inv_capacity,
 from ..ops.sketches import (bundle_digest_jit, bundle_ingest_jit,
                             bundle_stack_sharded, decode_digest,
                             make_bundle_harvest_sharded,
-                            make_bundle_ingest_sharded)
+                            make_bundle_ingest_sharded, update_arm)
 from ..ops.window import wcms_advance, wcms_init, wcms_query, wcms_update
 from ..params import ParamDesc, ParamDescs, ParamError, Params, TypeHint
 from ..params.validators import validate_int_range
@@ -107,6 +107,10 @@ _tm_events = counter("ig_tpusketch_events_total",
                      "events absorbed by the sketch plane", ("gadget",))
 _tm_steps = counter("ig_tpusketch_steps_total",
                     "bundle_update device steps", ("gadget",))
+_tm_arm_steps = counter("ig_tpusketch_update_arm_steps_total",
+                        "bundle_update device steps by the arm the step "
+                        "traced to (ops.sketches.update_arm)",
+                        ("gadget", "arm"))
 _tm_drops = counter("ig_tpusketch_drops_total",
                     "upstream drops folded into the bundle", ("gadget",))
 _tm_harvests = counter("ig_tpusketch_harvests_total",
@@ -650,6 +654,7 @@ class TpuSketchInstance(OperatorInstance):
             if bs > 0:
                 pad = max(pad, 1 << (bs - 1).bit_length())
         self._pad = pad
+        self._note_arm(pad)
         # pinned staging pool + depth-N H2D double buffer (created lazily
         # at the first batch, once the pad shape is known for real)
         self._h2d_depth = (p.get("h2d-depth").as_int()
@@ -996,6 +1001,14 @@ class TpuSketchInstance(OperatorInstance):
 
     # the columnar hot path -------------------------------------------------
 
+    def _note_arm(self, pad: int) -> None:
+        """The arm the update step traces to at this pad shape (the
+        dispatcher's own rule, asked where the shape is fixed), for the
+        arm counter and the summary's pipeline block."""
+        self._arm = update_arm(self.bundle, pad)
+        self._m_arm_steps = _tm_arm_steps.labels(
+            gadget=self.ctx.desc.full_name, arm=self._arm)
+
     def _staging_for(self, pad: int) -> tuple[PinnedBufferPool, H2DStager]:
         """The pinned pool + stager for the current pad shape; a pad
         growth (rare: one bigger batch) drains the old stager first so
@@ -1013,6 +1026,7 @@ class TpuSketchInstance(OperatorInstance):
                                           max_free=self._h2d_depth + 2)
             self._stager = H2DStager(self._pool, depth=self._h2d_depth,
                                      stats=self._pstats)
+            self._note_arm(pad)
         self._pad = max(self._pad, pad)
         return self._pool, self._stager
 
@@ -1066,6 +1080,7 @@ class TpuSketchInstance(OperatorInstance):
             self._lane_zeros = [
                 jax.device_put(np.zeros(pad, np.uint32), devices[k])
                 for k in range(self._chips)]
+            self._note_arm(pad)
         self._pad = max(self._pad, pad)
         return (self._lane_pools[self._next_lane],
                 self._lane_stagers[self._next_lane])
@@ -1364,6 +1379,7 @@ class TpuSketchInstance(OperatorInstance):
             self._m_update.observe(t2 - t1)
             self._m_events.inc(n)
             self._m_steps.inc()
+            self._m_arm_steps.inc()
             self._qt_count(vals, n)
             if new_drops > 0:
                 self._m_drops.inc(new_drops)
@@ -1501,6 +1517,7 @@ class TpuSketchInstance(OperatorInstance):
             self._m_update.observe(t2 - t1)
             self._m_events.inc(n)
             self._m_steps.inc()
+            self._m_arm_steps.inc()
             self._qt_count(fvals, n)
             if new_drops > 0:
                 self._m_drops.inc(new_drops)
@@ -1954,6 +1971,7 @@ class TpuSketchInstance(OperatorInstance):
         # watermarks/quantiles in the span args (run/trace IDs thread
         # through the ambient harvest context)
         pipe_out = self._pstats.snapshot()
+        pipe_out["update_arm"] = self._arm
         for stage, row in pipe_out["stages"].items():
             with self._span(f"tpusketch/stage/{stage}",
                             watermark_s=row["watermark_s"],

@@ -137,14 +137,36 @@ bundle_update_jit = jax.jit(bundle_update, donate_argnums=0)
 
 
 # -- fused single-pass update (ISSUE 10 tentpole) ---------------------------
-# On TPU with aligned shapes the four sketch planes update in ONE Pallas
-# pass over the staged batch (ops/pallas_kernels.fused_sketch_planes);
-# everywhere else bundle_update above stays the reference implementation
-# AND the runtime fallback — the selection mirrors entropy_update's
-# pallas_histogram/xla_histogram split. IG_FUSED_DISABLE=1 forces the
-# reference path even on TPU. The env var is read at TRACE time (inside
-# bundle_update_fused), so it takes effect for any shape not yet
-# compiled; already-cached traces keep their path until retrace.
+# One algorithm, two implementations whose costs scale differently with
+# the geometry: the fused Pallas pass over the staged batch
+# (ops/pallas_kernels.fused_sketch_planes) does one-hot work on every
+# plane padded to the widest, the scatter composition bundle_update above
+# touches one counter per plane and event. update_arm() picks between them
+# at TRACE time from what it can see (backend, shapes); bundle_update also
+# stays the reference implementation the parity tier holds the kernel to.
+# IG_FUSED_DISABLE=1 means "never the kernel"; like the rest of the choice
+# it is read at trace time, so it takes effect for any shape not yet
+# compiled and already-cached traces keep their arm until retrace.
+
+# Compare-selects the kernel does in the time the scatter composition
+# touches one counter, read on a TPU v5e (PR 26; fused_expected_to_win has
+# the table): the lowest of its readings, so the kernel is taken only
+# where every reading says it wins.
+FUSED_ONEHOTS_PER_COUNTER = 4700
+
+
+def _fused_planes(bundle: SketchBundle) -> tuple[int, int]:
+    """(planes, widest plane) as the fused kernel lays them out: one plane
+    per count-min row, entropy, HLL, three per invertible row, one for the
+    quantile row; every plane padded to the widest."""
+    inv, qt = bundle.inv, bundle.quantiles
+    wmax = max(bundle.cms.width, bundle.entropy.counts.shape[0],
+               bundle.hll.registers.shape[0],
+               inv.buckets if inv is not None else 0,
+               qt.counts.shape[0] if qt is not None else 0)
+    n_planes = (bundle.cms.depth + 2 + (3 * inv.rows if inv is not None else 0)
+                + (1 if qt is not None else 0))
+    return n_planes, wmax
 
 
 def fused_supported(bundle: SketchBundle, n: int) -> bool:
@@ -154,12 +176,47 @@ def fused_supported(bundle: SketchBundle, n: int) -> bool:
     invertible plane (when present) counts toward the widest plane like
     every other lane, as does the quantile row."""
     from .pallas_kernels import N_CHUNK, W_TILE
-    wmax = max(bundle.cms.width, bundle.entropy.counts.shape[0],
-               bundle.hll.registers.shape[0],
-               bundle.inv.buckets if bundle.inv is not None else 0,
-               (bundle.quantiles.counts.shape[0]
-                if bundle.quantiles is not None else 0))
-    return n % N_CHUNK == 0 and wmax % W_TILE == 0
+    return n % N_CHUNK == 0 and _fused_planes(bundle)[1] % W_TILE == 0
+
+
+def fused_expected_to_win(bundle: SketchBundle, n: int) -> bool:
+    """Whether the kernel is expected to be the faster arm for a batch of
+    n rows. Its work is planes x widest plane x n one-hot compare-selects
+    (every plane padded to the widest), the scatter composition's n x
+    planes counters touched; FUSED_ONEHOTS_PER_COUNTER is the exchange
+    rate between the two. The table it comes from (TPU v5e, ms a step
+    without the top-k refresh both arms share, fused / scatter, at batch
+    65,536 and 8,192; base = 6 planes, +inv 15, +qt 7):
+
+      widest   base 65,536   +inv 65,536   +qt 65,536    base 8,192
+      2^16     36.8 / 2.77   91.2 / 6.81   43.0 / 3.34   4.73 / 0.45
+      2^14      9.30 / 2.78  22.9 / 6.80   10.9 / 3.34   1.27 / 0.44
+      2^13      4.73 / 2.76  11.5 / 6.78    5.50 / 3.43  0.68 / 0.50
+      2^12      2.42 / 2.78   5.86 / 6.80   2.81 / 3.40  0.51 / 0.49
+      2^10      0.68 / 3.11   -             -            0.47 / 0.48
+
+    The kernel's time is 1.43 ps a compare-select in every row and the
+    scatter's 7.0-9.2 ns a counter, so the arms cross at a widest plane of
+    4,700-6,300 whatever the batch and the plane set (the work ratio is the
+    widest plane). Whole steps, top-k refresh included: 6.24 / 6.60 ms at
+    2^12 and 40.6 / 6.63 ms at 2^16 (batch 65,536); 0.88 / 0.94 at 2^12,
+    1.17 / 0.92 at 2^13 (batch 8,192)."""
+    n_planes, wmax = _fused_planes(bundle)
+    onehots = n_planes * wmax * n
+    counters = n_planes * n
+    return onehots <= FUSED_ONEHOTS_PER_COUNTER * counters
+
+
+def update_arm(bundle: SketchBundle, n: int) -> str:
+    """The arm bundle_update_fused takes for a batch of n rows, decided
+    where it traces: "fused" (the kernel: on a TPU, shapes aligned, expected
+    to win, not disabled) or "scatter" (the reference composition)."""
+    if (os.environ.get("IG_FUSED_DISABLE", "") != "1"
+            and jax.default_backend() == "tpu"
+            and fused_supported(bundle, n)
+            and fused_expected_to_win(bundle, n)):
+        return "fused"
+    return "scatter"
 
 
 def _bundle_update_pallas(
@@ -239,12 +296,10 @@ def bundle_update_fused(
     drops: jnp.ndarray | None = None,
     values: jnp.ndarray | None = None,
 ) -> SketchBundle:
-    """Drop-in bundle_update replacement: fused Pallas pass on TPU with
-    aligned shapes, the reference composition everywhere else. Both paths
-    produce bit-identical state (tests/test_sketches.py parity tier)."""
-    if (os.environ.get("IG_FUSED_DISABLE", "") != "1"
-            and jax.default_backend() == "tpu"
-            and fused_supported(bundle, hh_keys.shape[0])):
+    """Drop-in bundle_update replacement: the arm update_arm() names for
+    these shapes. Both arms produce bit-identical state
+    (tests/test_sketches.py parity tier)."""
+    if update_arm(bundle, hh_keys.shape[0]) == "fused":
         return _bundle_update_pallas(bundle, hh_keys, distinct_keys,
                                      dist_keys, mask, drops, values)
     return bundle_update(bundle, hh_keys, distinct_keys, dist_keys, mask,

@@ -47,13 +47,19 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
     for line in lines[:-1]:
         phases[json.loads(line)["phase"]] = json.loads(line)
     assert list(phases) == ["acquire", "step-times", "local-runtime",
-                            "invertible", "quantiles", "agent", "done"]
-    # truthful about what ran: no kernel on the CPU, and it says so
-    assert phases["step-times"]["served_path"] == "scatter"
-    assert phases["step-times"]["fused_equals_scatter"] is True
-    assert "fused(interpret)" in phases["step-times"]
+                            "invertible", "quantiles", "narrow", "agent",
+                            "done"]
+    # truthful about what ran: no kernel on the CPU, and it says so, at
+    # the run's geometry and at the one where a TPU takes the kernel
+    for times in (phases["step-times"], phases["step-times"]["narrow"]):
+        assert times["served_path"] == "scatter"
+        assert times["fused_equals_scatter"] is True
+        assert "fused(interpret)" in times
     local = phases["local-runtime"]
     assert local["path"] == "scatter" and local["state_on"] == ["cpu"]
+    # the operator's arm counter names the arm of every step it ran
+    for name in ("local-runtime", "invertible", "quantiles", "narrow"):
+        assert phases[name]["arm_steps"] == {"scatter": phases[name]["steps"]}
     assert local["windows_sealed"] >= 5 and local["harvests"] >= 6
     assert local["events_absorbed"] == local["events_offered"] - local["drops"]
     assert local["generator"] == "native C++ synthetic"

@@ -406,6 +406,132 @@ def test_fused_dispatch_selection_and_fallback():
         _assert_bundles_bit_identical(ref, got, ctx=n)
 
 
+# the operator's default geometry, and a narrow one from the table of
+# ops.sketches.fused_expected_to_win where the kernel measured faster
+_DEFAULT_GEOMETRY = dict(depth=4, log2_width=16, hll_p=14,
+                         entropy_log2_width=12, k=128)
+_KERNEL_GEOMETRY = dict(_DEFAULT_GEOMETRY, log2_width=12, hll_p=12)
+
+
+@pytest.mark.parametrize("geometry,n,backend,disabled,arm", [
+    pytest.param(_DEFAULT_GEOMETRY, 65536, "tpu", False, "scatter",
+                 id="default-65536"),
+    pytest.param(_DEFAULT_GEOMETRY, 8192, "tpu", False, "scatter",
+                 id="default-8192"),
+    pytest.param(dict(_DEFAULT_GEOMETRY, inv_rows=3), 65536, "tpu", False,
+                 "scatter", id="default+invertible"),
+    pytest.param(dict(_DEFAULT_GEOMETRY, quantiles=True), 65536, "tpu",
+                 False, "scatter", id="default+quantiles"),
+    pytest.param(dict(_DEFAULT_GEOMETRY, log2_width=12), 65536, "tpu",
+                 False, "scatter", id="narrow-cms-default-hll"),
+    pytest.param(dict(_DEFAULT_GEOMETRY, log2_width=13, hll_p=13), 8192,
+                 "tpu", False, "scatter", id="widest-2^13"),
+    pytest.param(_KERNEL_GEOMETRY, 65536, "tpu", False, "fused",
+                 id="narrow-65536"),
+    pytest.param(dict(_KERNEL_GEOMETRY, inv_rows=3, quantiles=True), 65536,
+                 "tpu", False, "fused", id="narrow+invertible+quantiles"),
+    pytest.param(_KERNEL_GEOMETRY, 8192, "tpu", False, "fused",
+                 id="narrow-8192"),
+    pytest.param(_KERNEL_GEOMETRY, 999, "tpu", False, "scatter",
+                 id="odd-batch"),
+    pytest.param(dict(depth=4, log2_width=8, hll_p=6, entropy_log2_width=6,
+                      k=8), 512, "tpu", False, "scatter",
+                 id="under-one-tile"),
+    pytest.param(_KERNEL_GEOMETRY, 65536, "tpu", True, "scatter",
+                 id="IG_FUSED_DISABLE"),
+    pytest.param(_KERNEL_GEOMETRY, 65536, "cpu", False, "scatter",
+                 id="cpu"),
+])
+def test_update_arm_selection(monkeypatch, geometry, n, backend, disabled,
+                              arm):
+    """(geometry, batch) -> arm: the kernel only on a TPU, for aligned
+    shapes, where it is expected to win and is not disabled; and
+    bundle_update_fused takes the arm update_arm names."""
+    from inspektor_gadget_tpu.ops import sketches
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if disabled:
+        monkeypatch.setenv("IG_FUSED_DISABLE", "1")
+    else:
+        monkeypatch.delenv("IG_FUSED_DISABLE", raising=False)
+    b = bundle_init(**geometry)
+    assert sketches.update_arm(b, n) == arm
+    took = []
+    monkeypatch.setattr(sketches, "_bundle_update_pallas",
+                        lambda bundle, *a, **kw: took.append("fused"))
+    monkeypatch.setattr(sketches, "bundle_update",
+                        lambda bundle, *a, **kw: took.append("scatter"))
+    keys = jnp.zeros(n, jnp.uint32)
+    sketches.bundle_update_fused(b, keys, keys, keys, jnp.ones(n, bool))
+    assert took == [arm]
+
+
+@pytest.mark.parametrize("settings", [
+    pytest.param({"log2-width": "12", "hll-p": "12"}, id="kernel-geometry"),
+    pytest.param({"log2-width": "10", "hll-p": "8",
+                  "entropy-log2-width": "8", "quantiles": "true"},
+                 id="quantiles"),
+])
+def test_operator_names_the_arm_the_dispatcher_took(settings):
+    """The arm counter counts every step under the arm its step lowers
+    to, and every summary's pipeline block names it (here, on the CPU,
+    the scatter composition whatever the geometry)."""
+    import inspektor_gadget_tpu.all_gadgets  # noqa: F401
+    from inspektor_gadget_tpu.gadgets import GadgetContext, get
+    from inspektor_gadget_tpu.operators import tpusketch
+    from inspektor_gadget_tpu.operators.operators import get as get_op
+    from inspektor_gadget_tpu.ops.pallas_kernels import (FUSED_KERNEL_NAME,
+                                                         kernel_in_lowered)
+    from inspektor_gadget_tpu.params import Collection
+    from inspektor_gadget_tpu.runtime.local import LocalRuntime
+    from inspektor_gadget_tpu.telemetry import snapshot
+
+    def counters() -> dict:
+        return {k: v for k, v in snapshot().items() if k.startswith((
+            "ig_tpusketch_update_arm_steps_total{",
+            "ig_tpusketch_steps_total{"))}
+
+    desc = get("trace", "exec")
+    params = desc.params().to_params()
+    for k, v in (("source", "pysynthetic"), ("rate", "50000"),
+                 ("batch-size", "512")):
+        params.set(k, v)
+    sp = get_op("tpusketch").instance_params().to_params()
+    for k, v in {"enable": "true", "topk": "16",
+                 "harvest-interval": "50ms", **settings}.items():
+        sp.set(k, v)
+    ops = Collection()
+    ops["operator.tpusketch."] = sp
+    summaries: list = []
+    lowered: list[str] = []
+
+    def on_summary(s) -> None:
+        summaries.append(s)
+        if not lowered:
+            (inst,) = tpusketch.live_instances()
+            step, args = inst.device_view()["step"]
+            lowered.append(step.lower(*args).as_text())
+        if len(summaries) >= 3:
+            ctx.cancel()
+
+    ctx = GadgetContext(desc, gadget_params=params, operator_params=ops,
+                        timeout=60.0, extra={"on_sketch_summary": on_summary})
+    before = counters()
+    result = LocalRuntime().run_gadget(ctx)
+    assert not result.errors(), result.errors()
+    took = ("fused" if kernel_in_lowered(lowered[0], FUSED_KERNEL_NAME)
+            else "scatter")
+    assert took == "scatter"
+    delta = {k: v - before.get(k, 0.0) for k, v in counters().items()
+             if v != before.get(k, 0.0)}
+    steps = delta.pop('ig_tpusketch_steps_total{gadget="trace/exec"}')
+    assert steps > 0
+    assert delta == {'ig_tpusketch_update_arm_steps_total'
+                     f'{{gadget="trace/exec",arm="{took}"}}': steps}
+    assert [s.pipeline["update_arm"] for s in summaries] == \
+        [took] * len(summaries)
+
+
 def test_fused_update_under_vmap_and_psum_merge():
     """Per-node fused updates must vmap cleanly and their states must
     merge exactly like reference states — both by pairwise bundle_merge

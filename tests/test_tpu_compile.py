@@ -162,20 +162,32 @@ def test_sharded_harvest_compiles_with_its_collectives(topo):
     assert "all-reduce" in text and "all-gather" in text
 
 
-def test_ingest_step_compiles_with_the_kernel(one_chip, monkeypatch):
-    """The whole donated ingest step at the default geometry, as the
-    operator dispatches it on a TPU. The dispatch asks
-    jax.default_backend(), which sees the CPU in this process, so the
-    test steers it; the kernel must be in the compiled step (a step that
-    quietly fell back to the scatter path is a failure here) and the
-    program must fit the chip."""
+@pytest.mark.parametrize("geometry,arm", [
+    pytest.param(GEOMETRY, "scatter", id="default-scatter"),
+    pytest.param(dict(GEOMETRY, log2_width=12, hll_p=12), "fused",
+                 id="narrow-fused"),
+])
+def test_ingest_step_compiles_with_the_selected_arm(one_chip, monkeypatch,
+                                                    geometry, arm):
+    """The whole donated ingest step as the operator dispatches it on a
+    TPU, at the default geometry (the scatter composition, whose entropy
+    plane is the histogram kernel) and at a narrow one where the fused
+    kernel is expected to win. The dispatch asks jax.default_backend(),
+    which sees the CPU in this process, so the test steers it; the step
+    must hold the arm update_arm names, and the program must fit the
+    chip."""
+    from inspektor_gadget_tpu.ops.sketches import update_arm
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    bundle = _on(one_chip, jax.eval_shape(lambda: bundle_init(**GEOMETRY)))
+    assert update_arm(bundle_init(**geometry), BATCH) == arm
+    bundle = _on(one_chip, jax.eval_shape(lambda: bundle_init(**geometry)))
     k = _lane(one_chip)
     drops = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
     lowered = jax.jit(bundle_ingest_step, donate_argnums=0).lower(
         bundle, k, k, k, k, drops)
-    assert kernel_in_lowered(lowered.as_text(), FUSED_KERNEL_NAME)
+    text = lowered.as_text()
+    assert kernel_in_lowered(text, FUSED_KERNEL_NAME) == (arm == "fused")
+    assert kernel_in_lowered(text, HIST_KERNEL_NAME) == (arm == "scatter")
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
