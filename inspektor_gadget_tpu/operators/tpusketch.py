@@ -113,6 +113,19 @@ _tm_arm_steps = counter("ig_tpusketch_update_arm_steps_total",
                         ("gadget", "arm"))
 _tm_drops = counter("ig_tpusketch_drops_total",
                     "upstream drops folded into the bundle", ("gadget",))
+# sharded ingest only: every family is labelled, so a one-chip run (which
+# asks for no child) leaves none of these names in the registry
+_tm_shard_rounds = counter(
+    "ig_tpusketch_shard_rounds_total",
+    "sharded update rounds dispatched: full (every lane held a batch) or "
+    "flushed (a harvest, seal or checkpoint closed the round early)",
+    ("gadget", "kind"))
+_tm_shard_fillers = counter(
+    "ig_tpusketch_shard_filler_lanes_total",
+    "zero-weight filler lanes that flushed rounds rode", ("gadget",))
+_tm_shard_lane_events = counter(
+    "ig_tpusketch_shard_lane_events_total",
+    "events of the batches parked on each ingest lane", ("gadget", "lane"))
 _tm_harvests = counter("ig_tpusketch_harvests_total",
                        "harvest ticks", ("gadget",))
 _tm_h2d = histogram("ig_tpusketch_h2d_seconds",
@@ -531,11 +544,13 @@ class TpuSketchInstance(OperatorInstance):
         # on the profiler's clock, seconds into the run's TurnClock
         self.times_own_stages = True
         self._turn = turn = ctx.turn
-        (self._st_fold, self._st_h2d, self._st_update, self._st_planes,
-         self._st_slices, self._st_inv, self._st_post, self._st_seal,
-         self._st_harvest) = (turn.stage("tpusketch_" + n) for n in (
-             "fold", "h2d", "update", "window_planes", "slices",
-             "inv_classes", "post", "seal", "harvest"))
+        (self._st_fold, self._st_h2d, self._st_restage, self._st_update,
+         self._st_planes, self._st_slices, self._st_inv, self._st_post,
+         self._st_seal, self._st_merge, self._st_harvest) = (
+             turn.stage("tpusketch_" + n) for n in (
+                 "fold", "h2d", "shard_restage", "update", "window_planes",
+                 "slices", "inv_classes", "post", "seal", "shard_merge",
+                 "harvest"))
         # -- invertible heavy-key plane + priority classes (ISSUE 15) ----
         # All validation answers a typed ParamError HERE, before the
         # first batch: classes without the plane, and class geometries
@@ -1045,6 +1060,14 @@ class TpuSketchInstance(OperatorInstance):
         self._harvest_sharded = make_bundle_harvest_sharded(self._mesh,
                                                             self.bundle)
         self._sharded = bundle_stack_sharded(self.bundle, self._mesh)
+        g = self.ctx.desc.full_name
+        self._m_rounds = {kind: _tm_shard_rounds.labels(gadget=g, kind=kind)
+                          for kind in ("full", "flushed")}
+        self._m_fillers = _tm_shard_fillers.labels(gadget=g)
+        self._m_lane_events = [
+            _tm_shard_lane_events.labels(gadget=g, lane=str(k))
+            for k in range(self._chips)]
+        self._pstats.shard_lanes(self._chips)
 
     def _lane_staging(self, pad: int) -> tuple[PinnedBufferPool, H2DStager]:
         """Pool + stager for the lane the NEXT batch lands on
@@ -1087,10 +1110,11 @@ class TpuSketchInstance(OperatorInstance):
 
     def _shard_absorb_locked(self, hh_d, distinct_d, dist_d, w_d,
                              new_drops: float, window_tokens: list,
-                             slot: int, values_d=None) -> None:
-        """Park one staged batch on its lane (the staged arrays already
-        live on that lane's chip; `slot` — captured at stage time —
-        names the stager slot to fence at dispatch) and advance the
+                             slot: int, events: int, values_d=None) -> None:
+        """Park one staged batch of `events` events on its lane (the
+        staged arrays already live on that lane's chip; `slot` — captured
+        at stage time — names the stager slot to fence at dispatch),
+        count its events on the lane, and advance the
         round-robin counter; dispatch ONE sharded step when every lane
         holds a batch. Under the quantile plane each round carries a 5th
         value-lane array; a batch without one (folded source with no
@@ -1110,6 +1134,8 @@ class TpuSketchInstance(OperatorInstance):
             "drops": max(new_drops, 0.0),
             "fences": list(window_tokens),
         }
+        self._m_lane_events[lane].inc(events)
+        self._pstats.note_lane_events(lane, events)
         self._next_lane = (self._next_lane + 1) % self._chips
         if len(self._pending) >= self._chips:
             self._dispatch_round_locked()
@@ -1134,6 +1160,7 @@ class TpuSketchInstance(OperatorInstance):
         from ..parallel.mesh import NODE_AXIS
         pad = self._lane_pools[0].capacity
         n_arr = 5 if self._qt_on else 4
+        fillers = self._chips - len(self._pending)
         for lane in range(self._chips):
             if lane in self._pending:
                 continue
@@ -1172,7 +1199,10 @@ class TpuSketchInstance(OperatorInstance):
                 self._lane_stagers[lane].fence_slot(
                     p["slot"], tuple([tok] + p["fences"]))
         self._pending = {}
-        self._pstats.note_round()
+        self._m_rounds["flushed" if fillers else "full"].inc()
+        if fillers:
+            self._m_fillers.inc(fillers)
+        self._pstats.note_round(fillers)
 
     def _flush_round_locked(self) -> None:
         self._dispatch_round_locked()
@@ -1312,13 +1342,15 @@ class TpuSketchInstance(OperatorInstance):
                     # on the default device; their tokens join the lane's
                     # round fence because on CPU PJRT these asarrays may
                     # alias the pinned block
+                    with self._st_restage:
+                        hh_w, distinct_w, w_w = (
+                            jnp.asarray(hh), jnp.asarray(distinct),
+                            jnp.asarray(w))
                     with self._st_planes:
                         self._wcms, wtok = _wcms_ingest_jit(
-                            self._wcms, jnp.asarray(hh),
-                            jnp.asarray(w).astype(jnp.int32))
+                            self._wcms, hh_w, w_w.astype(jnp.int32))
                         self._win_hll, htok = _hll_ingest_jit(
-                            self._win_hll, jnp.asarray(distinct),
-                            jnp.asarray(w) > 0)
+                            self._win_hll, distinct_w, w_w > 0)
                     with self._st_slices:
                         self._accumulate_slices(batch, n, hh, distinct, dist)
                     window_tokens = [wtok, htok]
@@ -1330,7 +1362,7 @@ class TpuSketchInstance(OperatorInstance):
                     self._shard_absorb_locked(
                         hh_d, distinct_d, dist_d, w_d,
                         float(max(new_drops, 0)), window_tokens,
-                        staged_slot, values_d=v_d)
+                        staged_slot, n, values_d=v_d)
             else:
                 with self._st_update, self._bundle_mu:
                     if self._qt_on:
@@ -1462,13 +1494,14 @@ class TpuSketchInstance(OperatorInstance):
                     # single-chip window plane, restaged host views (see
                     # enrich_batch) — sealed windows stay correct under
                     # sharding, still minus slices on the folded path
+                    with self._st_restage:
+                        keys_w, w_w = (jnp.asarray(fb.keys),
+                                       jnp.asarray(fb.weights))
                     with self._st_planes:
                         self._wcms, wtok = _wcms_ingest_jit(
-                            self._wcms, jnp.asarray(fb.keys),
-                            jnp.asarray(fb.weights).astype(jnp.int32))
+                            self._wcms, keys_w, w_w.astype(jnp.int32))
                         self._win_hll, htok = _hll_ingest_jit(
-                            self._win_hll, jnp.asarray(fb.keys),
-                            jnp.asarray(fb.weights) > 0)
+                            self._win_hll, keys_w, w_w > 0)
                     window_tokens = [wtok, htok]
                 if self._inv_classes:
                     with self._st_inv, self._bundle_mu:
@@ -1477,7 +1510,7 @@ class TpuSketchInstance(OperatorInstance):
                 with self._st_update, self._bundle_mu:
                     self._shard_absorb_locked(
                         k_d, k_d, k_d, w_d, float(max(new_drops, 0)),
-                        window_tokens, staged_slot, values_d=v_d)
+                        window_tokens, staged_slot, n, values_d=v_d)
             else:
                 with self._st_update, self._bundle_mu:
                     if self._qt_on:
@@ -1854,8 +1887,17 @@ class TpuSketchInstance(OperatorInstance):
 
     def harvest(self) -> SketchSummary:
         with self._span("tpusketch/harvest", epoch=self._epoch + 1):
+            t0 = time.perf_counter()
+            merged = None
+            if self._sharded is not None:
+                # what sharding puts before a harvest, as a sibling stage:
+                # the flush of the open round and the dispatch of the
+                # collective harvest. Its output is a fresh bundle no step
+                # donates, so the lock is let go before the digest
+                with self._st_merge, self._bundle_mu:
+                    merged = self._merged_locked()
             with self._st_harvest:
-                summary = self._harvest_traced()
+                summary = self._harvest_traced(t0, merged)
             if self._hist_on and self._hist_interval <= 0:
                 # history-interval 0: one sealed window per harvest — the
                 # deterministic-replay mode (harvest boundaries are
@@ -1864,13 +1906,12 @@ class TpuSketchInstance(OperatorInstance):
                 self.seal_window()
         return summary
 
-    def _harvest_traced(self) -> SketchSummary:
-        t0 = time.perf_counter()
+    def _harvest_traced(self, t0: float, merged=None) -> SketchSummary:
         # one packed digest: a single blocking D2H read per tick, not 6;
         # dispatched under the
         # bundle lock so a concurrent update can't donate the buffers
-        # mid-read. Under shard-ingest _merged_locked flushes the open
-        # round and runs the collective harvest first — same digest, any
+        # mid-read. Under shard-ingest harvest() hands in `merged`, the
+        # collective harvest of the flushed lanes — same digest, any
         # chip count. The invertible decode's DEVICE loop dispatches
         # under the same lock (its outputs are fresh buffers, and the
         # dispatched computation pins its inputs against later donation);
@@ -1878,7 +1919,8 @@ class TpuSketchInstance(OperatorInstance):
         inv_dev = None
         qt_now = None
         with self._bundle_mu:
-            merged = self._merged_locked()
+            if merged is None:
+                merged = self.bundle
             digest = bundle_digest_jit(merged)
             if self._inv_on and merged.inv is not None:
                 from ..ops.invertible import inv_decode_device

@@ -70,10 +70,13 @@ _tm_occupancy = gauge(
 # annotation `ig:<name>` on the profiler's clock (siblings, never nested)
 TURN_STAGES = (
     "source_wait", "source_pop", "source_filter", "operator_other",
-    "tpusketch_fold", "tpusketch_h2d", "tpusketch_update",
-    "tpusketch_window_planes", "tpusketch_slices", "tpusketch_inv_classes",
-    "tpusketch_post", "tpusketch_seal", "tpusketch_harvest",
-    "runtime_deliver")
+    "tpusketch_fold", "tpusketch_h2d", "tpusketch_shard_restage",
+    "tpusketch_update", "tpusketch_window_planes", "tpusketch_slices",
+    "tpusketch_inv_classes", "tpusketch_post", "tpusketch_seal",
+    "tpusketch_shard_merge", "tpusketch_harvest", "runtime_deliver")
+# stages only a run under shard-ingest opens. A one-chip run carries none
+# of their names: no counter child, no key in the `pipeline` block
+SHARD_STAGES = ("tpusketch_shard_restage", "tpusketch_shard_merge")
 # the blocking read of the digest: a part of tpusketch_harvest counted
 # apart, so it rides the same array but is no stage (it tiles nothing)
 HARVEST_WAIT = "harvest_wait"
@@ -157,6 +160,12 @@ class PipelineStats:
         self.saturated = 0
         self.stall_s = 0.0
         self.rounds = 0
+        # sharded ingest only (shard_lanes): events parked per lane, and
+        # the rounds a harvest, seal or checkpoint closed early with
+        # their zero-weight filler lanes
+        self._lane_events: list[int] | None = None
+        self._rounds_flushed = 0
+        self._filler_lanes = 0
         self._backpressure: dict[str, int] = {}
         self._occupancy: dict[str, float] = {}
         self._occ_touched: set[tuple[str, str]] = set()
@@ -217,9 +226,24 @@ class PipelineStats:
             self._occ_touched.add((stage, str(lane)))
         _tm_occupancy.labels(stage=stage, lane=str(lane)).set(occupied)
 
-    def note_round(self) -> None:
+    def shard_lanes(self, chips: int) -> None:
+        """Sharded ingest is on: the snapshot also carries the `shard`
+        block and the stages of SHARD_STAGES."""
+        with self._mu:
+            self._lane_events = [0] * chips
+
+    def note_lane_events(self, lane: int, events: int) -> None:
+        with self._mu:
+            self._lane_events[lane] += events
+
+    def note_round(self, fillers: int = 0) -> None:
+        """One dispatched round; `fillers` lanes of it held no batch (a
+        flush closed the round early)."""
         with self._mu:
             self.rounds += 1
+            if fillers:
+                self._rounds_flushed += 1
+                self._filler_lanes += fillers
 
     def note_turn(self, ns: list[int], wall_ns: int, cpu_ns: int,
                   start: float, seq: int) -> None:
@@ -260,6 +284,12 @@ class PipelineStats:
                 row["p99_s"] = max(row["p99_s"], sk.quantile(0.99))
                 row["count"] += sk.total
             ticks = self.starved + self.saturated
+            sharded = self._lane_events is not None
+            shard = {"shard": {
+                "rounds_full": self.rounds - self._rounds_flushed,
+                "rounds_flushed": self._rounds_flushed,
+                "filler_lanes": self._filler_lanes,
+                "lane_events": list(self._lane_events)}} if sharded else {}
             return {
                 "stages": stages,
                 "host_lag_s": stages.get("pop", {}).get("watermark_s", 0.0),
@@ -271,11 +301,13 @@ class PipelineStats:
                 "backpressure": dict(self._backpressure),
                 "occupancy": dict(self._occupancy),
                 "rounds": self.rounds,
+                **shard,
                 "turn": {
                     "turns": self._turns,
                     "wall_s": self._turn_wall_ns * 1e-9,
                     "stages": {n: v * 1e-9 for n, v in
-                               zip(TURN_STAGES, self._turn_ns)},
+                               zip(TURN_STAGES, self._turn_ns)
+                               if sharded or n not in SHARD_STAGES},
                     "harvest_wait_s": self._turn_ns[-1] * 1e-9,
                 },
                 "slow_turns": [
@@ -345,7 +377,10 @@ class TurnClock:
         self._ns = [0] * len(_TURN_SLOTS)
         self._stages = {name: _Stage(self._ns, i, "ig:" + name)
                         for i, name in enumerate(TURN_STAGES)}
-        self._children = [_tm_turn_seconds.labels(stage=name)
+        # a sharding-only stage gets its counter child with its first
+        # nanosecond, so a one-chip run's registry has no such label
+        self._children = [None if name in SHARD_STAGES
+                          else _tm_turn_seconds.labels(stage=name)
                           for name in _TURN_SLOTS]
         self._stats: PipelineStats | None = None
         self._seq = 0
@@ -379,9 +414,13 @@ class TurnClock:
                           time.time())
         ns = self._ns
         self._seq += 1
-        for child, v in zip(self._children, ns):
+        children = self._children
+        for i, v in enumerate(ns):
             if v:
-                child.inc(v * 1e-9)
+                if children[i] is None:
+                    children[i] = _tm_turn_seconds.labels(
+                        stage=_TURN_SLOTS[i])
+                children[i].inc(v * 1e-9)
         _tm_turns.inc()
         if self._stats is not None:
             self._stats.note_turn(ns, now - self._t_pub, cpu - self._cpu_pub,

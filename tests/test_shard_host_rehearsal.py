@@ -1,0 +1,80 @@
+"""The benchmark's four-chip cell rehearsed on the CPU (ISSUE 27):
+`chipbench/run.py --workload exec-host4.saturate --platform cpu` as a
+process of its own, on four virtual devices at the sizes of
+`chipbench/rehearsal.json`. The sound run comes out `correct` with the
+metrics that read what sharding adds to the turn; the same run with the
+exchange between chips left out (`faults.planted("nomerge")`) does not.
+Every name ends in `.cpu_rehearsal`; none is a device number.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+BENCH = ROOT / "chipbench"
+CELL = "exec-host4.saturate"
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
+
+# run.py's main() as it is. A `--trace 1` run reads the per-layer metrics,
+# and a CPU trace has no device plane to reduce, so the reduction alone is
+# stood in for (as chipbench/tests/test_turn_metrics.py does); `fault`
+# names what faults.py plants around the run, if anything
+REHEARSAL = """
+import contextlib, sys
+sys.path[:0] = [{bench!r}, {root!r}]
+import run as harness
+from faults import planted
+harness.trace_reduction.load = lambda path: []
+harness.trace_reduction.reduce_trace = lambda planes, spans, anchor: {{
+    "window_s": 1.0, "busy_s": 0.0, "busiest_busy_s": 0.0, "chips": 4,
+    "programs": {{}}, "collective_s": 0.0, "harvests": 0,
+    "device_ops": [], "idle_gaps": []}}
+fault = {fault!r}
+with planted(fault) if fault else contextlib.nullcontext():
+    sys.exit(harness.main(sys.argv[1:]))
+"""
+
+
+def rehearse(fault: str, trace: int, seed: int) -> dict:
+    out = subprocess.run(
+        [sys.executable, "-c",
+         REHEARSAL.format(bench=str(BENCH), root=str(ROOT), fault=fault),
+         "--workload", CELL, "--seed", str(seed), "--seconds", "3",
+         "--trace", str(trace), "--platform", "cpu"],
+        cwd=ROOT, env=ENV, capture_output=True, text=True, timeout=420)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_the_sound_rehearsal_is_correct_and_reads_what_sharding_adds():
+    line = rehearse("", 1, 2700000041)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 4
+    values = {k: v["value"] for k, v in line["metrics"].items()}
+    assert all(k.endswith(".cpu_rehearsal") for k in values)
+    get = lambda name: values[name + ".cpu_rehearsal"]   # noqa: E731
+    assert get("shard_restage_host_ms_per_batch") > 0.0
+    assert get("shard_merge_host_ms") > 0.0
+    # a harvest every 250 ms flushes what is open: some lanes ride fillers
+    assert 25.0 <= get("shard_round_fill_share") <= 100.0
+    # whole batches dealt round-robin: the busiest lane is a batch or two
+    # ahead of the mean at most
+    assert 1.0 <= get("shard_lane_skew") < 1.25
+    assert 95.0 <= get("turn_accounted_share") <= 100.0
+    # the restage left the window planes' stage; both are still read
+    assert get("window_planes_host_ms_per_batch") > 0.0
+
+
+def test_the_rehearsal_without_the_merge_is_not_correct():
+    line = rehearse("nomerge", 0, 2700000043)
+    assert line["correct"] is False
+    compared = line["compared"]
+    # lane 0 alone holds a quarter of the events: the accounting fails
+    # first, and the heavy hitters are under-counted
+    assert compared["events_gap"]["value"] > 0.5
+    assert compared["hh_under"]["value"] > 0.0
