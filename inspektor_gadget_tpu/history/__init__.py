@@ -47,6 +47,7 @@ from .window import (
     SealedWindow,
     SliceSketch,
     WINDOW_SCHEMA,
+    WindowSlices,
     decode_window,
     encode_window,
     header_overlaps,
@@ -61,8 +62,8 @@ __all__ = [
     "CompactionEngine", "DEFAULT_SCHEDULE", "FilesystemArchive", "HISTORY",
     "HISTORY_METRICS", "HISTORY_SCHEMA", "HistoryStore", "MergedWindows",
     "QueryAnswer", "ScheduleLevel", "SealedWindow", "SliceSketch",
-    "WINDOW_SCHEMA", "answer_query", "decode_frames", "decode_window",
-    "dedupe_compacted", "encode_window", "header_overlaps",
+    "WINDOW_SCHEMA", "WindowSlices", "answer_query", "decode_frames",
+    "decode_window", "dedupe_compacted", "encode_window", "header_overlaps",
     "history_base_dir", "level_counts", "merge_windows", "merged_to_sealed",
     "pack_frames", "parse_schedule", "provenance_row", "unpack_frames",
     "validate_schedule", "validate_store_name", "window_digest",

@@ -17,7 +17,11 @@ One sealed window carries:
   ``kind:<syscall>``, and the ``mntns:<ns>|kind:<k>`` cross product), a
   small host-side HLL + entropy-bucket vector + exact truncated
   heavy-hitter table, so per-pod × per-syscall × time questions answer
-  from sealed state without replaying raw events;
+  from sealed state without replaying raw events. The operator's open
+  window keeps them in ONE grouped array store (`WindowSlices`): state
+  per (mntns, kind) cell, absorbed a batch at a time, the per-mntns and
+  per-kind slices folded out of the cells at the seal. `SliceSketch` is
+  the plain per-slice reference that store is held to;
 - a content digest over the decoded state (arrays hashed by value, wall
   timestamps excluded) — the determinism anchor: replaying the same
   PR-5 capture journal reseals byte-identical digests.
@@ -51,11 +55,33 @@ SLICE_ENT_LOG2_WIDTH = 6   # 64 buckets per slice
 SLICE_HH_K = 32            # exact truncated heavy-hitter table per slice
 
 
+def _slice_hll_lanes(h: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Register index and rank for a slice HLL from the keys' fmix32
+    hashes (numpy twin of ops.hll): the one place this arithmetic lives,
+    so the per-slice reference and the grouped store cannot drift apart."""
+    p = SLICE_HLL_P
+    idx = (h >> np.uint32(32 - p)).astype(np.int64)
+    rest = ((h << np.uint32(p)) | np.uint32((1 << p) - 1)).astype(np.uint32)
+    # rank = leading zeros + 1 = 32 - floor(log2(rest)); rest is never
+    # 0 (low p bits are padded with ones), and float64 is exact below
+    # 2^32, so the vectorized log2 is the exact clz
+    rank = (np.uint32(32) - np.floor(np.log2(
+        rest.astype(np.float64))).astype(np.uint32)).astype(np.uint8)
+    return idx, np.minimum(rank, np.uint8(32 - p + 1))
+
+
+def _slice_ent_lanes(eh: np.ndarray) -> np.ndarray:
+    """Entropy bucket from the fmix32 hashes of the distribution stream."""
+    return (eh >> np.uint32(32 - SLICE_ENT_LOG2_WIDTH)).astype(np.int64)
 
 
 @dataclasses.dataclass
 class SliceSketch:
-    """One subpopulation's per-window state (host-side, numpy-only)."""
+    """One subpopulation's per-window state (host-side, numpy-only):
+    the plain per-slice reference. The operator's open window keeps its
+    slices in `WindowSlices`; this class builds windows for the fleet
+    simulator and the store tests, and is the oracle `WindowSlices` is
+    tested against."""
 
     events: int = 0
     hll: np.ndarray = dataclasses.field(
@@ -68,22 +94,13 @@ class SliceSketch:
     def update(self, hh_keys: np.ndarray, distinct_keys: np.ndarray,
                dist_keys: np.ndarray) -> None:
         self.events += len(hh_keys)
-        # HLL scatter-max over leading-zero ranks (numpy twin of ops.hll)
-        h = _fmix32_np(distinct_keys.astype(np.uint32))
-        p = SLICE_HLL_P
-        idx = (h >> np.uint32(32 - p)).astype(np.int64)
-        rest = ((h << np.uint32(p)) | np.uint32((1 << p) - 1)).astype(np.uint32)
-        # rank = leading zeros + 1 = 32 - floor(log2(rest)); rest is never
-        # 0 (low p bits are padded with ones), and float64 is exact below
-        # 2^32, so the vectorized log2 is the exact clz
-        rank = (np.uint32(32) - np.floor(np.log2(
-            rest.astype(np.float64))).astype(np.uint32)).astype(np.uint8)
-        rank = np.minimum(rank, np.uint8(32 - p + 1))
+        # HLL scatter-max over leading-zero ranks
+        idx, rank = _slice_hll_lanes(
+            _fmix32_np(distinct_keys.astype(np.uint32)))
         np.maximum.at(self.hll, idx, rank)
         # entropy buckets over the distribution stream
-        eh = _fmix32_np(dist_keys.astype(np.uint32))
-        eidx = (eh >> np.uint32(32 - SLICE_ENT_LOG2_WIDTH)).astype(np.int64)
-        np.add.at(self.ent, eidx, 1)
+        np.add.at(self.ent, _slice_ent_lanes(
+            _fmix32_np(dist_keys.astype(np.uint32))), 1)
         # exact heavy-hitter counts (truncated to SLICE_HH_K at seal)
         uniq, counts = np.unique(hh_keys.astype(np.uint32),
                                  return_counts=True)
@@ -93,6 +110,354 @@ class SliceSketch:
 
     def sealed_hh(self) -> list[tuple[int, int]]:
         return sorted(self.hh.items(), key=lambda kv: -kv[1])[:SLICE_HH_K]
+
+
+def _cell_codes(mntns: np.ndarray, kind: np.ndarray
+                ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A batch grouped by its (mntns, kind) cell: the container and the
+    kind of each distinct cell, ascending by container and then by kind,
+    and each event's index into them (what one `np.unique` over the pair
+    gives, with its inverse). Ids that lie close together (containers
+    numbered by one counter, a handful of event kinds) are coded through
+    a table over the pair's span: one pass, where the sorts behind
+    `np.unique` would be the dearest step of a batch (0.35 ms against
+    3-4 ms for 65,536 events on one CPU core)."""
+    lo_ns, lo_kind = mntns.min(), kind.min()
+    kinds = int(kind.max()) - int(lo_kind) + 1
+    span = (int(mntns.max()) - int(lo_ns) + 1) * kinds
+    if span > 4 * len(mntns):
+        ns_vals, ns_i = np.unique(mntns, return_inverse=True)
+        kind_vals, kind_i = np.unique(kind, return_inverse=True)
+        pairs, code = np.unique(ns_i * len(kind_vals) + kind_i,
+                                return_inverse=True)
+        return (ns_vals[pairs // len(kind_vals)],
+                kind_vals[pairs % len(kind_vals)], code)
+    pair = (mntns - lo_ns).astype(np.intp) * kinds
+    pair += kind - lo_kind
+    seen = np.zeros(span, dtype=bool)
+    seen[pair] = True
+    pairs = np.flatnonzero(seen)
+    code = np.empty(span, dtype=np.intp)
+    code[pairs] = np.arange(len(pairs))
+    return ((pairs // kinds).astype(mntns.dtype) + lo_ns,
+            (pairs % kinds).astype(kind.dtype) + lo_kind, code[pair])
+
+
+def _run_starts(a: np.ndarray) -> np.ndarray:
+    """Where each run of equal values starts in a sorted array."""
+    new = np.ones(len(a), dtype=bool)
+    np.not_equal(a[1:], a[:-1], out=new[1:])
+    return np.flatnonzero(new)
+
+
+_KEY32 = np.uint64(0xFFFFFFFF)
+_U32 = np.uint64(32)
+# the heavy-hitter backlog is folded into the merged table once it holds
+# more events than this many times the table's entries (a fold costs a
+# pass over the table, so it waits for work worth the pass), and never
+# for less than _MIN_BACKLOG_EVENTS (2 MiB of words)
+_BACKLOG_PER_ENTRY = 8
+_MIN_BACKLOG_EVENTS = 1 << 18
+
+
+class WindowSlices:
+    """The open window's subpopulation slices as one grouped array store.
+
+    A batch is grouped once by its (mntns, kind) cell and absorbed in one
+    pass: one hash of the distinct lane and one of the distribution lane,
+    one scatter-max into the stacked HLL registers, one scatter-add into
+    the stacked entropy buckets, and one array of (cell, key) words
+    appended to the heavy-hitter backlog. State is kept per CELL only;
+    the `mntns:<ns>` and `kind:<k>` slices are folds of the cells they
+    cover, taken at the seal (max for registers, sum for everything
+    else). That is exact because a slice is admitted at its first
+    appearance or never: once `max_slices` are admitted nothing later in
+    the window is, so an admitted slice holds every event of its
+    subpopulation. A cell keeps state while any slice it feeds is
+    admitted; cells that feed nothing but their `kind:<k>` slice keep it
+    in one row between them, so the state is a row for each cell of an
+    admitted container and one more for each kind, however many
+    containers the window sees.
+
+    The heavy-hitter table is exact: the backlog is sorted and run-length
+    counted into a merged (cell, key) -> (count, first batch) table when
+    it outgrows what the table holds, and at the seal; the top
+    `SLICE_HH_K` of a slice are cut from the merged arrays. No Python
+    object per key exists at any point.
+
+    `seal()` returns what a `dict[str, SliceSketch]` fed by one mask per
+    subpopulation would have sealed, byte for byte: the same key strings
+    in the same admission order (per batch: containers ascending, each
+    `mntns:<ns>` before its `mntns:<ns>|kind:<k>` by kind ascending, then
+    `kind:<k>` ascending), key 0 left out of `hh`, and `hh` ordered by
+    count descending with ties by first appearance (earlier batch first,
+    ascending key within a batch). One store serves one window."""
+
+    def __init__(self, max_slices: int) -> None:
+        self._max = int(max_slices)
+        self.dropped = 0                  # slices over the cap, once each
+        self._keys: list[str] = []        # admitted slices, admission order
+        # decisions, final once made: index into _keys, or -1 (dropped)
+        self._ns: dict[int, int] = {}
+        self._kinds: dict[int, int] = {}
+        # (mntns, kind) -> row of the stacked arrays, -1 for a cell that
+        # feeds no admitted slice; _feeds[row] = (mntns, cross, kind) slices.
+        # A row is a cell of an admitted container, or the one row of its
+        # kind's other cells (_kind_rows): the rows, and with them the open
+        # window's memory, follow the admitted slices
+        self._cells: dict[tuple[int, int], int] = {}
+        self._feeds: list[tuple[int, int, int]] = []
+        self._kind_rows: dict[int, int] = {}
+        rows = 64
+        self._events = np.zeros(rows, dtype=np.int64)
+        self._hll = np.zeros((rows, 1 << SLICE_HLL_P), dtype=np.uint8)
+        self._ent = np.zeros((rows, 1 << SLICE_ENT_LOG2_WIDTH),
+                             dtype=np.int64)
+        # heavy hitters: one uint64 word an event, (row, key, batch
+        # ordinal within the backlog) from the top bit down, so one plain
+        # sort groups a key's events and leaves its first batch in front
+        self._batches = 0
+        self._backlog: list[np.ndarray] = []
+        self._backlog_from = 0            # ordinal of the backlog's first batch
+        self._backlog_events = 0
+        self._hh_keys = np.zeros(0, dtype=np.uint64)    # row << 32 | key, sorted
+        self._hh_counts = np.zeros(0, dtype=np.int64)
+        self._hh_first = np.zeros(0, dtype=np.int64)
+
+    def __len__(self) -> int:
+        return len(self._keys)
+
+    @property
+    def cells(self) -> int:
+        """Rows of state: a cell that feeds an admitted slice has one, and
+        the cells that feed a `kind:<k>` slice alone share one."""
+        return len(self._feeds)
+
+    @property
+    def hh_entries(self) -> int:
+        """(cell, key) entries of the merged heavy-hitter table: all the
+        window's once `seal()` has folded the backlog."""
+        return len(self._hh_keys)
+
+    @property
+    def _ord_bits(self) -> int:
+        # what the rows leave of a word's upper half
+        return 32 - max(len(self._events) - 1, 1).bit_length()
+
+    # -- admission ----------------------------------------------------------
+
+    def _decide(self, key: str) -> int:
+        if len(self._keys) >= self._max:
+            # counted per SLICE, at its one decision: an over-cap
+            # subpopulation recurring in every batch is one dropped slice
+            self.dropped += 1
+            return -1
+        self._keys.append(key)
+        return len(self._keys) - 1
+
+    def _rows_of(self, cells: list[tuple[int, int]]) -> np.ndarray:
+        """Rows of a batch's distinct cells (ascending by container, then
+        kind), admitting what the batch brings for the first time in the
+        order the per-subpopulation loop did."""
+        rows = []
+        fresh = []
+        for ns, k in cells:
+            row = self._cells.get((ns, k))
+            if row is None:
+                if ns not in self._ns:
+                    self._ns[ns] = self._decide(f"mntns:{ns}")
+                fresh.append((len(rows), ns, k,
+                              self._decide(f"mntns:{ns}|kind:{k}")))
+            rows.append(row)
+        for k in sorted({k for _ns, k in cells} - self._kinds.keys()):
+            self._kinds[k] = self._decide(f"kind:{k}")
+        for at, ns, k, cross in fresh:
+            feeds = (self._ns[ns], cross, self._kinds[k])
+            if max(feeds) < 0:
+                row = -1
+            elif max(feeds[:2]) < 0:
+                # cells that feed their `kind:<k>` alone share its row
+                row = self._kind_rows.setdefault(k, len(self._feeds))
+            else:
+                row = len(self._feeds)
+            if row == len(self._feeds):
+                self._feeds.append(feeds)
+            self._cells[(ns, k)] = rows[at] = row
+        if len(self._feeds) > len(self._events):
+            self._grow()
+        return np.array(rows, dtype=np.int64)
+
+    def _grow(self) -> None:
+        # the backlog's words hold rows in the bits of the old capacity
+        self._compact()
+        rows = len(self._events)
+        while rows < len(self._feeds):
+            rows *= 2
+        for name in ("_events", "_hll", "_ent"):
+            old = getattr(self, name)
+            new = np.zeros((rows,) + old.shape[1:], dtype=old.dtype)
+            new[:len(old)] = old
+            setattr(self, name, new)
+
+    # -- the batch ----------------------------------------------------------
+
+    def absorb(self, mntns: np.ndarray, kind: np.ndarray, hh: np.ndarray,
+               distinct: np.ndarray, dist: np.ndarray | None = None) -> None:
+        """Absorb one batch: lanes of equal length, one event each
+        (weights are not the slices' business). Without `dist` the
+        distribution stream is the `distinct` lane, hashed once."""
+        if not len(hh):
+            return
+        ordinal = self._batches
+        self._batches += 1
+        cell_ns, cell_kind, code = _cell_codes(mntns, kind)
+        rows = self._rows_of(list(zip(cell_ns.tolist(), cell_kind.tolist())))
+        row = rows[code]
+        held = rows >= 0
+        if not held.all():
+            # cells that feed no admitted slice keep nothing
+            keep = row >= 0
+            row, hh, distinct = row[keep], hh[keep], distinct[keep]
+            if dist is not None:
+                dist = dist[keep]
+            if not len(row):
+                return
+        np.add.at(self._events, rows[held],
+                  np.bincount(code, minlength=len(rows))[held])
+        h = _fmix32_np(distinct)
+        idx, rank = _slice_hll_lanes(h)
+        np.maximum.at(self._hll.reshape(-1),
+                      row * (1 << SLICE_HLL_P) + idx, rank)
+        eidx = _slice_ent_lanes(h if dist is None else _fmix32_np(dist))
+        np.add.at(self._ent.reshape(-1),
+                  row * (1 << SLICE_ENT_LOG2_WIDTH) + eidx, 1)
+        bits = self._ord_bits
+        if not self._backlog:
+            self._backlog_from = ordinal
+        elif (ordinal - self._backlog_from) >> bits:
+            # no bits left to number another batch of this backlog
+            self._compact()
+            self._backlog_from = ordinal
+        word = (row.astype(np.uint64) << _U32) | hh.astype(np.uint64)
+        word <<= np.uint64(bits)
+        word |= np.uint64(ordinal - self._backlog_from)
+        self._backlog.append(word)
+        self._backlog_events += len(word)
+        if self._backlog_events > max(_BACKLOG_PER_ENTRY * len(self._hh_keys),
+                                      _MIN_BACKLOG_EVENTS):
+            self._compact()
+
+    # -- heavy hitters ------------------------------------------------------
+
+    def _compact(self) -> None:
+        """Fold the backlog into the merged table: a plain sort, run
+        lengths for the counts, the front of each run for the first
+        batch; then counts of keys the table holds are added in place
+        and the others inserted where they belong."""
+        if not self._backlog:
+            return
+        bits = np.uint64(self._ord_bits)
+        words = np.concatenate(self._backlog)
+        self._backlog = []
+        self._backlog_events = 0
+        words.sort()
+        pairs = words >> bits
+        starts = _run_starts(pairs)
+        keys = pairs[starts]
+        counts = np.diff(starts, append=len(words))
+        first = (words[starts] - (keys << bits)).astype(np.int64)
+        first += self._backlog_from
+        unnamed = np.flatnonzero((keys & _KEY32) == 0)
+        if len(unnamed):
+            # key 0 is "no key": at most one entry a row
+            keys, counts, first = (np.delete(keys, unnamed),
+                                   np.delete(counts, unnamed),
+                                   np.delete(first, unnamed))
+        if not len(self._hh_keys):
+            self._hh_keys, self._hh_counts, self._hh_first = (
+                keys, counts, first)
+            return
+        # the table's keys carry tag 0 and the backlog's tag 1 in the low
+        # bit: after one plain sort a backlog key stands right behind the
+        # table's entry of the same key, where there is one, and `before`
+        # counts the table's entries that stand before it (in front of
+        # everything `at - 1` wraps to the largest word: never its twin)
+        tagged = np.concatenate([self._hh_keys << np.uint64(1),
+                                 (keys << np.uint64(1)) | np.uint64(1)])
+        tagged.sort()
+        at = np.flatnonzero(tagged & np.uint64(1))
+        before = at - np.arange(len(at))
+        known = tagged[at] == tagged[at - 1] + np.uint64(1)
+        self._hh_counts[before[known] - 1] += counts[known]
+        if not known.all():
+            fresh = ~known          # the table's `first` is the earlier one
+            self._hh_keys = np.insert(self._hh_keys, before[fresh],
+                                      keys[fresh])
+            self._hh_counts = np.insert(self._hh_counts, before[fresh],
+                                        counts[fresh])
+            self._hh_first = np.insert(self._hh_first, before[fresh],
+                                       first[fresh])
+
+    # -- the seal -----------------------------------------------------------
+
+    def seal(self) -> dict[str, dict]:
+        """The window's slices as `SealedWindow.slices` holds them."""
+        self._compact()
+        n, rows = len(self._keys), len(self._feeds)
+        events = np.zeros(n, dtype=np.int64)
+        hll = np.zeros((n, 1 << SLICE_HLL_P), dtype=np.uint8)
+        ent = np.zeros((n, 1 << SLICE_ENT_LOG2_WIDTH), dtype=np.int64)
+        hh: list[list[tuple[int, int]]] = [[] for _ in range(n)]
+        held_by = (self._hh_keys >> _U32).astype(np.intp)
+        keys = self._hh_keys & _KEY32
+        counts, first = self._hh_counts, self._hh_first
+        # a row's entries stand together: ends[row] .. ends[row + 1]
+        ends = np.searchsorted(held_by, np.arange(rows + 1)).tolist()
+        feeds = np.array(self._feeds, dtype=np.int64).reshape(rows, 3)
+        for to in feeds.T:
+            fed = np.flatnonzero(to >= 0)
+            np.add.at(events, to[fed], self._events[fed])
+            np.maximum.at(hll, to[fed], self._hll[fed])
+            np.add.at(ent, to[fed], self._ent[fed])
+            if np.bincount(to[fed], minlength=1).max() <= 1:
+                # every slice of this kind is one cell
+                for row, i in zip(fed.tolist(), to[fed].tolist()):
+                    a, b = ends[row], ends[row + 1]
+                    hh[i] = _top_hh(keys[a:b], counts[a:b], first[a:b])
+                continue
+            # cells of one slice hold the same keys: add them up first
+            into = to[held_by]
+            pairs = (into.astype(np.uint64) << _U32) | keys
+            order = np.argsort(pairs)
+            if len(fed) < rows:
+                # -1 stands last as an unsigned number
+                order = order[:np.count_nonzero(into >= 0)]
+            pairs = pairs[order]
+            starts = _run_starts(pairs)
+            g_counts = np.add.reduceat(counts[order], starts)
+            g_first = np.minimum.reduceat(first[order], starts)
+            pairs = pairs[starts]
+            g_keys = pairs & _KEY32
+            which = (pairs >> _U32).astype(np.intp)
+            bounds = np.append(_run_starts(which), len(which)).tolist()
+            for a, b in zip(bounds[:-1], bounds[1:]):
+                hh[which[a]] = _top_hh(g_keys[a:b], g_counts[a:b],
+                                       g_first[a:b])
+        return {key: {"events": int(events[i]), "hll": hll[i],
+                      "ent": ent[i], "hh": hh[i]}
+                for i, key in enumerate(self._keys)}
+
+
+def _top_hh(keys: np.ndarray, counts: np.ndarray,
+            first: np.ndarray) -> list[tuple[int, int]]:
+    """The `SLICE_HH_K` largest of one slice's entries, given by ascending
+    key: count descending, ties by first batch, then by key."""
+    over = len(counts) - SLICE_HH_K
+    if over > 0:
+        cand = np.flatnonzero(counts >= np.partition(counts, over)[over])
+        keys, counts, first = keys[cand], counts[cand], first[cand]
+    top = np.lexsort((first, -counts))[:SLICE_HH_K]    # a stable sort
+    return list(zip(keys[top].tolist(), counts[top].tolist()))
 
 
 def slice_hll_estimate(registers: np.ndarray) -> float:
@@ -850,6 +1215,7 @@ def merged_to_sealed(merged: MergedWindows, *, gadget: str, node: str,
 
 __all__ = ["MergedWindows", "SLICE_ENT_LOG2_WIDTH", "SLICE_HH_K",
            "SLICE_HLL_P", "SealedWindow", "SliceSketch", "WINDOW_SCHEMA",
+           "WindowSlices",
            "decode_window", "encode_window", "entropy_bits",
            "header_overlaps", "merge_windows", "merged_to_sealed",
            "provenance_row", "slice_hll_estimate", "window_digest"]

@@ -128,6 +128,11 @@ _tm_shard_lane_events = counter(
     "events of the batches parked on each ingest lane", ("gadget", "lane"))
 _tm_harvests = counter("ig_tpusketch_harvests_total",
                        "harvest ticks", ("gadget",))
+# history on only (labelled, so a history-off run leaves no such name)
+_tm_slice_hh_entries = counter(
+    "ig_history_slice_hh_entries_total",
+    "(cell, key) entries of the slices' exact heavy-hitter table at each "
+    "window seal: what the seal's slice work follows", ("gadget",))
 _tm_h2d = histogram("ig_tpusketch_h2d_seconds",
                     "host→device batch staging (pad/fold + transfer "
                     "dispatch)", ("gadget",))
@@ -197,7 +202,15 @@ def _inv_class_ingest_step(s, keys, weights):
     return out, out.count[0, :1] + 0
 
 
+def _wcms_window_step(w, cand):
+    """What a seal reads of the window CMS, as one program: the ring's
+    current slot and the candidates' estimates against it. Eagerly the
+    hashes and gathers are some ninety dispatches a seal."""
+    return w.slots[w.epoch], wcms_query(w, cand, last_k=1)
+
+
 _wcms_ingest_jit = jax.jit(_wcms_ingest_step, donate_argnums=0)
+_wcms_window_jit = jax.jit(_wcms_window_step)
 _hll_ingest_jit = jax.jit(_hll_ingest_step, donate_argnums=0)
 _inv_class_jit = jax.jit(_inv_class_ingest_step, donate_argnums=0)
 
@@ -448,7 +461,8 @@ class TpuSketch(Operator):
             ParamDesc(key="history-max-slices", default="256",
                       type_hint=TypeHint.INT,
                       description="subpopulation slices tracked per window "
-                                  "(overflow dropped and accounted)"),
+                                  "(admitted at first appearance; overflow "
+                                  "dropped and accounted)"),
             # tiered history lifecycle (history/lifecycle.py +
             # history/archive.py): retention as a POLICY — aged windows
             # compact into coarser super-windows per the resolution
@@ -790,9 +804,11 @@ class TpuSketchInstance(OperatorInstance):
             self._win_ent0 = np.asarray(self.bundle.entropy.counts).copy()
             self._win_inv0 = self._inv_host(self.bundle)
             self._win_qt0 = self._qt_host(self.bundle)
-            self._win_slices: dict[str, Any] = {}
-            self._win_slices_dropped_keys: set[str] = set()
-            from ..history import HISTORY
+            from ..history import HISTORY, WindowSlices
+            self._win_slices = WindowSlices(self._hist_max_slices)
+            self._last_slices: dict[str, int] | None = None
+            self._m_slice_hh = _tm_slice_hh_entries.labels(
+                gadget=ctx.desc.full_name)
             try:
                 self._hist_writer = HISTORY.writer_for(
                     self._hist_gadget, node=ctx.extra.get("node", "") or "",
@@ -1693,34 +1709,17 @@ class TpuSketchInstance(OperatorInstance):
                            dist: np.ndarray) -> None:
         """Hydra-lite subpopulation accumulation for the open window:
         per-mntns (container/pod identity), per-kind (syscall), and the
-        mntns×kind cross product, each a small host sketch. Bounded by
+        mntns×kind cross product. The batch is grouped once by its
+        (mntns, kind) cell and absorbed by the window's one array store
+        (history/window.py WindowSlices); the per-mntns and per-kind
+        slices are folds of the cells, taken at the seal. Bounded by
         history-max-slices; overflow is dropped AND accounted in the
         sealed window's header."""
-        from ..history import SliceSketch
-        mntns = batch.cols["mntns"][:n]
-        kind = batch.cols["kind"][:n]
-        hh_n, distinct_n, dist_n = hh[:n], distinct[:n], dist[:n]
-
-        def feed(key: str, sel: np.ndarray) -> None:
-            s = self._win_slices.get(key)
-            if s is None:
-                if len(self._win_slices) >= self._hist_max_slices:
-                    # count distinct dropped SLICES, not drop attempts —
-                    # one over-cap subpopulation recurring in every
-                    # batch is still one dropped slice
-                    self._win_slices_dropped_keys.add(key)
-                    return
-                s = self._win_slices[key] = SliceSketch()
-            s.update(hh_n[sel], distinct_n[sel], dist_n[sel])
-
-        for ns in np.unique(mntns):
-            sel = mntns == ns
-            feed(f"mntns:{int(ns)}", sel)
-            for k in np.unique(kind[sel]):
-                ksel = sel & (kind == k)
-                feed(f"mntns:{int(ns)}|kind:{int(k)}", ksel)
-        for k in np.unique(kind):
-            feed(f"kind:{int(k)}", kind == k)
+        self._win_slices.absorb(
+            batch.cols["mntns"][:n], batch.cols["kind"][:n], hh[:n],
+            distinct[:n],
+            # one column for both streams is one lane, hashed once
+            None if self.dist_col == self.distinct_col else dist[:n])
 
     def seal_window(self) -> None:
         """Seal the open window into the history store: ONE frame, ONE
@@ -1732,7 +1731,8 @@ class TpuSketchInstance(OperatorInstance):
             self._seal_window()
 
     def _seal_window(self) -> None:
-        from ..history import HISTORY, SealedWindow, window_digest
+        from ..history import (HISTORY, SealedWindow, WindowSlices,
+                               window_digest)
         end = self._hist_clock()
         with self._bundle_mu:
             b = self._merged_locked()
@@ -1747,14 +1747,14 @@ class TpuSketchInstance(OperatorInstance):
             inv_now = self._inv_host(b)
             qt_now = self._qt_host(b)
         win_events = int(events - self._win_events0)
-        if win_events <= 0 and not self._win_slices:
+        if win_events <= 0 and not len(self._win_slices):
             self._win_start = end
             return
         # window-only snapshots: the ring's CURRENT slot is this window's
         # CMS; candidates re-estimated against it give the window top-k
-        cms = np.asarray(self._wcms.slots[self._wcms.epoch])
-        counts = np.asarray(wcms_query(self._wcms, jnp.asarray(cand),
-                                       last_k=1)).astype(np.int64)
+        cms, counts = _wcms_window_jit(self._wcms, jnp.asarray(cand))
+        cms = np.asarray(cms)
+        counts = np.asarray(counts).astype(np.int64)
         order = np.argsort(-counts)
         keep = [(int(cand[i]), int(counts[i])) for i in order
                 if cand[i] != 0 and counts[i] > 0]
@@ -1806,11 +1806,9 @@ class TpuSketchInstance(OperatorInstance):
             ent=(ent_now - self._win_ent0).astype(np.float32),
             topk_keys=np.array([k for k, _ in keep], dtype=np.uint32),
             topk_counts=np.array([c for _, c in keep], dtype=np.int64),
-            slices={key: {"events": s.events, "hll": s.hll, "ent": s.ent,
-                          "hh": s.sealed_hh()}
-                    for key, s in self._win_slices.items()},
+            slices=self._win_slices.seal(),
             names={k: self._names[k] for k, _ in keep if k in self._names},
-            slices_dropped=len(self._win_slices_dropped_keys),
+            slices_dropped=self._win_slices.dropped,
             approx=overflow,
             **inv_kw,
         )
@@ -1880,8 +1878,11 @@ class TpuSketchInstance(OperatorInstance):
         self._win_qt0 = qt_now
         if self._win_shadow is not None:
             self._win_shadow.reset()
-        self._win_slices = {}
-        self._win_slices_dropped_keys = set()
+        self._last_slices = {"slices": len(self._win_slices),
+                             "cells": self._win_slices.cells,
+                             "hh_entries": self._win_slices.hh_entries}
+        self._m_slice_hh.inc(self._win_slices.hh_entries)
+        self._win_slices = WindowSlices(self._hist_max_slices)
 
     # harvest ---------------------------------------------------------------
 
@@ -2014,6 +2015,9 @@ class TpuSketchInstance(OperatorInstance):
         # through the ambient harvest context)
         pipe_out = self._pstats.snapshot()
         pipe_out["update_arm"] = self._arm
+        if self._hist_on and self._last_slices is not None:
+            # what the last sealed window's slice store held
+            pipe_out["slices"] = dict(self._last_slices)
         for stage, row in pipe_out["stages"].items():
             with self._span(f"tpusketch/stage/{stage}",
                             watermark_s=row["watermark_s"],

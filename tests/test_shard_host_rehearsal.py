@@ -62,10 +62,20 @@ def test_the_sound_rehearsal_is_correct_and_reads_what_sharding_adds():
     assert get("shard_merge_host_ms") > 0.0
     # a harvest every 250 ms flushes what is open: some lanes ride fillers
     assert 25.0 <= get("shard_round_fill_share") <= 100.0
-    # whole batches dealt round-robin: the busiest lane is a batch or two
-    # ahead of the mean at most
-    assert 1.0 <= get("shard_lane_skew") < 1.25
-    assert 95.0 <= get("turn_accounted_share") <= 100.0
+    # Whole batches dealt round-robin. While every batch was full the
+    # busiest lane was a batch or two ahead of the mean (1.00-1.01). Since
+    # ISSUE 28 the rehearsed loop outruns its source (240-370 turns a
+    # second on half-full batches of what the ring holds), and the lane
+    # whose pop follows the round's longest turn takes the largest
+    # batches: 1.06-1.28 read here; 1.0 on the chip's host, where the
+    # batches are still full
+    assert 1.0 <= get("shard_lane_skew") < 1.6
+    # What no stage covers is a fixed 0.17-0.23 ms a turn (0.2-0.3 on a
+    # busy host). This line held it to 5% of a 17-20 ms turn; a turn is
+    # 3-5 ms since ISSUE 28, so it holds the millisecond that 5% was
+    share = get("turn_accounted_share")
+    assert share <= 100.0
+    assert get("turn_host_ms_per_batch") * (100.0 - share) / 100.0 < 1.0
     # the restage left the window planes' stage; both are still read
     assert get("window_planes_host_ms_per_batch") > 0.0
 

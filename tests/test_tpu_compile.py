@@ -148,6 +148,20 @@ def test_bundle_digest_compiles(one_chip):
     jax.jit(bundle_digest).lower(bundle).compile()
 
 
+def test_seal_window_read_compiles_as_one_program(one_chip):
+    """What a seal reads of the window CMS (the current slot and the
+    candidates' estimates): one program, at the history plane's defaults."""
+    from inspektor_gadget_tpu.operators.tpusketch import _wcms_window_step
+    from inspektor_gadget_tpu.ops.window import wcms_init
+    wcms = _on(one_chip, jax.eval_shape(
+        lambda: wcms_init(n_slots=8, depth=4, log2_width=12)))
+    cand = jax.ShapeDtypeStruct((GEOMETRY["k"],), jnp.uint32,
+                                sharding=one_chip)
+    table, counts = jax.eval_shape(_wcms_window_step, wcms, cand)
+    assert table.shape == (4, 1 << 12) and counts.shape == (GEOMETRY["k"],)
+    jax.jit(_wcms_window_step).lower(wcms, cand).compile()
+
+
 def test_sharded_harvest_compiles_with_its_collectives(topo):
     """The collective harvest over a 4-chip (node) mesh: psum/pmax for the
     additive planes and registers, all-gather for the candidate union."""

@@ -63,6 +63,13 @@ class RecordingAnnotation:
                          time.perf_counter_ns()))
 
 
+def loop_annotations(run) -> list[tuple[int, int, str]]:
+    """The loop thread's `ig:` annotations in time order: open, close,
+    name."""
+    return sorted((a, b, n) for n, th, a, b in run["annotations"]
+                  if th == run["thread"] and n.startswith("ig:"))
+
+
 def run_once(batches: int, history_dir: str):
     """One local `trace exec` run with the native synthetic source and
     history on, cancelled after `batches` batches. Returns the teardown
@@ -128,23 +135,48 @@ def test_stages_tile_the_turn(recorded_run):
         assert turn["stages"][name] > 0.0, name
     assert turn["stages"]["tpusketch_inv_classes"] == 0.0
     assert 0.0 < turn["harvest_wait_s"] < turn["stages"]["tpusketch_harvest"]
-    assert sum(turn["stages"].values()) >= 0.95 * turn["wall_s"]
-    assert sum(turn["stages"].values()) <= turn["wall_s"]
     assert turn["turns"] == recorded_run["steps"] == recorded_run["batches"]
+    # a stage's two clock reads stand right around its annotation (26-55
+    # us a turn apart over the fourteen of them, on a busy host too), and
+    # the turns' wall is the loop's span from its first stage to its last
+    # publication. With the annotations' own cover of that span, held in
+    # the next test, this is "the stages tile the turn". It was `stages
+    # >= 0.95 * wall` while the slice loop made a turn 13 ms long; since
+    # ISSUE 28 the glue between the stages is 4% and more of a 4-5 ms turn
+    stages = sum(turn["stages"].values())
+    loop = loop_annotations(recorded_run)
+    last = max(b for _a, b, n in loop if n == "ig:runtime_deliver")
+    covered = sum(b - a for a, b, _n in loop if b <= last) * 1e-9
+    assert covered <= stages <= covered + 100e-6 * turn["turns"]
+    assert stages <= turn["wall_s"] <= (last - loop[0][0]) * 1e-9 + 0.01
 
 
 def test_annotations_are_siblings_that_cover_the_loop(recorded_run):
-    loop = sorted((a, b, n) for n, th, a, b in recorded_run["annotations"]
-                  if th == recorded_run["thread"] and n.startswith("ig:"))
-    assert {n for _a, _b, n in loop} == {"ig:" + s for s in TIMED}
+    loop = loop_annotations(recorded_run)
+    # a loop that keeps up with its source also waits for it now and then
+    assert ({n for _a, _b, n in loop} - {"ig:source_wait"}
+            == {"ig:" + s for s in TIMED})
     # every turn ends in runtime_deliver (the tap): first to last turn
     ends = [b for _a, b, n in loop if n == "ig:runtime_deliver"]
     inside = [(a, b, n) for a, b, n in loop if ends[0] <= a and b <= ends[-1]]
-    uncovered = 0
+    per_turn = [0]          # time between stages, turn by turn
     for (_a0, b0, n0), (a1, _b1, n1) in zip(inside, inside[1:]):
         assert a1 >= b0, f"{n1} opened inside {n0}"
-        uncovered += a1 - b0
-    assert uncovered <= 0.05 * (ends[-1] - ends[0])
+        if n0 == "ig:runtime_deliver":
+            per_turn.append(0)
+        per_turn[-1] += a1 - b0
+    # What lies between two stages is the loop's own glue, a fixed cost a
+    # turn whatever the stages take (0.16-0.21 ms). It was held to 5% of
+    # the loop's span while the slice loop made a turn 13 ms long, which
+    # is 0.65 ms a turn; a turn is 4-5 ms since ISSUE 28. On a busy host
+    # (seven of eight cores kept busy) a tenth of the turns also wait 3-8
+    # ms where the turn is published, and the whole reads 0.33-0.8 ms a
+    # turn; the other nine tenths read 0.18-0.26 ms a turn whatever the
+    # host runs, and are held to 0.5 ms, so a stage of 0.3 ms left
+    # without a name is seen unless it comes in fewer than a tenth of
+    # the turns.
+    kept = sorted(per_turn)[:len(per_turn) * 9 // 10]
+    assert sum(kept) <= 500_000 * len(kept)
 
 
 def test_nothing_per_stage_reaches_the_tracer_ring(recorded_run):
