@@ -26,7 +26,6 @@ from .manager import RECORDINGS, Recording, RecordingManager
 from .replay import (
     ReplayClock,
     ReplayResult,
-    ReplaySource,
     iter_journals,
     replay_journal,
 )
@@ -34,7 +33,6 @@ from .replay import (
 __all__ = [
     "JOURNAL_SCHEMA", "JournalReader", "JournalWriter", "RECORDINGS",
     "Recording", "RecordingManager", "ReplayClock", "ReplayResult",
-    "ReplaySource", "SegmentLoss", "build_manifest", "capture_base_dir",
-    "is_journal", "iter_journals", "replay_journal", "summary_digest",
-    "summary_to_dict",
+    "SegmentLoss", "build_manifest", "capture_base_dir", "is_journal",
+    "iter_journals", "replay_journal", "summary_digest", "summary_to_dict",
 ]

@@ -50,70 +50,6 @@ class ReplayClock:
         return self._now
 
 
-class ReplaySource:
-    """Source-interface adapter over a journal's recorded batches — what
-    `bench run --replay` feeds the perf harness so stage numbers are
-    reproducible input-for-input. Batches are decoded once up front;
-    generate()/pop() hands them out in recorded order (cycling when
-    `cycle`, the harness mode: a fixed input sequence per pass)."""
-
-    def __init__(self, journal: "str | JournalReader", *, cycle: bool = False):
-        reader = (journal if isinstance(journal, JournalReader)
-                  else JournalReader(journal))
-        self.reader = reader
-        self.batches = [wire.decode_batch(payload)
-                        for header, payload in reader.records(
-                            types=(wire.EV_BATCH_NPZ,))]
-        self.digest = reader.digest()
-        self.cycle = cycle
-        self._i = 0
-        self._seq = 0
-
-    def __len__(self) -> int:
-        return len(self.batches)
-
-    def start(self) -> None:  # interface parity
-        pass
-
-    def stop(self) -> None:
-        pass
-
-    def close(self) -> None:
-        pass
-
-    def generate(self, n: int | None = None):
-        if not self.batches:
-            raise ValueError(f"{self.reader.path}: journal carries no "
-                             "EV_BATCH_NPZ records to replay")
-        if self._i >= len(self.batches):
-            if not self.cycle:
-                from ..sources.batch import EventBatch
-                return EventBatch.alloc(0, with_comm=False)
-            self._i = 0
-        b = self.batches[self._i]
-        self._i += 1
-        b.seq = self._seq
-        self._seq += b.count
-        return b
-
-    pop = generate
-
-    def reset(self) -> None:
-        """Rewind to the first recorded batch (the harness warms up on
-        recorded data, then measures the sequence from the start)."""
-        self._i = 0
-        self._seq = 0
-
-    def exhausted(self) -> bool:
-        return not self.cycle and self._i >= len(self.batches)
-
-    def drops(self) -> int:
-        return 0
-
-    def vocab_lookup(self, key_hash: int) -> str:
-        return ""
-
-
 @dataclasses.dataclass
 class ReplayResult:
     journal: str
@@ -361,5 +297,5 @@ def iter_journals(path: str) -> Iterator[str]:
                     yield jpath
 
 
-__all__ = ["ReplayClock", "ReplayResult", "ReplaySource", "iter_journals",
+__all__ = ["ReplayClock", "ReplayResult", "iter_journals",
            "replay_journal"]

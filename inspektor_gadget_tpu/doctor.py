@@ -23,7 +23,7 @@ import socket
 from .telemetry import gauge
 
 # probed platform facts as registry gauges: scrapes and embedded snapshots
-# (bench.py, cmd_doctor --output json) carry degraded/unavailable windows
+# (cmd_doctor --output json) carry degraded/unavailable windows
 # as data, not hand-assembled prose
 _tm_window_ok = gauge("ig_doctor_window_ok",
                       "capture window probe result (1 ok, 0 down)",

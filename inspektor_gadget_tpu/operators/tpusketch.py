@@ -174,13 +174,12 @@ _ckpt_log = get_logger("ig-tpu.tpusketch")
 _wcms_advance_jit = jax.jit(wcms_advance, donate_argnums=0)
 
 
-# The fused ingest step (ISSUE 10 tentpole) is the SHARED
-# ops.sketches.bundle_ingest_jit: staged uint32 weights pass through as
-# integer per-event weights (pad slots 0; pre-aggregated runs may weigh
-# > 1), the fused-vs-reference selection happens inside
+# The ingest step is ops.sketches.bundle_ingest_jit: staged uint32 weights
+# pass through as integer per-event weights (pad slots 0; pre-aggregated
+# runs may weigh > 1), the fused-vs-scatter selection happens inside
 # bundle_update_fused at trace time, and the second output is the fence
 # token the stager blocks on (the donation/fence contract is documented
-# ONCE, on bundle_ingest_step).
+# on bundle_ingest_step).
 _ingest_jit = bundle_ingest_jit
 
 
@@ -1288,20 +1287,28 @@ class TpuSketchInstance(OperatorInstance):
                 "lane_devices": lane_devices, "state_shards": state_shards,
                 "staged": staged, "harvest": harvest, "step": (step, args)}
 
+    def _staging(self, pad: int) -> tuple[PinnedBufferPool, H2DStager]:
+        """Pool and stager of the batch about to land (its lane's under
+        shard-ingest)."""
+        return (self._lane_staging(pad) if self._shard_on
+                else self._staging_for(pad))
+
     def enrich_batch(self, batch: EventBatch) -> None:
+        """The fold adapter: an EventBatch's key columns folded into the
+        lanes of a pinned block and staged, then `_absorb_staged`; the
+        slices, the label sample and the anomaly distributions, which
+        only an EventBatch's columns can give, ride along as its hooks."""
         if not self.enabled or batch.count == 0:
             return
         n = batch.count
         pad = self._pad
         while pad < n:
             pad *= 2
-        lane = self._next_lane if self._shard_on else 0
 
         t0 = time.perf_counter()
         with self._span("tpusketch/h2d", events=n, pad=pad):
             with self._st_fold:
-                pool, stager = (self._lane_staging(pad) if self._shard_on
-                                else self._staging_for(pad))
+                pool, stager = self._staging(pad)
                 block = pool.get()
                 lanes: dict[str, np.ndarray] = {}
 
@@ -1326,140 +1333,44 @@ class TpuSketchInstance(OperatorInstance):
                 w[n:] = 0
                 vals = (self._qt_value_lane(batch, block, n)
                         if self._qt_on else None)
-                new_drops = batch.drops - self._drops_seen
-                self._drops_seen = batch.drops
+                mntns = (self._padded_mntns(batch, n, pad)
+                         if self._inv_classes else None)
+                # pipeline watermarks: prefer the batch's stamped fields; an
+                # unstamped batch with a real ts column recovers the oldest
+                # event from it (one vectorized min)
+                oldest = batch.oldest_ts
+                if oldest <= 0.0:
+                    tmin = float(batch.cols["ts"][:n].min())
+                    if tmin > 0.0:
+                        oldest = tmin / 1e9
             # ONE async device put per distinct lane (shared columns stage
             # once); the transfer of this batch overlaps device compute of
             # the previous one — the block returns to the pool only after
-            # the consumer fence below completes
+            # the consumer fence completes
             with self._st_h2d:
-                uniq = list(lanes.values())
-                staged = stager.stage(
-                    block, uniq + [w] + ([vals] if vals is not None else []))
-                staged_slot = stager.last_slot
+                puts = stager.stage(
+                    block, list(lanes.values()) + [w]
+                    + ([vals] if vals is not None else []))
                 nk = len(lanes)
-                by_col = dict(zip(lanes.keys(), staged[:nk]))
-                hh_d = by_col[self.hh_col]
-                distinct_d = by_col[self.distinct_col]
-                dist_d = by_col[self.dist_col]
-                w_d = staged[nk]
-                v_d = staged[nk + 1] if vals is not None else None
-        t1 = time.perf_counter()
-        # the stages below are siblings: `ig:tpusketch_update` covers the
-        # step's dispatch only, so an idle gap under the window planes or
-        # the slices is named after them and not after the update
-        with self._span("tpusketch/update", events=n):
-            if self._shard_on:
-                window_tokens = []
-                if self._hist_on:
-                    # the window plane stays single-chip: the staged
-                    # arrays live on this batch's lane chip, so the
-                    # WindowedCMS/HLL steps restage the HOST lane views
-                    # on the default device; their tokens join the lane's
-                    # round fence because on CPU PJRT these asarrays may
-                    # alias the pinned block
-                    with self._st_restage:
-                        hh_w, distinct_w, w_w = (
-                            jnp.asarray(hh), jnp.asarray(distinct),
-                            jnp.asarray(w))
-                    with self._st_planes:
-                        self._wcms, wtok = _wcms_ingest_jit(
-                            self._wcms, hh_w, w_w.astype(jnp.int32))
-                        self._win_hll, htok = _hll_ingest_jit(
-                            self._win_hll, distinct_w, w_w > 0)
-                    with self._st_slices:
-                        self._accumulate_slices(batch, n, hh, distinct, dist)
-                    window_tokens = [wtok, htok]
-                if self._inv_classes:
-                    with self._st_inv, self._bundle_mu:
-                        window_tokens += self._inv_class_absorb(
-                            hh, self._padded_mntns(batch, n, len(hh)), w)
-                with self._st_update, self._bundle_mu:
-                    self._shard_absorb_locked(
-                        hh_d, distinct_d, dist_d, w_d,
-                        float(max(new_drops, 0)), window_tokens,
-                        staged_slot, n, values_d=v_d)
-            else:
-                with self._st_update, self._bundle_mu:
-                    if self._qt_on:
-                        self.bundle, tok = _ingest_jit(
-                            self.bundle, hh_d, distinct_d, dist_d, w_d,
-                            jnp.float32(max(new_drops, 0)), v_d,
-                        )
-                    else:
-                        self.bundle, tok = _ingest_jit(
-                            self.bundle, hh_d, distinct_d, dist_d, w_d,
-                            jnp.float32(max(new_drops, 0)),
-                        )
-                fence = [tok]
-                if self._hist_on:
-                    # window-plane device steps ride the same staged
-                    # arrays: the WindowedCMS current slot and the
-                    # per-window HLL absorb the batch so a seal reads
-                    # window-only state
-                    with self._st_planes:
-                        self._wcms, wtok = _wcms_ingest_jit(
-                            self._wcms, hh_d, w_d.astype(jnp.int32))
-                        self._win_hll, htok = _hll_ingest_jit(
-                            self._win_hll, distinct_d, w_d > 0)
-                    with self._st_slices:
-                        self._accumulate_slices(batch, n, hh, distinct, dist)
-                    fence += [wtok, htok]
-                if self._inv_classes:
-                    # the keys are already staged on the device (hh_d):
-                    # reuse them instead of re-uploading the host lane —
-                    # only per-class WEIGHT vectors need a transfer.
-                    # Under _bundle_mu: _inv_class_jit donates, and the
-                    # checkpointer thread snapshots class state under
-                    # the same lock
-                    with self._st_inv, self._bundle_mu:
-                        fence += self._inv_class_absorb(
-                            hh_d, self._padded_mntns(batch, n, len(hh)), w)
-                # every consumer of the staged arrays is in the fence: the
-                # pinned block is reused only once they all completed (on
-                # CPU PJRT the device arrays may alias the host block, so
-                # transfer-complete alone is not enough)
-                with self._st_post:
-                    stager.fence(tuple(fence))
-        t2 = time.perf_counter()
-        with self._st_post:
-            self._m_h2d.observe(t1 - t0)
-            self._m_update.observe(t2 - t1)
-            self._m_events.inc(n)
-            self._m_steps.inc()
-            self._m_arm_steps.inc()
-            self._qt_count(vals, n)
-            if new_drops > 0:
-                self._m_drops.inc(new_drops)
-            self._stats.steps += 1
-            self._stats.events += n
-            self._stats.drops = batch.drops
-            # pipeline watermarks: prefer the batch's stamped fields; an
-            # unstamped batch with a real ts column recovers the oldest
-            # event from it (one vectorized min)
-            oldest = batch.oldest_ts
-            if oldest <= 0.0:
-                tmin = float(batch.cols["ts"][:n].min())
-                if tmin > 0.0:
-                    oldest = tmin / 1e9
-            self._note_watermarks(batch.pop_ts, oldest, lane)
-            # accuracy audit plane: the heavy-hitter key lane's real rows
-            # feed the shadow sample host-side (weight 1 per event, matching
-            # the staged weight lane)
-            self._shadow_feed(hh[:n])
+                by_col = dict(zip(lanes, puts))
+                staged = (by_col[self.hh_col], by_col[self.distinct_col],
+                          by_col[self.dist_col], puts[nk],
+                          puts[nk + 1] if vals is not None else None)
+
+        def late() -> None:
             # late enrichment (display-only work off the ingest path): two
             # vectorized slice writes park a small (k64, k32, comm) sample in
             # the rolling ring; name resolution happens at harvest/seal time
             self._label_sample(batch, hh, n)
             if self.anomaly_on:
                 self._accumulate_container_dists(batch, n)
-        if self._hist_on and self._hist_interval > 0 and \
-                self._hist_clock() - self._win_start >= self._hist_interval:
-            self.seal_window()
-        now = time.monotonic()
-        if now - self._last_harvest >= self.harvest_interval:
-            self._last_harvest = now
-            self.harvest()
+
+        self._absorb_staged(
+            stager, staged, (hh, distinct, w), n, t0, drops=batch.drops,
+            pop_ts=batch.pop_ts, oldest_ts=oldest, mntns=mntns, vals=vals,
+            slices=lambda: self._accumulate_slices(batch, n, hh, distinct,
+                                                   dist),
+            late=late)
 
     def ingest_folded(self, fb: FoldedBatch) -> None:
         """Zero-copy ingest of a pre-folded SoA batch (ig_source_pop_folded
@@ -1476,88 +1387,84 @@ class TpuSketchInstance(OperatorInstance):
         if not self.enabled or fb.count == 0:
             return
         n = fb.count
-        lane = self._next_lane if self._shard_on else 0
         t0 = time.perf_counter()
-        # the same sibling stages as enrich_batch, minus fold and slices
         with self._span("tpusketch/h2d", events=n, pad=fb.capacity), \
                 self._st_h2d:
-            _pool, stager = (self._lane_staging(fb.capacity)
-                             if self._shard_on
-                             else self._staging_for(fb.capacity))
-            fvals = fb.values if self._qt_on else None
+            _pool, stager = self._staging(fb.capacity)
+            # pop_folded2 filled row 3 with per-event magnitudes: the
+            # value lane stages with the keys/weights in the same pinned
+            # block (one more view, zero extra copies)
+            keys, weights = fb.keys, fb.weights
+            vals = fb.values if self._qt_on else None
+            host = (keys, weights) + (() if vals is None else (vals,))
             if n < fb.capacity:
-                fb.keys[n:] = 0
-                fb.weights[n:] = 0
-                if fvals is not None:
-                    fvals[n:] = 0
-            new_drops = fb.drops - self._drops_seen
-            self._drops_seen = fb.drops
-            if fvals is not None:
-                # pop_folded2 filled row 3 with per-event magnitudes:
-                # the value lane stages with the keys/weights in the
-                # same pinned block (one more view, zero extra copies)
-                k_d, w_d, v_d = stager.stage(
-                    fb.lanes, (fb.keys, fb.weights, fvals))
-            else:
-                k_d, w_d = stager.stage(fb.lanes, (fb.keys, fb.weights))
-                v_d = None
-            staged_slot = stager.last_slot
+                for lane in host:
+                    lane[n:] = 0
+            k_d, w_d, *v_d = stager.stage(fb.lanes, host)
+        self._absorb_staged(
+            stager, (k_d, k_d, k_d, w_d, v_d[0] if v_d else None),
+            (keys, keys, weights), n, t0, drops=fb.drops, pop_ts=fb.pop_ts,
+            oldest_ts=fb.oldest_ts, mntns=fb.mntns, vals=vals)
+
+    def _absorb_staged(self, stager: H2DStager, staged: tuple, host: tuple,
+                       n: int, t0: float, *, drops: int, pop_ts: float,
+                       oldest_ts: float, mntns: np.ndarray | None,
+                       vals: np.ndarray | None, slices=None,
+                       late=None) -> None:
+        """The one dispatch of a staged batch of `n` events, whichever
+        adapter staged it (at `t0`): `staged` is the device arrays (hh,
+        distinct, dist, weights, values or None) of the stager's last
+        slot, `host` the pinned lanes (hh, distinct, weights) they were
+        put from, `drops` the source's cumulative count. On one chip the
+        update is dispatched first and the window planes and class
+        sketches ride the same staged arrays; under shard-ingest the
+        staged arrays live on the batch's lane chip while those planes
+        stay on chip 0, so they take the host lanes and the batch is
+        parked on its lane after them, their tokens joining the round's
+        fence. The stages are siblings: `ig:tpusketch_update` covers the
+        step's dispatch only, so an idle gap under the window planes or
+        the slices is named after them and not after the update."""
+        hh_d, distinct_d, dist_d, w_d, v_d = staged
+        hh, distinct, w = host
+        sharded = self._shard_on
+        slot = stager.last_slot
+        lane = self._next_lane if sharded else 0
+        new_drops = max(drops - self._drops_seen, 0)
+        self._drops_seen = drops
         t1 = time.perf_counter()
         with self._span("tpusketch/update", events=n):
-            if self._shard_on:
-                window_tokens = []
-                if self._hist_on:
-                    # single-chip window plane, restaged host views (see
-                    # enrich_batch) — sealed windows stay correct under
-                    # sharding, still minus slices on the folded path
-                    with self._st_restage:
-                        keys_w, w_w = (jnp.asarray(fb.keys),
-                                       jnp.asarray(fb.weights))
-                    with self._st_planes:
-                        self._wcms, wtok = _wcms_ingest_jit(
-                            self._wcms, keys_w, w_w.astype(jnp.int32))
-                        self._win_hll, htok = _hll_ingest_jit(
-                            self._win_hll, keys_w, w_w > 0)
-                    window_tokens = [wtok, htok]
-                if self._inv_classes:
-                    with self._st_inv, self._bundle_mu:
-                        window_tokens += self._inv_class_absorb(
-                            fb.keys, fb.mntns, fb.weights)
+            fence = []
+            if not sharded:
+                with self._st_update, self._bundle_mu:
+                    # the value lane is an argument only under the
+                    # quantile plane (None from a folded source without
+                    # one: the step zero-fills, every event lands in the
+                    # zero bucket, totals honest)
+                    self.bundle, tok = _ingest_jit(
+                        self.bundle, hh_d, distinct_d, dist_d, w_d,
+                        jnp.float32(new_drops),
+                        *((v_d,) if self._qt_on else ()))
+                fence.append(tok)
+            fence += (self._window_planes(hh, distinct, w, slices) if sharded
+                      else self._window_planes(hh_d, distinct_d, w_d, slices))
+            if self._inv_classes:
+                # the staged keys are reused on one chip (only the
+                # per-class WEIGHT vectors need a transfer). Under
+                # _bundle_mu: _inv_class_jit donates, and the checkpointer
+                # thread snapshots class state under the same lock
+                with self._st_inv, self._bundle_mu:
+                    fence += self._inv_class_absorb(
+                        hh if sharded else hh_d, mntns, w)
+            if sharded:
                 with self._st_update, self._bundle_mu:
                     self._shard_absorb_locked(
-                        k_d, k_d, k_d, w_d, float(max(new_drops, 0)),
-                        window_tokens, staged_slot, n, values_d=v_d)
+                        hh_d, distinct_d, dist_d, w_d, float(new_drops),
+                        fence, slot, n, values_d=v_d)
             else:
-                with self._st_update, self._bundle_mu:
-                    if self._qt_on:
-                        # v_d may be None (folded source with no value
-                        # lane): the ingest step zero-fills — every
-                        # event lands in the zero bucket, totals honest
-                        self.bundle, tok = _ingest_jit(
-                            self.bundle, k_d, k_d, k_d, w_d,
-                            jnp.float32(max(new_drops, 0)), v_d)
-                    else:
-                        self.bundle, tok = _ingest_jit(
-                            self.bundle, k_d, k_d, k_d, w_d,
-                            jnp.float32(max(new_drops, 0)))
-                fence = [tok]
-                if self._hist_on:
-                    # same window-plane steps as enrich_batch: the
-                    # WindowedCMS current slot and per-window HLL absorb
-                    # the staged batch so interval seals read correct
-                    # window-only state (minus slices — see the docstring)
-                    with self._st_planes:
-                        self._wcms, wtok = _wcms_ingest_jit(
-                            self._wcms, k_d, w_d.astype(jnp.int32))
-                        self._win_hll, htok = _hll_ingest_jit(
-                            self._win_hll, k_d, w_d > 0)
-                    fence += [wtok, htok]
-                if self._inv_classes:
-                    # staged keys (k_d) reused — see enrich_batch; under
-                    # _bundle_mu for the checkpointer snapshot
-                    with self._st_inv, self._bundle_mu:
-                        fence += self._inv_class_absorb(k_d, fb.mntns,
-                                                        fb.weights)
+                # every consumer of the staged arrays is in the fence: the
+                # pinned block is reused only once they all completed (on
+                # CPU PJRT the device arrays may alias the host block, so
+                # transfer-complete alone is not enough)
                 with self._st_post:
                     stager.fence(tuple(fence))
         t2 = time.perf_counter()
@@ -1567,16 +1474,18 @@ class TpuSketchInstance(OperatorInstance):
             self._m_events.inc(n)
             self._m_steps.inc()
             self._m_arm_steps.inc()
-            self._qt_count(fvals, n)
-            if new_drops > 0:
+            self._qt_count(vals, n)
+            if new_drops:
                 self._m_drops.inc(new_drops)
             self._stats.steps += 1
             self._stats.events += n
-            self._stats.drops = fb.drops
-            self._note_watermarks(fb.pop_ts, fb.oldest_ts, lane)
-            # accuracy audit plane: folded batches carry real integer
-            # weights — the shadow's ground-truth totals honor them
-            self._shadow_feed(fb.keys[:n], fb.weights[:n])
+            self._stats.drops = drops
+            self._note_watermarks(pop_ts, oldest_ts, lane)
+            # accuracy audit plane: the heavy-hitter lane's real rows feed
+            # the shadow sample host-side, at the staged lane's weights
+            self._shadow_feed(hh[:n], w[:n])
+            if late is not None:
+                late()
         if self._hist_on and self._hist_interval > 0 and \
                 self._hist_clock() - self._win_start >= self._hist_interval:
             self.seal_window()
@@ -1584,6 +1493,30 @@ class TpuSketchInstance(OperatorInstance):
         if now - self._last_harvest >= self.harvest_interval:
             self._last_harvest = now
             self.harvest()
+
+    def _window_planes(self, hh, distinct, w, slices) -> list:
+        """The history window's share of a batch: the WindowedCMS's
+        current slot and the per-window HLL absorb it, so a seal reads
+        window-only state, then the adapter's `slices`. Fed the staged
+        arrays on one chip; under shard-ingest the host lanes, each put
+        once on the default device. Returns the steps' fence tokens (on
+        CPU PJRT the restaged arrays may alias the pinned block)."""
+        if not self._hist_on:
+            return []
+        if self._shard_on:
+            with self._st_restage:
+                hh_w = jnp.asarray(hh)
+                distinct = hh_w if distinct is hh else jnp.asarray(distinct)
+                hh, w = hh_w, jnp.asarray(w)
+        with self._st_planes:
+            self._wcms, wtok = _wcms_ingest_jit(
+                self._wcms, hh, w.astype(jnp.int32))
+            self._win_hll, htok = _hll_ingest_jit(
+                self._win_hll, distinct, w > 0)
+        if slices is not None:
+            with self._st_slices:
+                slices()
+        return [wtok, htok]
 
     def folded_block(self) -> np.ndarray:
         """A pinned (4+, pad) staging block for pop_folded (rows 0..2 are
@@ -1593,11 +1526,7 @@ class TpuSketchInstance(OperatorInstance):
         shard-ingest the block comes from the pool of the lane the next
         ingest_folded will land on, so it recycles through that lane's
         ring."""
-        if self._shard_on:
-            pool, _ = self._lane_staging(self._pad)
-        else:
-            pool, _ = self._staging_for(self._pad)
-        return pool.get()
+        return self._staging(self._pad)[0].get()
 
     # -- late enrichment (off the ingest path) ------------------------------
 

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the sketch hot path.
 
-Design note (measured, see bench.py): the wide count-min table (W=65536)
+Design note: the wide count-min table (W=65536)
 ingests fastest through XLA's native scatter-add — the sort/segment
 machinery XLA emits for scatter is already near memory-bound. Where Pallas
 wins is the *narrow* histogram planes (entropy sketch W≤4096, autoencoder

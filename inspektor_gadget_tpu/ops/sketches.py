@@ -315,8 +315,8 @@ def bundle_ingest_step(
     drops: jnp.ndarray | None = None,
     values: jnp.ndarray | None = None,
 ) -> tuple[SketchBundle, jnp.ndarray]:
-    """THE staged-ingest step every hot path shares (tpusketch, bench.py,
-    perf harness) — two contracts live here, once:
+    """The staged-ingest step the tpusketch operator dispatches — two
+    contracts live here, once:
 
     - `weights` is the FoldedBatch weights lane as integer per-event
       weights: pad slots weigh 0, and a capture shim that pre-aggregates

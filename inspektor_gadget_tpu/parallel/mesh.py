@@ -50,8 +50,8 @@ def make_mesh(n_nodes: int | None = None, n_model: int = 1,
 def ingest_mesh(chips: int, devices=None) -> Mesh:
     """The (node)-only mesh the sharded ingest plane runs on (ISSUE 14):
     `chips` local devices, one SketchBundle replica each, collectives only
-    at harvest. A 1-chip mesh is legal for the perf harness's scale-point
-    sweep; the operator short-circuits chips=1 to the unsharded path."""
+    at harvest. A 1-chip mesh is legal; the operator short-circuits
+    chips=1 to the unsharded path."""
     if devices is None:
         devices = jax.local_devices()
     if chips < 1:

@@ -1,10 +1,10 @@
-"""Perf-observability plane: stage-segmented harness, provenance-stamped
-PerfRecords, append-only ledger, and noise-aware regression comparison.
+"""Perf-observability plane: provenance-stamped PerfRecords, append-only
+ledger, and noise-aware regression comparison.
 
 The third leg of the observability stool (PR 1 metrics, PR 2 tracing):
 perf numbers become machine-written, schema-validated artifacts with
 provenance the docs cannot drift from. Surfaces: `ig-tpu bench
-run|compare|report|import` and `tools/check_perf_claims.py`.
+compare|report|import` and `tools/check_perf_claims.py`.
 """
 
 from .compare import (
@@ -14,7 +14,6 @@ from .compare import (
     render_compare,
     render_report,
 )
-from .harness import HARNESS_CONFIGS, run_harness
 from .ledger import (
     DEFAULT_LEDGER,
     append_record,
@@ -24,12 +23,12 @@ from .ledger import (
     read_ledger,
 )
 from .provenance import build_provenance, probe_block
-from .schema import SCHEMA_ID, STAGES, make_record, validate_record
+from .schema import SCHEMA_ID, make_record, validate_record
 
 __all__ = [
-    "CompareResult", "DEFAULT_LEDGER", "HARNESS_CONFIGS", "SCHEMA_ID",
-    "STAGES", "append_record", "bench_json_to_record", "build_provenance",
-    "compare_ledger", "compare_record", "import_bench_files", "ledger_path",
-    "make_record", "probe_block", "read_ledger", "render_compare",
-    "render_report", "run_harness", "validate_record",
+    "CompareResult", "DEFAULT_LEDGER", "SCHEMA_ID", "append_record",
+    "bench_json_to_record", "build_provenance", "compare_ledger",
+    "compare_record", "import_bench_files", "ledger_path", "make_record",
+    "probe_block", "read_ledger", "render_compare", "render_report",
+    "validate_record",
 ]

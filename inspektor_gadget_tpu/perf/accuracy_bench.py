@@ -13,8 +13,7 @@ series to the perf ledger:
     every batch.
   * `accuracy-overhead` / `audit_overhead` (fraction, lower better):
     relative ingest throughput cost of the plane — the same loop with
-    the feed off vs on; `extra.audit_overhead` in harness records
-    tracks the same quantity live.
+    the feed off vs on.
   * `accuracy-observed-err` / `cms_observed_err` (pct, lower better):
     shadow-audited heavy-hitter relative error of a real CountMin at
     depth=4 / width=65536 over a millions-of-events zipf stream — the

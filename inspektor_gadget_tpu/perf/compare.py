@@ -213,7 +213,7 @@ def render_report(records: list[dict], last: int = 10,
     cols = Columns(PerfReportRow)
     fmt = TextFormatter(cols)
     if not rows:
-        return "(perf ledger is empty — run `ig-tpu bench run` first)"
+        return "(perf ledger is empty)"
     return fmt.format_table(rows)
 
 

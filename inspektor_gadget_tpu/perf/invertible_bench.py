@@ -25,7 +25,7 @@ import numpy as np
 def measure_update(*, batch: int = 1 << 15, rows: int = 3,
                    log2_buckets: int = 12, seconds: float = 1.0) -> dict:
     """Events/sec through the jitted standalone inv_update at one batch
-    shape (donating steps, periodic sync — the bench.py honesty rule)."""
+    shape (donating steps, periodic sync)."""
     import jax
     import jax.numpy as jnp
 

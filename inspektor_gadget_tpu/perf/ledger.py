@@ -68,10 +68,10 @@ def read_ledger(path: str | None = None) -> LedgerRead:
 # ---------------------------------------------------------------------------
 
 def bench_json_to_record(doc: dict, source: str = "") -> dict:
-    """Convert one driver BENCH_r*.json document (or a bare bench.py JSON
+    """Convert one driver BENCH_r*.json document (or its bare result
     line) into a PerfRecord. Provenance that the old artifact never
     carried is recorded as unknown — imported history is explicitly
-    second-class, never dressed up as harness-grade."""
+    second-class, never dressed up as stamped at the source."""
     parsed = doc.get("parsed") if "parsed" in doc else doc
     if not isinstance(parsed, dict) or "value" not in parsed:
         raise ValueError(f"{source or 'document'}: no parsed benchmark "

@@ -35,7 +35,7 @@ def _latencies(batch: int, seed: int = 42) -> np.ndarray:
 def measure_update(*, batch: int = 1 << 15, n_buckets: int = 2048,
                    alpha: float = 0.01, seconds: float = 1.0) -> dict:
     """Events/sec through the jitted standalone dd_update at one batch
-    shape (donating steps, periodic sync — the bench.py honesty rule)."""
+    shape (donating steps, periodic sync)."""
     import jax
     import jax.numpy as jnp
 

@@ -5,7 +5,7 @@ CPU record: a human wrote a number into a doc that no artifact supported.
 This module is the fix at the root: a perf result only exists as a
 schema-validated record whose provenance block (git sha, host
 fingerprint, platform, degraded flag, probe trail) is stamped by the
-harness, never by hand. The ledger (perf/ledger.py) refuses to append a
+writer's code, never by hand. The ledger (perf/ledger.py) refuses to append a
 record that fails `validate_record`, and the claims lint
 (tools/check_perf_claims.py) refuses doc numbers no record backs.
 
@@ -21,54 +21,8 @@ from typing import Any
 
 SCHEMA_ID = "ig-tpu/perf-record/v1"
 
-# canonical stage order of the ingest pipeline; records may carry any
-# subset. Three pipeline shapes share this table (the record's
-# extra.pipeline string says which one ran, so series keys — config +
-# metric + platform — never fork):
-#   classic: pop → decode → enrich → fold32 → h2d → bundle_update
-#   fused  : pop_folded → h2d_overlap → fused_update   (ISSUE 10: the
-#            zero-copy SoA exporter fills pinned blocks, the depth-N
-#            stager overlaps transfers with compute, and all sketch
-#            planes update in one fused device step)
-#   sharded: pop_folded → h2d_lanes → sharded_update   (ISSUE 14: the
-#            lane fill round-robins batches onto per-chip pinned rings,
-#            per-device H2D puts assemble into one node-sharded global,
-#            and ONE shard_map step updates every chip's fused bundle;
-#            harvest is the only collective)
-#   invertible (ISSUE 15): inv_update measures the invertible plane's
-#            standalone device update (the fused kernel absorbs it as
-#            extra grid planes on the hot path — extra.invertible says
-#            the planes were on, the series key never forks), and
-#            inv_decode the pure-bucket peeling of merged state at
-#            harvest ticks
-#   quantiles (ISSUE 16): qt_update is the standalone DDSketch batch
-#            fold (on the hot path the fused kernel carries the plane —
-#            extra.quantiles marks the record) and qt_merge the
-#            bucket-wise sketch merge at cluster-fold shape
-#   accuracy (ISSUE 19): audit_feed is the host-side bottom-k shadow-
-#            sample fold the accuracy audit plane adds per batch (rides
-#            an existing host lane; harness records its relative cost
-#            as extra.audit_overhead)
-STAGES = ("pop", "decode", "enrich", "fold32", "pop_folded", "h2d",
-          "h2d_overlap", "h2d_lanes", "bundle_update", "fused_update",
-          "sharded_update", "inv_update", "inv_decode", "qt_update",
-          "qt_merge", "audit_feed", "harvest", "merge", "sq_refresh",
-          "sq_recompute", "sq_cache_hit")
-
-# stages whose seconds count as HOST-plane ingest cost (the acceptance
-# comparison pop_folded→h2d vs pop→decode→enrich→fold32 sums these)
-HOST_STAGES = {
-    "classic": ("pop", "decode", "enrich", "fold32", "h2d"),
-    "fused": ("pop_folded", "h2d_overlap"),
-    "sharded": ("pop_folded", "h2d_lanes"),
-}
-
 DIRECTIONS = ("higher_better", "lower_better")
 PLATFORMS = ("tpu", "cpu", "gpu", "none", "unknown")
-
-# per-stage numeric keys the comparator/report understand; stages may add
-# more, but every stage value must be numeric
-STAGE_KEYS = ("ev_per_s", "ms_p50", "ms_p95", "seconds", "events", "calls")
 
 
 def utcnow_iso() -> str:

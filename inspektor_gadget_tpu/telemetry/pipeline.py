@@ -2,10 +2,10 @@
 starvation accounting for the ingest hot path.
 
 The BENCH_r04 starvation gap (device plane eats 2.6B ev/s/chip, one host
-thread supplies 130M) was only visible in one-off `bench run` sessions;
+thread supplies 130M) was only visible in one-off bench sessions;
 a live fleet had no per-stage lag, occupancy, or starvation signal at
-all. This module is the standing instrument: every tpusketch run (and
-the perf harness) registers a `PipelineStats`, the staging layer and the
+all. This module is the standing instrument: every tpusketch run
+registers a `PipelineStats`, the staging layer and the
 operator ingest loop feed it batch-grain observations, and every surface
 the fleet already looks at — harvest summaries, DumpState, Prometheus,
 doctor, `ig-tpu fleet lag`, the `pipeline_lag` alert kind — reads its

@@ -320,7 +320,7 @@ class Registry:
 
     def snapshot(self) -> dict[str, float]:
         """Deterministic flat map 'name{labels}' → value (JSON-embeddable;
-        bench.py / doctor.py ride this into their output records)."""
+        doctor.py rides this into its output records)."""
         return {f"{name}{lbl}": value
                 for name, _kind, lbl, value in self.samples()}
 

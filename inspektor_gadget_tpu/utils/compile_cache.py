@@ -2,7 +2,7 @@
 
 A whole ingest step costs most of a minute to compile for the chip and a
 run meets several pad shapes, so every process that will touch the device
-(CLI, `agent.main serve`, the perf harness, bench.py, chip_smoke.py) calls
+(CLI, `agent.main serve`, chip_smoke.py, the benchmark) calls
 `ensure_compile_cache()` before its first compile. Where
 `JAX_COMPILATION_CACHE_DIR` is set JAX already uses that directory and
 no directory is set in code. Otherwise the cache is ONE fixed directory

@@ -780,16 +780,6 @@ def test_accuracy_bench_publishes_schema_valid_records(tmp_path):
     assert all(r.rc == 0 for r in compare_ledger(on_disk))
 
 
-def test_harness_tiny_records_audit_overhead():
-    from inspektor_gadget_tpu.perf.harness import run_harness
-    from inspektor_gadget_tpu.perf.schema import validate_record
-
-    rec = run_harness("tiny", platform="cpu")
-    assert validate_record(rec) == []
-    assert "audit_feed" in rec["stages"]
-    assert 0.0 <= rec["extra"]["audit_overhead"] <= 1.0
-
-
 # ---------------------------------------------------------------------------
 # docs lint: the err-pct claim pattern in check_perf_claims
 # ---------------------------------------------------------------------------

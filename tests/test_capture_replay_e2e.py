@@ -334,17 +334,6 @@ def test_alerts_test_consumes_journals(recorded_bundle, tmp_path, capsys):
     assert cli_main(["alerts", "test", "--file", str(rules)]) == 2
 
 
-def test_bench_replay_reproducible_input(recorded_bundle):
-    from inspektor_gadget_tpu.perf.harness import run_harness
-    jpath = _node_journal(recorded_bundle["bundle_dir"], "cnode-1")
-    rec = run_harness("tiny", platform="cpu", seconds=0.05, replay=jpath)
-    replay_prov = rec["provenance"]["replay"]
-    assert replay_prov["journal"] == jpath
-    assert replay_prov["digest"] == JournalReader(jpath).digest()
-    assert replay_prov["batches"] == 9  # 9 scripted batches recorded
-    assert rec["extra"]["replay_digest"] == replay_prov["digest"]
-
-
 def test_alert_firing_at_run_end_is_journaled_and_replays(tmp_path):
     """An alert still firing when the run ends resolves via the engine's
     close(); the capture operator must still have its writers open at

@@ -440,7 +440,7 @@ def test_summary_wire_roundtrip_carries_inv_fields():
 
 
 # ---------------------------------------------------------------------------
-# perf: micro-bench records + harness stages (tier-1 smoke)
+# perf: micro-bench records (tier-1 smoke)
 # ---------------------------------------------------------------------------
 
 def test_invertible_bench_publishes_schema_valid_records(tmp_path):
@@ -461,18 +461,3 @@ def test_invertible_bench_publishes_schema_valid_records(tmp_path):
     from inspektor_gadget_tpu.perf.compare import compare_ledger
     results = compare_ledger(on_disk)
     assert all(r.rc == 0 for r in results)
-
-
-def test_harness_tiny_invertible_smoke():
-    from inspektor_gadget_tpu.perf.harness import run_harness
-    from inspektor_gadget_tpu.perf.schema import validate_record
-
-    rec = run_harness("tiny", platform="cpu", invertible=True)
-    assert validate_record(rec) == []
-    assert rec["extra"]["invertible"] is True
-    assert "+inv" in rec["extra"]["pipeline"]
-    assert "inv_update" in rec["stages"]
-    assert "inv_decode" in rec["stages"]
-    with pytest.raises(ValueError, match="single-chip"):
-        run_harness("tiny", platform="cpu", invertible=True,
-                    pipeline="sharded", chips=2)

@@ -1,6 +1,5 @@
-"""Perf-observability plane: PerfRecord schema, append-only ledger,
-noise-aware regression comparison, and a tiny-n stage-segmented harness
-smoke run (the acceptance gates of the perf plane).
+"""Perf-observability plane: PerfRecord schema, append-only ledger and
+noise-aware regression comparison (the acceptance gates of the perf plane).
 
 Key provenance contracts pinned here:
 
@@ -27,7 +26,6 @@ from inspektor_gadget_tpu.perf import (
     compare_record,
     make_record,
     read_ledger,
-    run_harness,
     validate_record,
 )
 from inspektor_gadget_tpu.perf.compare import (
@@ -273,108 +271,6 @@ def test_bench_json_to_record_marks_degraded():
 
 def test_render_report_empty_ledger():
     assert "empty" in render_report([])
-
-
-# ---------------------------------------------------------------------------
-# tiny-n harness smoke (tier-1: JAX pinned to CPU by conftest)
-# ---------------------------------------------------------------------------
-
-def test_harness_tiny_smoke_classic(tmp_path):
-    trace_out = str(tmp_path / "trace.json")
-    r = run_harness("tiny", platform="cpu", trace_out=trace_out,
-                    pipeline="classic")
-    assert validate_record(r) == []
-    assert r["value"] > 0
-    assert r["provenance"]["platform"] == "cpu"
-    assert r["provenance"]["degraded"] is False   # cpu requested ≠ degraded
-    assert r["provenance"]["probe"]["outcome"] == "ok"
-    # per-stage attribution: every throughput stage present and busy
-    for stage in ("pop", "decode", "enrich", "fold32", "h2d",
-                  "bundle_update", "merge"):
-        assert stage in r["stages"], r["stages"].keys()
-        assert r["stages"][stage]["seconds"] >= 0
-    assert r["stages"]["bundle_update"]["ev_per_s"] > 0
-    assert r["stages"]["merge"]["ms_p50"] >= 0
-    assert r["extra"]["pipeline"].startswith("pop(")
-    assert "->decode->enrich->fold32" in r["extra"]["pipeline"]
-    # harvest runs every harvest_every batches; tiny windows on a slow
-    # host may finish under one interval, so presence is conditional but
-    # the ledger roundtrip is not
-    p = str(tmp_path / "PERF.jsonl")
-    append_record(r, p)
-    assert read_ledger(p).records[0]["config"] == "harness.tiny"
-    # the Chrome-trace attachment is real and span-bearing
-    with open(trace_out) as f:
-        doc = json.load(f)
-    names = {e.get("name") for e in doc["traceEvents"]}
-    assert any(str(n).startswith("perf/run/tiny") for n in names)
-    assert "perf/pop" in names and "perf/bundle_update" in names
-
-
-def test_harness_tiny_smoke_fused(tmp_path):
-    """The fused (default) pipeline attributes to the NEW stage names —
-    pop_folded → h2d_overlap → fused_update — and records which host
-    implementation ran in extra.pipeline (ISSUE 10 satellite: the stage
-    list must name the fused stages; the series key stays harness.tiny)."""
-    r = run_harness("tiny", platform="cpu")
-    assert validate_record(r) == []
-    assert r["value"] > 0
-    for stage in ("pop_folded", "h2d_overlap", "fused_update", "merge"):
-        assert stage in r["stages"], r["stages"].keys()
-    for gone in ("pop", "decode", "enrich", "fold32", "h2d",
-                 "bundle_update"):
-        assert gone not in r["stages"]
-    assert r["stages"]["fused_update"]["ev_per_s"] > 0
-    assert r["extra"]["pipeline"].startswith("pop_folded(")
-    assert "->h2d_overlap(" in r["extra"]["pipeline"]
-    assert r["extra"]["host_plane_ev_per_s"] > 0
-    assert r["config"] == "harness.tiny"  # same ledger series as classic
-
-
-def test_fused_and_classic_arms_run_the_same_config():
-    """What a CPU run can show of the fused-vs-classic comparison: BOTH
-    arms run the same config off the native synthetic source, each counts
-    exactly steps x batch events at the same batch shape, and each
-    attributes its host plane to its own stage names. The speed ratio
-    between them is a device-host measurement and belongs to the
-    benchmark, not to a unit test on a shared CPU."""
-    from inspektor_gadget_tpu.perf.schema import HOST_STAGES
-    from inspektor_gadget_tpu.sources.bridge import native_available
-    if not native_available():
-        pytest.skip("native folded exporter unavailable "
-                    "(doctor: native_lib/native_toolchain rows)")
-    arms = {p: run_harness("e2e", platform="cpu", seconds=0.2, pipeline=p)
-            for p in ("fused", "classic")}
-    for name, r in arms.items():
-        assert validate_record(r) == []
-        e = r["extra"]
-        assert e["events"] == e["steps"] * e["batch"] > 0
-        for stage in HOST_STAGES[name]:
-            assert r["stages"][stage]["calls"] > 0, (name, stage)
-        assert e["host_plane_ev_per_s"] > 0
-    assert arms["fused"]["extra"]["batch"] == arms["classic"]["extra"]["batch"]
-    assert arms["fused"]["extra"]["pipeline"].startswith("pop_folded(native)")
-    assert arms["classic"]["extra"]["pipeline"].startswith("pop(native)")
-    assert not set(HOST_STAGES["fused"]) & set(arms["classic"]["stages"])
-
-
-def test_harness_unknown_config():
-    with pytest.raises(ValueError, match="unknown harness config"):
-        run_harness("nope", platform="cpu")
-
-
-def test_harness_tpu_absent_fails(capsys):
-    """A harness run asked for the TPU fails when it does not get one —
-    no record, no CPU number, non-zero exit from the CLI verb."""
-    from inspektor_gadget_tpu.cli.bench import main as bench_main
-    from inspektor_gadget_tpu.utils.platform_probe import PlatformUnavailable
-    with pytest.raises(PlatformUnavailable, match="tpu requested"):
-        run_harness("tiny", platform="tpu")
-    rc = bench_main(["run", "--config", "tiny", "--platform", "tpu",
-                     "--no-ledger"])
-    out = capsys.readouterr()
-    assert rc == 1 and "tpu requested" in out.err
-    assert out.out == ""
 
 
 def test_same_second_records_still_baseline(tmp_path):

@@ -524,49 +524,6 @@ def test_dump_state_carries_pipeline_rows(agents):
 
 
 # ---------------------------------------------------------------------------
-# perf: harness extras + the derived pipeline-lag ledger series
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_harness_records_pipeline_extras():
-    from inspektor_gadget_tpu.perf.harness import run_harness
-    from inspektor_gadget_tpu.perf.schema import validate_record
-
-    rec = run_harness("tiny", platform="cpu")
-    assert validate_record(rec) == []
-    extra = rec["extra"]
-    assert 0.0 <= extra["starved_fraction"] <= 1.0
-    assert extra["stall_s"] >= 0.0
-    assert {"pop", "h2d"} <= set(extra["stage_lag"])
-    for row in extra["stage_lag"].values():
-        assert row["p99_s"] >= row["p50_s"] >= 0.0
-    # the harness unregisters its stats: gauges back at baseline
-    assert all(v == 0.0 for v in _pipeline_gauges().values())
-
-
-@pytest.mark.slow
-def test_bench_run_derives_pipeline_lag_record(tmp_path):
-    from inspektor_gadget_tpu.cli.main import main as cli_main
-    from inspektor_gadget_tpu.perf.ledger import read_ledger
-    from inspektor_gadget_tpu.perf.schema import validate_record
-
-    ledger = str(tmp_path / "PERF.jsonl")
-    assert cli_main(["bench", "run", "--config", "tiny", "--platform",
-                     "cpu", "--pipeline", "fused", "--ledger",
-                     ledger]) == 0
-    recs = read_ledger(ledger).records
-    assert len(recs) == 2
-    main_rec, lag_rec = recs
-    assert validate_record(lag_rec) == []
-    assert lag_rec["config"] == "harness.tiny.pipeline-lag"
-    assert lag_rec["metric"] == "pipeline_device_lag_p99"
-    assert lag_rec["unit"] == "seconds"     # → lower_better gating
-    assert lag_rec["value"] == \
-        main_rec["extra"]["stage_lag"]["h2d"]["p99_s"]
-    assert lag_rec["extra"]["source_config"] == "harness.tiny"
-
-
-# ---------------------------------------------------------------------------
 # docs lint: the starved-claim pattern in check_perf_claims
 # ---------------------------------------------------------------------------
 

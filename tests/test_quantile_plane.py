@@ -501,19 +501,3 @@ def test_quantile_bench_publishes_schema_valid_records(tmp_path):
     assert len(on_disk) == 2
     # the series gates like any other: fresh series → no baseline → rc 0
     assert all(r.rc == 0 for r in compare_ledger(on_disk))
-
-
-def test_harness_tiny_quantiles_smoke():
-    from inspektor_gadget_tpu.perf.harness import run_harness
-    from inspektor_gadget_tpu.perf.schema import validate_record
-
-    rec = run_harness("tiny", platform="cpu", quantiles=True)
-    assert validate_record(rec) == []
-    assert rec["extra"]["quantiles"] is True
-    assert rec["extra"]["qt_geometry"] == "2048@alpha0.01"
-    assert "+qt" in rec["extra"]["pipeline"]
-    assert "qt_update" in rec["stages"]
-    # the plane measures the fused arm only — classic has no value lane
-    with pytest.raises(ValueError, match="fused arm"):
-        run_harness("tiny", platform="cpu", quantiles=True,
-                    pipeline="classic")

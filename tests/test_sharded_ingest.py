@@ -17,6 +17,8 @@ union-at-harvest can only widen the candidate pool.
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -190,9 +192,10 @@ def test_sharded_window_digest_identical_across_device_counts():
 # operator tier
 # ---------------------------------------------------------------------------
 
-def _make_instance(extra_params: dict, gadget_params: dict | None = None):
+def _make_instance(extra_params: dict, gadget_params: dict | None = None,
+                   extra_ctx: dict | None = None):
     desc = get("trace", "exec")
-    ctx = GadgetContext(desc)
+    ctx = GadgetContext(desc, extra=dict(extra_ctx or {}))
     for k, v in (gadget_params or {}).items():
         ctx.gadget_params.set(k, v)
     op = get_op("tpusketch")
@@ -398,39 +401,107 @@ def test_ig_shard_disable_escape_hatch(monkeypatch, batches):
 
 
 # ---------------------------------------------------------------------------
-# harness arm (bench/CI plumbing)
+# the two entries of the one dispatch
 # ---------------------------------------------------------------------------
 
-def test_harness_sharded_smoke_tiny():
-    """Tier-1 smoke for the chips-scaling arm: a tiny sharded run emits a
-    schema-valid record under the device-plane series with the scale
-    point in extra.chips and the honest wall rates beside the
-    aggregate."""
-    from inspektor_gadget_tpu.perf.harness import run_harness
-    from inspektor_gadget_tpu.perf.schema import validate_record
-
-    rec = run_harness("tiny", platform="cpu", pipeline="sharded", chips=2)
-    assert validate_record(rec) == []
-    assert rec["metric"] == "sketch_ingest_device_plane_aggregate"
-    assert rec["config"] == "harness.tiny"
-    ex = rec["extra"]
-    assert ex["chips"] == 2
-    assert ex["lane_batch"] * 2 == ex["batch"]
-    assert ex["per_chip_ev_per_s"] > 0
-    assert ex["device_plane_wall_ev_per_s"] > 0
-    assert ex["e2e_wall_ev_per_s"] > 0
-    assert "per_chip_ev_per_s x chips" in ex["aggregation"]
-    assert rec["value"] == pytest.approx(ex["per_chip_ev_per_s"] * 2)
-    assert "sharded_update" in rec["stages"]
-    assert "h2d_lanes" in rec["stages"]
+HISTORY_ON = {"history": "true", "history-interval": "0"}
+ENTRY_CASES = {
+    "base": {},
+    "history": HISTORY_ON,
+    "quantiles": {**HISTORY_ON, "quantiles": "true"},
+    "invertible": {**HISTORY_ON, "invertible": "true"},
+    "classes": {**HISTORY_ON, "invertible": "true",
+                "priority-classes": "hot=6:101|102,rest=6:*"},
+}
+# what a sealed window carries only through enrich_batch (slices and the
+# label sample's names need an EventBatch's columns; the digest covers
+# the slices) or that names the instance, not the state
+WINDOW_FIELDS_APART = {"slices", "slices_dropped", "names", "digest",
+                       "seq", "run_id"}
 
 
-def test_harness_sharded_validation_is_loud():
-    from inspektor_gadget_tpu.perf.harness import run_harness
+def _entry_stream(rng, count: int = 9):
+    """(keys, mntns, values, cumulative drops) per batch: ragged tails,
+    drops that grow on every second batch, three tenants, magnitudes that
+    span DDSketch buckets and include zeros."""
+    out, drops = [], 0
+    for i in range(count):
+        n = BATCH if i % 3 else 300 + i
+        drops += (i % 2) * (i + 1)
+        out.append((rng.integers(1, 50, n).astype(np.uint32),
+                    rng.choice([101, 102, 777], n).astype(np.uint32),
+                    rng.integers(0, 1 << 20, n).astype(np.uint32), drops))
+    return out
 
-    with pytest.raises(ValueError, match="out of range"):
-        run_harness("tiny", platform="cpu", pipeline="sharded", chips=99)
-    with pytest.raises(ValueError, match="needs pipeline=sharded"):
-        run_harness("tiny", platform="cpu", pipeline="fused", chips=2)
-    with pytest.raises(ValueError, match="unknown pipeline"):
-        run_harness("tiny", platform="cpu", pipeline="warp")
+
+def _leaves(tree) -> list:
+    return [(jax.tree_util.keystr(path), np.asarray(leaf))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)]
+
+
+def _drive_entry(entry: str, params: dict, stream, tmp_path) -> dict:
+    """Feed `stream` through one entry of a fresh instance, sealing a
+    window after the fifth batch and after the last; returns every leaf
+    of the state it left and the windows it sealed."""
+    from inspektor_gadget_tpu.history import HISTORY
+    from inspektor_gadget_tpu.history.window import decode_window
+    from inspektor_gadget_tpu.sources.batch import EventBatch, FoldedBatch
+
+    ticks = iter(range(1, 1000))
+    inst = _make_instance(
+        {"harvest-interval": "1h", "history-dir": str(tmp_path / entry),
+         **params},
+        extra_ctx={"history_clock": lambda: float(next(ticks))})
+    hist = "history" in params
+    got = {}
+    for i, (keys, mntns, values, drops) in enumerate(stream):
+        n = len(keys)
+        if entry == "folded":
+            block = inst.folded_block()
+            block[0][:n], block[1][:n], block[2][:n] = keys, 1, mntns
+            block[3][:n] = values
+            inst.ingest_folded(FoldedBatch(
+                lanes=block, count=n, drops=drops,
+                has_values="quantiles" in params))
+        else:
+            b = EventBatch.alloc(BATCH, with_comm=False)
+            b.cols["key_hash"][:n] = keys      # under 2^32: folds to itself
+            b.cols["mntns"][:n] = mntns
+            b.cols["aux1"][:n] = values
+            b.cols["ts"][:n] = 1
+            b.count, b.drops = n, drops
+            inst.enrich_batch(b)
+        if hist and i in (4, len(stream) - 1):
+            if i == 4:
+                got["window_planes"] = _leaves((inst._wcms, inst._win_hll))
+            inst.seal_window()
+    with inst._bundle_mu:
+        got["bundle"] = _leaves(inst._merged_locked())
+        got["classes"] = _leaves([s for _, s in inst._inv_classes])
+    inst.post_gadget_run()
+    if hist:
+        got["windows"] = [
+            {f.name: getattr(w, f.name) for f in dataclasses.fields(w)
+             if f.name not in WINDOW_FIELDS_APART}
+            for w in (decode_window(h, payload) for h, payload in
+                      HISTORY.fetch_windows(base_dir=str(tmp_path / entry)))]
+        assert len(got["windows"]) == 2
+    return got
+
+
+@pytest.mark.parametrize("lanes", ["1", "4"])
+@pytest.mark.parametrize("case", ENTRY_CASES)
+def test_folded_entry_leaves_the_state_enrich_batch_leaves(case, lanes,
+                                                           tmp_path):
+    """`enrich_batch` (an EventBatch whose three key columns are one
+    column) and `ingest_folded` are two adapters in front of one
+    dispatch: the same keys, weights and drops leave every bundle leaf,
+    the class sketches, the window CMS and HLL and every sealed window
+    bit-equal, with each plane on, on one lane and on four."""
+    params = dict(ENTRY_CASES[case])
+    if lanes != "1":
+        params.update({"shard-ingest": "true", "chips": lanes})
+    stream = _entry_stream(np.random.default_rng(29))
+    want = _drive_entry("batch", params, stream, tmp_path)
+    got = _drive_entry("folded", params, stream, tmp_path)
+    np.testing.assert_equal(got, want)

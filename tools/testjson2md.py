@@ -38,7 +38,7 @@ def _parse(lines: Iterable[str]) -> tuple[list[dict], list[dict]]:
                 tests.append({"name": rec["Test"],
                               "outcome": rec["Action"],
                               "duration": rec.get("Elapsed", 0.0)})
-        elif "metric" in rec:  # bench.py shape
+        elif "metric" in rec:  # benchmark result shape
             benches.append(rec)
         elif "name" in rec and "outcome" in rec:
             tests.append({"name": rec["name"], "outcome": rec["outcome"],
