@@ -107,6 +107,10 @@ _tm_events = counter("ig_tpusketch_events_total",
                      "events absorbed by the sketch plane", ("gadget",))
 _tm_steps = counter("ig_tpusketch_steps_total",
                     "bundle_update device steps", ("gadget",))
+_tm_step_rows = counter("ig_tpusketch_step_rows_total",
+                        "rows the update steps ran at, pad rows included "
+                        "(beside ig_tpusketch_events_total: the fill of "
+                        "the steps)", ("gadget",))
 _tm_arm_steps = counter("ig_tpusketch_update_arm_steps_total",
                         "bundle_update device steps by the arm the step "
                         "traced to (ops.sketches.update_arm)",
@@ -165,6 +169,14 @@ _tm_qt_zero = counter(
     "they land in the sketch's zero bucket, not a log bucket)")
 
 _ckpt_log = get_logger("ig-tpu.tpusketch")
+
+# The staged shape of a batch follows what the batch holds: the smallest
+# power of two of rows that holds its events, from this floor (the gadget's
+# documented default batch-size, so a default deployment has one program)
+# up to the pad. A pad row is key 0 at weight 0 and changes no leaf, so
+# fewer of them is the same state. A constant, not an option: every size of
+# the ladder is primed before the source starts (`pre_gadget_run`).
+STEP_ROWS_FLOOR = 8192
 
 # window-plane device steps (history sealing): the WindowedCMS ring
 # rotates at each boundary (current slot = this window's CMS) and a
@@ -673,16 +685,24 @@ class TpuSketchInstance(OperatorInstance):
         self._names: dict[int, str] = {}
         self.on_summary: Callable[[SketchSummary], None] | None = ctx.extra.get(
             "on_sketch_summary")
-        # fixed device batch shape (pad/mask): start at the gadget's own
-        # batch size so the first batches don't compile a ladder of
-        # intermediate pad shapes (each is a fresh ~15s TPU compile)
-        pad = 8192
+        # device batch shapes (pad/mask): the pad is the gadget's own batch
+        # size as a power of two, and the capacity of the pinned blocks; a
+        # batch is staged and stepped at the power of two that holds it
+        # (`_step_rows`: STEP_ROWS_FLOOR .. pad, all of them primed by
+        # `pre_gadget_run`, each a fresh ~30 s TPU compile when cold). A
+        # batch over the pad ratchets the pad up and compiles where it lands
+        pad = STEP_ROWS_FLOOR
         if "batch-size" in ctx.gadget_params:
             bs = ctx.gadget_params.get("batch-size").as_int()
             if bs > 0:
                 pad = max(pad, 1 << (bs - 1).bit_length())
         self._pad = pad
-        self._note_arm(pad)
+        self._m_step_rows = _tm_step_rows.labels(gadget=g)
+        # per size of step: the arm it traces to with its counter, and
+        # the steps dispatched at it
+        self._arms: dict[int, tuple[str, Any]] = {}
+        self._steps_by_rows: dict[int, int] = {}
+        self._arm = self._note_arm(pad)[0]
         # pinned staging pool + depth-N H2D double buffer (created lazily
         # at the first batch, once the pad shape is known for real)
         self._h2d_depth = (p.get("h2d-depth").as_int()
@@ -956,13 +976,13 @@ class TpuSketchInstance(OperatorInstance):
         return (np.asarray(b.quantiles.counts).astype(np.int64).copy(),
                 int(b.quantiles.zeros), int(b.quantiles.total))
 
-    def _qt_value_lane(self, batch: EventBatch, block: np.ndarray,
+    def _qt_value_lane(self, batch: EventBatch, vals: np.ndarray,
                        n: int) -> np.ndarray:
-        """Fill the block's value lane (row 4) from the configured wire
-        column: saturate-cast to uint32 so magnitudes past 2^32-1 (~4.3s
-        of latency) clamp into the top bucket span instead of wrapping
-        back into the small buckets. Pad slots carry 0 (weight 0 anyway)."""
-        vals = block[4]
+        """Fill `vals`, the staged rows of the block's value lane (row 4),
+        from the configured wire column: saturate-cast to uint32 so
+        magnitudes past 2^32-1 (~4.3s of latency) clamp into the top
+        bucket span instead of wrapping back into the small buckets. Pad
+        slots carry 0 (weight 0 anyway)."""
         raw = batch.cols[self._qt_field][:n].astype(np.uint64, copy=False)
         vals[:n] = np.minimum(raw, np.uint64(0xFFFFFFFF)).astype(np.uint32)
         vals[n:] = 0
@@ -1031,13 +1051,55 @@ class TpuSketchInstance(OperatorInstance):
 
     # the columnar hot path -------------------------------------------------
 
-    def _note_arm(self, pad: int) -> None:
-        """The arm the update step traces to at this pad shape (the
-        dispatcher's own rule, asked where the shape is fixed), for the
-        arm counter and the summary's pipeline block."""
-        self._arm = update_arm(self.bundle, pad)
-        self._m_arm_steps = _tm_arm_steps.labels(
-            gadget=self.ctx.desc.full_name, arm=self._arm)
+    def _note_arm(self, rows: int) -> tuple[str, Any]:
+        """The arm the update step traces to at `rows` rows (the
+        dispatcher's own rule, asked once a size) and its child of the arm
+        counter, for the counter and the summary's pipeline block."""
+        arm = self._arms.get(rows)
+        if arm is None:
+            name = update_arm(self.bundle, rows)
+            arm = self._arms[rows] = (name, _tm_arm_steps.labels(
+                gadget=self.ctx.desc.full_name, arm=name))
+        return arm
+
+    def _step_rows(self, n: int, cap: int) -> int:
+        """Rows a batch of `n` events is staged and stepped at, in a block
+        of `cap` rows: the smallest power of two that holds it, from
+        STEP_ROWS_FLOOR up. A sharded round is rectangular and a batch is
+        parked on its lane before the round's others are known, so under
+        shard-ingest every batch keeps the whole block."""
+        if self._shard_on:
+            return cap
+        return min(max(STEP_ROWS_FLOOR, 1 << (n - 1).bit_length()), cap)
+
+    def pre_gadget_run(self) -> None:
+        """Every size of the one-chip ladder compiled (or read from the
+        persistent cache) before the source produces its first event: a
+        size first met inside the run would put its compile on the loop
+        thread. One step of each program the dispatch runs, on a block of
+        zeros: weight 0 adds to no counter, is masked out of the HLL and
+        becomes the empty key in the top-k, so the state stays what it was
+        (the filler lanes of a flushed sharded round rest on the same
+        property) and nothing is counted. The sharded step has one shape
+        and compiles with the first round, as before."""
+        if not self.enabled or self._shard_on:
+            return
+        rows = STEP_ROWS_FLOOR
+        while rows <= self._pad and not self.ctx.done:
+            z = jnp.asarray(np.zeros(rows, np.uint32))
+            with self._bundle_mu:
+                self.bundle, tok = _ingest_jit(
+                    self.bundle, z, z, z, z, jnp.float32(0),
+                    *((z,) if self._qt_on else ()))
+                toks = [tok]
+                for i, (c, s) in enumerate(self._inv_classes):
+                    s, tok = _inv_class_jit(s, z, z)
+                    self._inv_classes[i] = (c, s)
+                    toks.append(tok)
+            if self._hist_on:
+                toks += self._window_steps(z, z, z)
+            jax.block_until_ready(toks)
+            rows *= 2
 
     def _staging_for(self, pad: int) -> tuple[PinnedBufferPool, H2DStager]:
         """The pinned pool + stager for the current pad shape; a pad
@@ -1056,7 +1118,6 @@ class TpuSketchInstance(OperatorInstance):
                                           max_free=self._h2d_depth + 2)
             self._stager = H2DStager(self._pool, depth=self._h2d_depth,
                                      stats=self._pstats)
-            self._note_arm(pad)
         self._pad = max(self._pad, pad)
         return self._pool, self._stager
 
@@ -1118,7 +1179,6 @@ class TpuSketchInstance(OperatorInstance):
             self._lane_zeros = [
                 jax.device_put(np.zeros(pad, np.uint32), devices[k])
                 for k in range(self._chips)]
-            self._note_arm(pad)
         self._pad = max(self._pad, pad)
         return (self._lane_pools[self._next_lane],
                 self._lane_stagers[self._next_lane])
@@ -1248,7 +1308,11 @@ class TpuSketchInstance(OperatorInstance):
           staged        {lane: [device of each staged array]} for the
                         batches the open round has parked on their lanes
           step          (jitted ingest step, its arguments as
-                        ShapeDtypeStructs): lower it to see what compiled
+                        ShapeDtypeStructs): lower it to see what compiled.
+                        The lanes are shown at the pad; on one chip the
+                        same step also runs at the smaller sizes of the
+                        ladder (`_step_rows`), sharded rounds at the pad
+                        alone
           harvest       (jitted collective harvest, its arguments), None
                         while the state lives on one chip
         """
@@ -1304,18 +1368,22 @@ class TpuSketchInstance(OperatorInstance):
         pad = self._pad
         while pad < n:
             pad *= 2
+        rows = self._step_rows(n, pad)
 
         t0 = time.perf_counter()
-        with self._span("tpusketch/h2d", events=n, pad=pad):
+        with self._span("tpusketch/h2d", events=n, pad=rows):
             with self._st_fold:
                 pool, stager = self._staging(pad)
+                # the block keeps the pad's capacity; its first `rows` rows
+                # are filled, staged and stepped (contiguous prefix views,
+                # so the H2D copy shrinks with the step)
                 block = pool.get()
                 lanes: dict[str, np.ndarray] = {}
 
                 def keys_for(colname: str) -> np.ndarray:
                     lane = lanes.get(colname)
                     if lane is None:
-                        lane = block[len(lanes)]
+                        lane = block[len(lanes)][:rows]
                         a = batch.cols[colname][:n]
                         if a.dtype == np.uint64:
                             lane[:n] = fold64_to_32(a)
@@ -1328,12 +1396,12 @@ class TpuSketchInstance(OperatorInstance):
                 hh = keys_for(self.hh_col)
                 distinct = keys_for(self.distinct_col)
                 dist = keys_for(self.dist_col)
-                w = block[3]
+                w = block[3][:rows]
                 w[:n] = 1
                 w[n:] = 0
-                vals = (self._qt_value_lane(batch, block, n)
+                vals = (self._qt_value_lane(batch, block[4][:rows], n)
                         if self._qt_on else None)
-                mntns = (self._padded_mntns(batch, n, pad)
+                mntns = (self._padded_mntns(batch, n, rows)
                          if self._inv_classes else None)
                 # pipeline watermarks: prefer the batch's stamped fields; an
                 # unstamped batch with a real ts column recovers the oldest
@@ -1387,24 +1455,25 @@ class TpuSketchInstance(OperatorInstance):
         if not self.enabled or fb.count == 0:
             return
         n = fb.count
+        rows = self._step_rows(n, fb.capacity)
         t0 = time.perf_counter()
-        with self._span("tpusketch/h2d", events=n, pad=fb.capacity), \
-                self._st_h2d:
+        with self._span("tpusketch/h2d", events=n, pad=rows), self._st_h2d:
             _pool, stager = self._staging(fb.capacity)
             # pop_folded2 filled row 3 with per-event magnitudes: the
             # value lane stages with the keys/weights in the same pinned
             # block (one more view, zero extra copies)
-            keys, weights = fb.keys, fb.weights
-            vals = fb.values if self._qt_on else None
+            keys, weights = fb.keys[:rows], fb.weights[:rows]
+            vals = (fb.values[:rows]
+                    if self._qt_on and fb.values is not None else None)
             host = (keys, weights) + (() if vals is None else (vals,))
-            if n < fb.capacity:
+            if n < rows:
                 for lane in host:
                     lane[n:] = 0
             k_d, w_d, *v_d = stager.stage(fb.lanes, host)
         self._absorb_staged(
             stager, (k_d, k_d, k_d, w_d, v_d[0] if v_d else None),
             (keys, keys, weights), n, t0, drops=fb.drops, pop_ts=fb.pop_ts,
-            oldest_ts=fb.oldest_ts, mntns=fb.mntns, vals=vals)
+            oldest_ts=fb.oldest_ts, mntns=fb.mntns[:rows], vals=vals)
 
     def _absorb_staged(self, stager: H2DStager, staged: tuple, host: tuple,
                        n: int, t0: float, *, drops: int, pop_ts: float,
@@ -1415,7 +1484,9 @@ class TpuSketchInstance(OperatorInstance):
         adapter staged it (at `t0`): `staged` is the device arrays (hh,
         distinct, dist, weights, values or None) of the stager's last
         slot, `host` the pinned lanes (hh, distinct, weights) they were
-        put from, `drops` the source's cumulative count. On one chip the
+        put from, `drops` the source's cumulative count. Their length is
+        the rows the adapter chose (`_step_rows`), and every program here
+        takes the shape it is given. On one chip the
         update is dispatched first and the window planes and class
         sketches ride the same staged arrays; under shard-ingest the
         staged arrays live on the batch's lane chip while those planes
@@ -1473,7 +1544,11 @@ class TpuSketchInstance(OperatorInstance):
             self._m_update.observe(t2 - t1)
             self._m_events.inc(n)
             self._m_steps.inc()
-            self._m_arm_steps.inc()
+            rows = w.shape[0]
+            self._m_step_rows.inc(rows)
+            self._steps_by_rows[rows] = self._steps_by_rows.get(rows, 0) + 1
+            self._arm, m_arm_steps = self._note_arm(rows)
+            m_arm_steps.inc()
             self._qt_count(vals, n)
             if new_drops:
                 self._m_drops.inc(new_drops)
@@ -1509,13 +1584,19 @@ class TpuSketchInstance(OperatorInstance):
                 distinct = hh_w if distinct is hh else jnp.asarray(distinct)
                 hh, w = hh_w, jnp.asarray(w)
         with self._st_planes:
-            self._wcms, wtok = _wcms_ingest_jit(
-                self._wcms, hh, w.astype(jnp.int32))
-            self._win_hll, htok = _hll_ingest_jit(
-                self._win_hll, distinct, w > 0)
+            toks = self._window_steps(hh, distinct, w)
         if slices is not None:
             with self._st_slices:
                 slices()
+        return toks
+
+    def _window_steps(self, hh, distinct, w) -> list:
+        """The two window-plane programs and their eager casts on device
+        arrays of one length (a batch's, or the zeros priming feeds)."""
+        self._wcms, wtok = _wcms_ingest_jit(
+            self._wcms, hh, w.astype(jnp.int32))
+        self._win_hll, htok = _hll_ingest_jit(
+            self._win_hll, distinct, w > 0)
         return [wtok, htok]
 
     def folded_block(self) -> np.ndarray:
@@ -1944,6 +2025,9 @@ class TpuSketchInstance(OperatorInstance):
         # through the ambient harvest context)
         pipe_out = self._pstats.snapshot()
         pipe_out["update_arm"] = self._arm
+        # steps by the rows they ran at (string keys: the block rides JSON)
+        pipe_out["step_rows"] = {str(rows): steps for rows, steps
+                                 in sorted(self._steps_by_rows.items())}
         if self._hist_on and self._last_slices is not None:
             # what the last sealed window's slice store held
             pipe_out["slices"] = dict(self._last_slices)

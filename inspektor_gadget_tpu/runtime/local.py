@@ -103,13 +103,14 @@ class LocalRuntime(Runtime):
                         on_batch(batch)
             gadget.set_batch_handler(handle_batch)
 
+        instances.pre_gadget_run()
+        # the timeout is the gadget's run: what the operators do ahead of
+        # it (tpusketch compiles its step shapes there) is not taken off it
         if ctx.timeout > 0:
             import threading
             threading.Thread(
                 target=ctx.wait_for_timeout_or_done, daemon=True
             ).start()
-
-        instances.pre_gadget_run()
         try:
             if isinstance(gadget, RunWithResult):
                 # the gadget collects until ctx timeout/cancel, then renders

@@ -34,6 +34,7 @@ from inspektor_gadget_tpu.ops.pallas_kernels import (FUSED_KERNEL_NAME,
 from inspektor_gadget_tpu.ops.sketches import (bundle_digest, bundle_init,
                                                bundle_ingest_step,
                                                make_bundle_harvest_sharded)
+from inspektor_gadget_tpu.operators.tpusketch import STEP_ROWS_FLOOR
 from inspektor_gadget_tpu.parallel.mesh import NODE_AXIS
 
 # the operator's default geometry and the smoke's batch
@@ -181,21 +182,27 @@ def test_sharded_harvest_compiles_with_its_collectives(topo):
     pytest.param(dict(GEOMETRY, log2_width=12, hll_p=12), "fused",
                  id="narrow-fused"),
 ])
+@pytest.mark.parametrize("rows", [
+    pytest.param(BATCH, id="pad"),
+    pytest.param(STEP_ROWS_FLOOR, id="ladder-floor"),
+])
 def test_ingest_step_compiles_with_the_selected_arm(one_chip, monkeypatch,
-                                                    geometry, arm):
+                                                    geometry, arm, rows):
     """The whole donated ingest step as the operator dispatches it on a
     TPU, at the default geometry (the scatter composition, whose entropy
     plane is the histogram kernel) and at a narrow one where the fused
-    kernel is expected to win. The dispatch asks jax.default_backend(),
-    which sees the CPU in this process, so the test steers it; the step
-    must hold the arm update_arm names, and the program must fit the
-    chip."""
+    kernel is expected to win, at the smoke's batch and at the floor of
+    the operator's ladder of step sizes (the sizes between them compile
+    too: read once by hand, ISSUE 30). The dispatch asks
+    jax.default_backend(), which sees the CPU in this process, so the
+    test steers it; the step must hold the arm update_arm names, and the
+    program must fit the chip."""
     from inspektor_gadget_tpu.ops.sketches import update_arm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert update_arm(bundle_init(**geometry), BATCH) == arm
+    assert update_arm(bundle_init(**geometry), rows) == arm
     bundle = _on(one_chip, jax.eval_shape(lambda: bundle_init(**geometry)))
-    k = _lane(one_chip)
+    k = jax.ShapeDtypeStruct((rows,), jnp.uint32, sharding=one_chip)
     drops = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
     lowered = jax.jit(bundle_ingest_step, donate_argnums=0).lower(
         bundle, k, k, k, k, drops)
