@@ -25,6 +25,13 @@ raises, so the exit code is non-zero and no result line is printed):
   quantiles      a short run with the DDSketch plane: p50..p99.9 vs exact
   narrow         a short run at the narrow geometry: on a TPU the operator
                  takes the kernel there, and its arm counter says so
+  anomaly        `advise seccomp-profile` with the anomaly scorer on, at
+                 the sizes of chipbench/configs/seccomp-node.json: every
+                 container's score against the plain replay of
+                 chipbench/reference_scorer.py within its tolerance,
+                 histograms and syscall sets exact; then the same run with
+                 each of three faults planted in the scorer's step, which
+                 must each read over the tolerance
   agent          one RunGadget from AgentClient against the agent service
                  (`agent.main serve`'s AgentServer) with --checkpoint-dir
                  semantics: the checkpointer thread reads device state
@@ -45,6 +52,7 @@ the script fails before printing any result.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import shutil
@@ -52,6 +60,7 @@ import sys
 import tempfile
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 
@@ -80,6 +89,13 @@ SIZES = {
                 time_steps=4, short_windows=2, shard_batches=10),
 }
 ZIPF = 1.2
+# the anomaly phase: chipbench/configs/seccomp-node.json's stream (a key is
+# a (container, syscall) pair: 64 x 335) and the operator's own scorer. A
+# harvest every 100 ms keeps a run of `harvests` summaries, each a training
+# step, to a few seconds: the third fault needs about thirty steps to drift
+# past the tolerance. Scores are judged on the first `head` summaries and
+# the last; the replay steps through every one
+ANOMALY = dict(vocab=64 * 335, harvest="100ms", harvests=40, head=8)
 INV_VOCAB = 500        # inside every decode capacity used here: complete
 SHARD_VOCAB = 24       # < top-k on both sizes: the candidate table stays
 #                        exact on every path (tests/test_sharded_ingest.py)
@@ -564,6 +580,159 @@ def phase_narrow(cfg, platform, seed, clock) -> None:
 
 
 # ---------------------------------------------------------------------------
+# phase: the anomaly scorer through LocalRuntime.run_gadget
+# ---------------------------------------------------------------------------
+
+SCORER_FAULTS = ("skipped", "before", "bf16")
+
+
+@contextlib.contextmanager
+def scorer_fault(name: str):
+    """One of three faults planted in the scorer's step for the runs made
+    inside (the operator binds `tpusketch.anomaly_step` when an instance is
+    made): `skipped` leaves the third harvest's training step out,
+    `before` returns the scores of the weights the step started from,
+    `bf16` rounds the parameters to bfloat16 after every step. "" plants
+    nothing."""
+    if not name:
+        yield
+        return
+    import jax
+    import jax.numpy as jnp
+    from inspektor_gadget_tpu.models import autoencoder as ae
+    from inspektor_gadget_tpu.operators import tpusketch
+
+    real = tpusketch.anomaly_step
+    score = jax.jit(lambda sc, c: ae.ae_score(sc, ae.normalize_counts(c)))
+
+    def skipped(scorer, counts, mask):
+        if int(scorer.steps) == 2:      # each scorer's third step
+            return scorer.replace(steps=scorer.steps + 1), score(scorer,
+                                                                 counts)
+        return real(scorer, counts, mask)
+
+    def before(scorer, counts, mask):
+        old = jax.block_until_ready(score(scorer, counts))
+        return real(scorer, counts, mask)[0], old
+
+    def bf16(scorer, counts, mask):
+        scorer, _scores = real(scorer, counts, mask)
+        scorer = scorer.replace(params=jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
+            scorer.params))
+        return scorer, score(scorer, counts)
+
+    tpusketch.anomaly_step = {"skipped": skipped, "before": before,
+                              "bf16": bf16}[name]
+    try:
+        yield
+    finally:
+        tpusketch.anomaly_step = real
+
+
+def reference_scorer():
+    """chipbench/reference_scorer.py: the benchmark's directory is no
+    package, so it is imported the way chipbench/run.py imports its own."""
+    bench = str(Path(__file__).resolve().parent / "chipbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import reference_scorer as ref
+    return ref
+
+
+def anomaly_run(cfg: dict, seed: int, fault: str = "",
+                extra: dict | None = None) -> dict:
+    """`advise seccomp-profile` on the native synthetic source with
+    tpusketch and its anomaly scorer on, until `ANOMALY["harvests"]`
+    summaries came, recorded by chipbench/reference_scorer.py's Recorder
+    and held to its three answers. Returns the readings, the last
+    summary's `pipeline` block and the emitted profile."""
+    import jax
+    import inspektor_gadget_tpu.all_gadgets  # noqa: F401
+    from inspektor_gadget_tpu.gadgets import GadgetContext, get
+    from inspektor_gadget_tpu.models.autoencoder import AEConfig, ae_init
+    from inspektor_gadget_tpu.operators import tpusketch
+    from inspektor_gadget_tpu.params import Collection
+    from inspektor_gadget_tpu.runtime import LocalRuntime
+
+    ref = reference_scorer()
+
+    desc = get("advise", "seccomp-profile")
+    params = desc.params().to_params()
+    for k, v in {"source": "synthetic", "rate": str(cfg["rate"]),
+                 "batch-size": str(cfg["batch"]),
+                 "vocab": str(ANOMALY["vocab"]), "zipf": str(ZIPF),
+                 "seed": str(seed)}.items():
+        params.set(k, v)
+    op_params = Collection()
+    op_params["operator.tpusketch."] = sketch_params(
+        {**cfg, "harvest": ANOMALY["harvest"]},
+        {"anomaly": "true", "audit-sample": "0", **(extra or {})})
+    rec = ref.Recorder()
+    last: dict = {}
+
+    def on_summary(s) -> None:
+        rec.on_summary(s)
+        (inst,) = tpusketch.live_instances()
+        last.update(counts=inst.container_distributions(),
+                    profile=inst.gadget.syscall_sets(), pipeline=s.pipeline)
+        if len(rec.summaries) >= ANOMALY["harvests"]:
+            ctx.cancel()
+
+    ctx = GadgetContext(desc, gadget_params=params,
+                        operator_params=op_params, timeout=cfg["deadline"],
+                        extra={"on_sketch_summary": on_summary})
+    with scorer_fault(fault):
+        result = LocalRuntime().run_gadget(ctx, on_batch=rec.on_batch)
+    require(not result.errors(), f"gadget run failed: {result.errors()}")
+    require(len(rec.summaries) > ANOMALY["harvests"],
+            f"only {len(rec.summaries)} harvests inside {cfg['deadline']}s")
+    dim = 1 << int(cfg["geometry"].get("entropy-log2-width", 12))
+    # the weights the operator starts from (ae_init is seeded), as arrays
+    start = jax.tree.map(np.asarray, ae_init(AEConfig(
+        input_dim=dim, hidden_dim=256, latent_dim=64)).params)
+    judged = list(range(ANOMALY["head"])) + [len(rec.summaries) - 1]
+    readings = ref.compare(rec, start, dim, profile=last["profile"],
+                           counts=last["counts"], summaries=judged)
+    return {"readings": readings, "tolerance": ref.TOLERANCE,
+            "pipeline": last["pipeline"], "emitted": result.first(),
+            "recorded": rec, "events": sum(len(a) for a in rec.mntns)}
+
+
+def phase_anomaly(cfg, platform, seed, clock) -> None:
+    sound = anomaly_run(cfg, seed + 4)
+    r, tol = sound["readings"], sound["tolerance"]
+    require(r["score_keys_equal"], "a summary's scores name other "
+            "containers than the stream held")
+    require(r["score_gap"] <= tol,
+            f"scores off the reference's replay by {r['score_gap']:.4f} "
+            f"> {tol}")
+    require(r["histograms_exact"], "per-container histograms differ from "
+            "the exact counts of the stream")
+    require(r["profile_exact"], "recorded syscall sets differ from the "
+            "stream's")
+    block = sound["pipeline"]["anomaly"]
+    require(block["steps"] == r["harvests"],
+            f"{block['steps']} scorer steps for {r['harvests']} harvests")
+    faults = {}
+    for i, fault in enumerate(SCORER_FAULTS):
+        gap = anomaly_run(cfg, seed + 5 + i, fault)["readings"]["score_gap"]
+        require(gap > tol, f"the planted fault {fault!r} reads {gap:.4f}, "
+                f"inside the tolerance {tol}: the comparison is blind to it")
+        faults[fault] = gap
+    stages = sound["pipeline"]["turn"]
+    say(phase="anomaly", events=sound["events"], **r, tolerance=tol,
+        **block, fault_gaps=faults,
+        anomaly_score_ms_per_harvest=round(
+            1e3 * stages["anomaly_score_s"] / block["steps"], 3),
+        record_ms_per_turn=round(
+            1e3 * stages["stages"]["gadget_record"] / stages["turns"], 3),
+        dists_ms_per_turn=round(
+            1e3 * stages["stages"]["tpusketch_container_dists"]
+            / stages["turns"], 3), **clock.take())
+
+
+# ---------------------------------------------------------------------------
 # phase: through the agent (in-process service, client on its own thread)
 # ---------------------------------------------------------------------------
 
@@ -834,6 +1003,7 @@ def main(argv: list[str] | None = None) -> int:
         phase_invertible(cfg, platform, args.seed, clock)
         phase_quantiles(cfg, platform, args.seed, clock)
         phase_narrow(cfg, platform, args.seed, clock)
+        phase_anomaly(cfg, platform, args.seed, clock)
         phase_agent(cfg, platform, args.seed, clock)
     say(phase="done", seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {

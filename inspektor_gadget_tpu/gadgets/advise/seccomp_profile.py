@@ -5,9 +5,11 @@ syscall bitmap; tracer Peek:107 converts bits→names via libseccomp;
 gadget-collection/gadgets/advise/seccomp/gadget.go:582 renders an OCI
 seccomp JSON or a SeccompProfile CR). Here the recording plane is the
 syscall event stream (synthetic, or EV_SYSCALL batches from any source)
-folded per-container into syscall sets — with the TPU twist that the
-per-container distribution also feeds the entropy sketch + autoencoder, so
-the generated profile carries an anomaly score per container.
+folded per-container into a syscall bitmap (one row a container behind a
+mntns -> slot table, one array pass a batch: the reference's per-mntns
+bitmap) — with the TPU twist that the per-container distribution also
+feeds the entropy sketch + autoencoder, so the generated profile carries
+an anomaly score per container.
 
 Run semantics: collect until timeout/stop, then emit the policy JSON
 (RunWithResult — the modern-path registration the reference also has,
@@ -19,12 +21,20 @@ from __future__ import annotations
 import json
 from collections import defaultdict
 
+import numpy as np
+
 from ...params import ParamDesc, ParamDescs
 from ..interface import GadgetDesc, GadgetType
 from ..registry import register
 from ..source_gadget import PtraceAttachMixin, SourceTraceGadget, source_params
 from ...sources import bridge as B
+from ...utils.grouping import SlotTable
 from ...utils.syscalls import syscall_name
+
+# columns of the per-container bitmap (seccomp.bpf.c: SYSCALLS_COUNT 500,
+# rounded up); the reference drops a number past its bitmap, here the few
+# there can be (an x32 or a failed-decode number) are kept in a set beside it
+SYSCALL_BITS = 512
 
 # Syscalls always allowed (runc needs them to start a container) — role of
 # the baseline set the reference inherits from its OCI template.
@@ -94,7 +104,9 @@ class AdviseSeccompProfile(PtraceAttachMixin, SourceTraceGadget):
         p = ctx.gadget_params
         self._command = p.get("command").as_string() if "command" in p else ""
         self._target_pid = p.get("pid").as_int() if "pid" in p else 0
-        self._per_container: dict[int, set[int]] = defaultdict(set)
+        self._containers = SlotTable()
+        self._bitmap = np.zeros((64, SYSCALL_BITS), dtype=bool)
+        self._wide: dict[int, set[int]] = defaultdict(set)
 
     def native_ready(self) -> bool:
         return bool(self._command or self._target_pid)
@@ -106,16 +118,37 @@ class AdviseSeccompProfile(PtraceAttachMixin, SourceTraceGadget):
         return B.make_cfg(pid=self._target_pid)
 
     def process_batch(self, batch) -> None:
-        c = batch.cols
-        for i in range(batch.count):
-            aux2 = int(c["aux2"][i])
-            nr = (aux2 >> 32) if self._is_native else aux2 % 335
-            self._per_container[int(c["mntns"][i])].add(nr)
+        n = batch.count
+        mntns = batch.cols["mntns"][:n]
+        aux2 = batch.cols["aux2"][:n]
+        nr = (aux2 >> np.uint64(32)) if self._is_native else aux2 % 335
+        wide = nr >= SYSCALL_BITS
+        if wide.any():
+            for ns, number in set(zip(mntns[wide].tolist(),
+                                      nr[wide].tolist())):
+                self._wide[ns].add(number)
+            mntns, nr = mntns[~wide], nr[~wide]
+            if not len(nr):
+                return
+        slot = self._containers.slots_of(mntns)
+        while len(self._containers) > len(self._bitmap):
+            self._bitmap = np.concatenate(
+                [self._bitmap, np.zeros_like(self._bitmap)])
+        self._bitmap[slot, nr.astype(np.intp)] = True
+
+    def syscall_sets(self) -> dict[int, set[int]]:
+        """The syscall numbers recorded for each container (mntns)."""
+        sets = {ns: set(np.flatnonzero(row).tolist())
+                for ns, row in zip(self._containers.ids(), self._bitmap)}
+        for ns, numbers in self._wide.items():
+            sets.setdefault(ns, set()).update(numbers)
+        return sets
 
     def run_with_result(self, ctx) -> bytes:
         self.run(ctx)  # records until timeout/cancel
+        recorded = sorted(self.syscall_sets().items())
         profiles = {}
-        for mntns, nrs in sorted(self._per_container.items()):
+        for mntns, nrs in recorded:
             names = {syscall_name(nr) for nr in nrs}
             profiles[str(mntns)] = generate_oci_seccomp_profile(names)
         ctx.result = profiles
@@ -127,7 +160,7 @@ class AdviseSeccompProfile(PtraceAttachMixin, SourceTraceGadget):
             prefix = (p.get("profile-name").as_string()
                       if "profile-name" in p else "") or "ig-seccomp"
             docs = []
-            for mntns, nrs in sorted(self._per_container.items()):
+            for mntns, nrs in recorded:
                 docs.append(generate_seccomp_profile_cr(
                     f"{prefix}-{mntns}", {syscall_name(nr) for nr in nrs}))
             return "---\n".join(docs).encode()

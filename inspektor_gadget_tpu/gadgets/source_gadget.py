@@ -20,6 +20,7 @@ from ..sources import EventBatch, PySyntheticSource
 from ..sources.bridge import NativeCapture, native_available
 from ..sources.bridge import make_cfg as B_make_cfg
 from ..telemetry import counter, gauge
+from ..telemetry.pipeline import RECORD_STAGE
 from .context import GadgetContext
 from .interface import GadgetDesc
 
@@ -462,6 +463,12 @@ class SourceTraceGadget:
         st_wait, st_pop, st_filter, st_deliver = (
             turn.stage(n) for n in ("source_wait", "source_pop",
                                     "source_filter", "runtime_deliver"))
+        # a gadget that records (overrides `process_batch`) times it as a
+        # stage of its own; the others carry no such name
+        st_record = None
+        if type(self).process_batch is not SourceTraceGadget.process_batch:
+            st_record = turn.stage(RECORD_STAGE)
+            turn.open_stages(RECORD_STAGE)
         turn.begin()
         try:
             while not ctx.done and not deadline_hit:
@@ -489,7 +496,8 @@ class SourceTraceGadget:
                         self._apply_filter(batch)
                         if batch.count != popped:
                             self._m_filtered.inc(popped - batch.count)
-                        if batch.count:
+                    if batch.count and st_record is not None:
+                        with st_record:
                             self.process_batch(batch)
                     if batch.count and self._batch_handler is not None:
                         self._batch_handler(batch)

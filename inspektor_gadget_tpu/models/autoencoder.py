@@ -14,6 +14,7 @@ hidden sizes padded to multiples of 128 (MXU lane width).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.struct
@@ -107,6 +108,33 @@ def ae_train_step(
     updates, opt_state = _optimizer(scorer.config).update(grads, scorer.opt_state, scorer.params)
     params = optax.apply_updates(scorer.params, updates)
     return scorer.replace(params=params, opt_state=opt_state, steps=scorer.steps + 1), loss
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def anomaly_step(scorer: AnomalyScorer, counts: jnp.ndarray,
+                 mask: jnp.ndarray) -> tuple[AnomalyScorer, jnp.ndarray]:
+    """What a harvest asks of the scorer, as one program: `normalize_counts`,
+    one Adam step, and the scores of the updated weights. `counts` is the
+    operator's `[slots, dim]` array, a power of two of rows, and `mask`
+    (`[slots]`, 1.0 where a slot holds a container) keeps the filler rows
+    out of the loss, so the shape changes only when the slots double. The
+    loss is the mean over the real rows of their mean squared error, which
+    is `ae_loss` of those rows alone; a filler row's score is computed and
+    is the caller's to drop. The scorer is donated: the caller swaps it
+    under the lock its other readers take."""
+    x = normalize_counts(counts)
+    cfg = scorer.config
+
+    def loss(params):
+        err = jnp.mean((ae_apply(params, x, cfg) - x) ** 2, axis=-1)
+        return jnp.sum(err * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+    grads = jax.grad(loss)(scorer.params)
+    updates, opt_state = _optimizer(cfg).update(grads, scorer.opt_state,
+                                                scorer.params)
+    scorer = scorer.replace(params=optax.apply_updates(scorer.params, updates),
+                            opt_state=opt_state, steps=scorer.steps + 1)
+    return scorer, ae_score(scorer, x)
 
 
 # ---------------------------------------------------------------------------

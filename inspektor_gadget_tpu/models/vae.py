@@ -12,12 +12,15 @@ the step jittable with an explicit PRNG key threaded through the state.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.struct
 import jax
 import jax.numpy as jnp
 import optax
+
+from .autoencoder import normalize_counts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,3 +122,29 @@ def vae_train_step(scorer: VAEScorer, x: jnp.ndarray,
     params = optax.apply_updates(scorer.params, updates)
     return scorer.replace(params=params, opt_state=opt_state, rng=next_rng,
                           steps=scorer.steps + 1), loss
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def anomaly_step(scorer: VAEScorer, counts: jnp.ndarray,
+                 mask: jnp.ndarray) -> tuple[VAEScorer, jnp.ndarray]:
+    """models/autoencoder.py `anomaly_step` for this scorer: normalise, one
+    Adam step on the mean loss of the rows `mask` keeps, scores of the
+    updated weights, as one program with the scorer donated. The noise is
+    drawn for every row of the padded array, so a real row's draw depends
+    on the padded shape (the same distribution, another sample)."""
+    x = normalize_counts(counts)
+    cfg = scorer.config
+    key, next_rng = jax.random.split(scorer.rng)
+
+    def loss(params):
+        rec, kl = vae_elbo_terms(params, x, key, cfg)
+        return (jnp.sum((rec + cfg.kl_weight * kl) * mask)
+                / jnp.maximum(jnp.sum(mask), 1.0))
+
+    grads = jax.grad(loss)(scorer.params)
+    updates, opt_state = _optimizer(cfg).update(grads, scorer.opt_state,
+                                                scorer.params)
+    scorer = scorer.replace(params=optax.apply_updates(scorer.params, updates),
+                            opt_state=opt_state, rng=next_rng,
+                            steps=scorer.steps + 1)
+    return scorer, vae_score(scorer, x)

@@ -32,7 +32,7 @@ import jax
 from ..columns import col
 from ..gadgets.context import GadgetContext
 from ..gadgets.interface import GadgetDesc
-from ..models.autoencoder import AEConfig, ae_init, ae_score, ae_train_step, normalize_counts
+from ..models.autoencoder import AEConfig, ae_init, anomaly_step
 from ..ops import bundle_init, fold64_to_32
 from ..ops.hll import hll_init, hll_update
 from ..ops.invertible import (InvSketch, class_weights, inv_capacity,
@@ -48,8 +48,10 @@ from ..params import ParamDesc, ParamDescs, ParamError, Params, TypeHint
 from ..params.validators import validate_int_range
 from ..sources.batch import BATCH_COLUMNS, EventBatch, FoldedBatch
 from ..sources.staging import H2DStager, PinnedBufferPool
-from ..telemetry import counter, histogram
+from ..telemetry import counter, gauge, histogram
+from ..telemetry.pipeline import ANOMALY_SCORE, DISTS_STAGE
 from ..telemetry.tracing import TRACER, device_annotation
+from ..utils.grouping import SlotTable
 from ..utils.logger import get_logger
 from .operators import Operator, OperatorInstance, register
 
@@ -137,6 +139,16 @@ _tm_slice_hh_entries = counter(
     "ig_history_slice_hh_entries_total",
     "(cell, key) entries of the slices' exact heavy-hitter table at each "
     "window seal: what the seal's slice work follows", ("gadget",))
+# anomaly scorer on only (labelled likewise)
+_tm_anomaly_steps = counter(
+    "ig_tpusketch_anomaly_steps_total",
+    "training-and-scoring steps of the anomaly scorer, one a harvest that "
+    "has a container to score", ("gadget", "model"))
+_tm_anomaly_slots = gauge(
+    "ig_tpusketch_anomaly_slots",
+    "rows of the per-container distribution array the scorer's step runs "
+    "on (a power of two; it doubles when the containers outgrow it)",
+    ("gadget",))
 _tm_h2d = histogram("ig_tpusketch_h2d_seconds",
                     "host→device batch staging (pad/fold + transfer "
                     "dispatch)", ("gadget",))
@@ -177,6 +189,11 @@ _ckpt_log = get_logger("ig-tpu.tpusketch")
 # fewer of them is the same state. A constant, not an option: every size of
 # the ladder is primed before the source starts (`pre_gadget_run`).
 STEP_ROWS_FLOOR = 8192
+
+# Rows the per-container distribution array starts with, and the scorer's
+# program is primed at: a power of two that doubles when the containers
+# outgrow it. A constant, not an option.
+CONTAINER_SLOTS_FLOOR = 64
 
 # window-plane device steps (history sealing): the WindowedCMS ring
 # rotates at each boundary (current slot = this window's CMS) and a
@@ -660,17 +677,24 @@ class TpuSketchInstance(OperatorInstance):
         self.anomaly_model = (p.get("anomaly-model").as_string()
                               if "anomaly-model" in p else "ae")
         self.scorer = None
-        self._container_counts: dict[int, np.ndarray] = {}
+        # per-container distributions (`ae` / `vae`): one [slots, dim]
+        # float32 array behind a mntns -> slot table; the slots are a power
+        # of two and double when a container more than they hold appears,
+        # which is the only time the scorer's program changes shape
+        self._containers = SlotTable()
+        self._container_counts: np.ndarray | None = None
+        self._anomaly_step = None
         self._container_seqs: dict[int, list[int]] = {}
         self._seq_window = (p.get("seq-window").as_int()
                             if "seq-window" in p else 256)
         if self.anomaly_on:
             dim = 1 << p.get("entropy-log2-width").as_int()
             if self.anomaly_model == "vae":
-                from ..models.vae import VAEConfig, vae_init
-                self._ae_cfg = VAEConfig(input_dim=dim, hidden_dim=256,
-                                         latent_dim=64)
-                self.scorer = vae_init(self._ae_cfg)
+                from ..models import vae
+                self._ae_cfg = vae.VAEConfig(input_dim=dim, hidden_dim=256,
+                                             latent_dim=64)
+                self.scorer = vae.vae_init(self._ae_cfg)
+                self._anomaly_step = vae.anomaly_step
             elif self.anomaly_model == "seq":
                 from ..models.seqmodel import SeqConfig, seq_init
                 self._ae_cfg = SeqConfig(vocab=min(dim, 512))
@@ -679,6 +703,15 @@ class TpuSketchInstance(OperatorInstance):
                 self._ae_cfg = AEConfig(input_dim=dim, hidden_dim=256,
                                         latent_dim=64)
                 self.scorer = ae_init(self._ae_cfg)
+                self._anomaly_step = anomaly_step
+            if self._anomaly_step is not None:
+                self._container_counts = np.zeros(
+                    (CONTAINER_SLOTS_FLOOR, dim), dtype=np.float32)
+                self._m_anomaly_steps = _tm_anomaly_steps.labels(
+                    gadget=g, model=self.anomaly_model)
+                self._m_anomaly_slots = _tm_anomaly_slots.labels(gadget=g)
+                self._m_anomaly_slots.set(CONTAINER_SLOTS_FLOOR)
+                self._anomaly_steps = 0
         self._drops_seen = 0
         self._last_harvest = time.monotonic()
         self._epoch = 0
@@ -788,6 +821,10 @@ class TpuSketchInstance(OperatorInstance):
         self._pstats = PipelineStats(ctx.run_id, ctx.desc.full_name)
         self._pstats.register()
         turn.attach(self._pstats)
+        if self.anomaly_on:
+            # only a run with the scorer carries these names
+            self._st_dists = turn.stage(DISTS_STAGE)
+            turn.open_stages(DISTS_STAGE, ANOMALY_SCORE)
         if self._astats is not None:
             # registered only when the audit plane is on: a plane-off
             # run must leave no accuracy gauges or live rows behind
@@ -1081,8 +1118,19 @@ class TpuSketchInstance(OperatorInstance):
         becomes the empty key in the top-k, so the state stays what it was
         (the filler lanes of a flushed sharded round rest on the same
         property) and nothing is counted. The sharded step has one shape
-        and compiles with the first round, as before."""
-        if not self.enabled or self._shard_on:
+        and compiles with the first round, as before. The anomaly scorer's
+        one program is primed at the slots it starts with, on a copy of
+        the scorer (the step donates what it is given, and a step on the
+        scorer itself would advance Adam's count)."""
+        if not self.enabled:
+            return
+        if self._anomaly_step is not None:
+            counts = np.zeros_like(self._container_counts)
+            _scorer, scores = self._anomaly_step(
+                jax.tree.map(jnp.array, self.scorer), counts,
+                np.zeros(len(counts), np.float32))
+            jax.block_until_ready(scores)
+        if self._shard_on:
             return
         rows = STEP_ROWS_FLOOR
         while rows <= self._pad and not self.ctx.done:
@@ -1430,15 +1478,15 @@ class TpuSketchInstance(OperatorInstance):
             # vectorized slice writes park a small (k64, k32, comm) sample in
             # the rolling ring; name resolution happens at harvest/seal time
             self._label_sample(batch, hh, n)
-            if self.anomaly_on:
-                self._accumulate_container_dists(batch, n)
 
         self._absorb_staged(
             stager, staged, (hh, distinct, w), n, t0, drops=batch.drops,
             pop_ts=batch.pop_ts, oldest_ts=oldest, mntns=mntns, vals=vals,
             slices=lambda: self._accumulate_slices(batch, n, hh, distinct,
                                                    dist),
-            late=late)
+            late=late,
+            dists=((lambda: self._accumulate_container_dists(batch, n))
+                   if self.anomaly_on else None))
 
     def ingest_folded(self, fb: FoldedBatch) -> None:
         """Zero-copy ingest of a pre-folded SoA batch (ig_source_pop_folded
@@ -1479,7 +1527,7 @@ class TpuSketchInstance(OperatorInstance):
                        n: int, t0: float, *, drops: int, pop_ts: float,
                        oldest_ts: float, mntns: np.ndarray | None,
                        vals: np.ndarray | None, slices=None,
-                       late=None) -> None:
+                       late=None, dists=None) -> None:
         """The one dispatch of a staged batch of `n` events, whichever
         adapter staged it (at `t0`): `staged` is the device arrays (hh,
         distinct, dist, weights, values or None) of the stager's last
@@ -1561,6 +1609,9 @@ class TpuSketchInstance(OperatorInstance):
             self._shadow_feed(hh[:n], w[:n])
             if late is not None:
                 late()
+        if dists is not None:
+            with self._st_dists:
+                dists()
         if self._hist_on and self._hist_interval > 0 and \
                 self._hist_clock() - self._win_start >= self._hist_interval:
             self.seal_window()
@@ -1682,13 +1733,31 @@ class TpuSketchInstance(OperatorInstance):
                 if len(seq) > w:
                     del seq[:-w]
             return
+        # one grouped pass: each event's slot behind the mntns -> slot
+        # table, then one scatter-add into the flat [slots * dim] array
         dim = self._ae_cfg.input_dim
-        buckets = (keys % np.uint64(dim)).astype(np.int64)
-        for ns in np.unique(mntns):
-            sel = mntns == ns
-            vec = self._container_counts.setdefault(
-                int(ns), np.zeros(dim, dtype=np.float32))
-            np.add.at(vec, buckets[sel], 1.0)
+        slot = self._containers.slots_of(mntns)
+        counts = self._container_counts
+        if len(self._containers) > len(counts):
+            rows = len(counts)
+            while rows < len(self._containers):
+                rows *= 2
+            grown = np.zeros((rows, dim), dtype=np.float32)
+            grown[:len(counts)] = counts
+            counts = self._container_counts = grown
+            self._m_anomaly_slots.set(rows)
+        flat = slot * dim
+        flat += (keys % np.uint64(dim)).astype(np.intp)
+        # the addend in the array's own type: a Python 1.0 is a float64,
+        # which sends `ufunc.at` down its casting path, thirty times slower
+        np.add.at(counts.reshape(-1), flat, np.float32(1.0))
+
+    def container_distributions(self) -> tuple[list[int], np.ndarray]:
+        """The containers seen (mntns, by slot) and a copy of their
+        `[containers, dim]` count rows (`ae` / `vae`; loop thread, or
+        after the run)."""
+        ids = self._containers.ids()
+        return ids, self._container_counts[:len(ids)].copy()
 
     def _seq_score_containers(self) -> dict[int, float] | None:
         """Train the sequence LM one step on all container windows and
@@ -1942,11 +2011,39 @@ class TpuSketchInstance(OperatorInstance):
                 # donates these buffers); the quantile math runs on the
                 # host copies outside it
                 qt_now = self._qt_host(merged)
+            scores_d = None
+            if self._anomaly_step is not None and len(self._containers):
+                # the scorer's one program, dispatched behind the digest so
+                # the device runs both while the host waits once; under the
+                # lock because the step donates the scorer the
+                # checkpointer reads. The counts go over as they stand (the
+                # scores are read back below, before the next batch adds
+                # to them)
+                t_score = time.perf_counter_ns()
+                rows = self._container_counts
+                live = np.zeros(len(rows), np.float32)
+                live[:len(self._containers)] = 1.0
+                self.scorer, scores_d = self._anomaly_step(
+                    self.scorer, rows, live)
+                self._turn.note_anomaly_score(
+                    time.perf_counter_ns() - t_score)
         # the one blocking read of the tick, counted apart from the stage
         t_wait = time.perf_counter_ns()
         events_f, drops_f, distinct, entropy_bits, approx, keys, counts = (
             decode_digest(digest))
         self._turn.note_harvest_wait(time.perf_counter_ns() - t_wait)
+        anomaly = None
+        if self.anomaly_on:
+            t_score = time.perf_counter_ns()
+            if self.anomaly_model == "seq":
+                anomaly = self._seq_score_containers()
+            elif scores_d is not None:
+                # filler rows' scores fall off the end of the zip
+                anomaly = dict(zip(self._containers.ids(),
+                                   np.asarray(scores_d).tolist()))
+                self._anomaly_steps += 1
+                self._m_anomaly_steps.inc()
+            self._turn.note_anomaly_score(time.perf_counter_ns() - t_score)
         if approx and not self._overflow_counted:
             # count RUNS that crossed into approximation, not harvests:
             # the flag is latched, so one inc per instance is the honest
@@ -2031,6 +2128,10 @@ class TpuSketchInstance(OperatorInstance):
         if self._hist_on and self._last_slices is not None:
             # what the last sealed window's slice store held
             pipe_out["slices"] = dict(self._last_slices)
+        if self._anomaly_step is not None:
+            pipe_out["anomaly"] = {"steps": self._anomaly_steps,
+                                   "containers": len(self._containers),
+                                   "slots": len(self._container_counts)}
         for stage, row in pipe_out["stages"].items():
             with self._span(f"tpusketch/stage/{stage}",
                             watermark_s=row["watermark_s"],
@@ -2070,21 +2171,6 @@ class TpuSketchInstance(OperatorInstance):
         # late enrichment: names resolve HERE (once per tick, from the
         # sample ring), not in the per-batch ingest path
         self._resolve_late([k for k, _ in hh[:32]])
-        anomaly = None
-        if self.anomaly_on and self.anomaly_model == "seq":
-            anomaly = self._seq_score_containers()
-        elif self.anomaly_on and self._container_counts:
-            mats = np.stack(list(self._container_counts.values()))
-            x = normalize_counts(jnp.asarray(mats))
-            if self.anomaly_model == "vae":
-                from ..models.vae import vae_score, vae_train_step
-                self.scorer, _ = vae_train_step(self.scorer, x)
-                scores = np.asarray(vae_score(self.scorer, x))
-            else:
-                self.scorer, _ = ae_train_step(self.scorer, x)
-                scores = np.asarray(ae_score(self.scorer, x))
-            anomaly = {ns: float(s) for ns, s in
-                       zip(self._container_counts.keys(), scores)}
         self._epoch += 1
         summary = SketchSummary(
             events=int(events_f),
@@ -2158,6 +2244,8 @@ class TpuSketchInstance(OperatorInstance):
                 _queries_engine.unregister(self.ctx.run_id)
             self._stats.unregister()
             self._pstats.unregister()
+            if self._anomaly_step is not None:
+                self._m_anomaly_slots.set(0)
             if self._astats is not None:
                 self._astats.unregister()
             if _ckpt_dir is not None:

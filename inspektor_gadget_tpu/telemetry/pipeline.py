@@ -69,24 +69,36 @@ _tm_occupancy = gauge(
 # the stages of a turn, in the order of the turn; each is also the host
 # annotation `ig:<name>` on the profiler's clock (siblings, never nested)
 TURN_STAGES = (
-    "source_wait", "source_pop", "source_filter", "operator_other",
-    "tpusketch_fold", "tpusketch_h2d", "tpusketch_shard_restage",
-    "tpusketch_update", "tpusketch_window_planes", "tpusketch_slices",
-    "tpusketch_inv_classes", "tpusketch_post", "tpusketch_seal",
-    "tpusketch_shard_merge", "tpusketch_harvest", "runtime_deliver")
+    "source_wait", "source_pop", "source_filter", "gadget_record",
+    "operator_other", "tpusketch_fold", "tpusketch_h2d",
+    "tpusketch_shard_restage", "tpusketch_update", "tpusketch_window_planes",
+    "tpusketch_slices", "tpusketch_inv_classes", "tpusketch_post",
+    "tpusketch_container_dists", "tpusketch_seal", "tpusketch_shard_merge",
+    "tpusketch_harvest", "runtime_deliver")
 # stages only a run under shard-ingest opens. A one-chip run carries none
 # of their names: no counter child, no key in the `pipeline` block
 SHARD_STAGES = ("tpusketch_shard_restage", "tpusketch_shard_merge")
-# the blocking read of the digest: a part of tpusketch_harvest counted
-# apart, so it rides the same array but is no stage (it tiles nothing)
+# likewise: the gadget's own `process_batch`, which only a gadget that
+# overrides it has (taken out of source_filter), and the per-container
+# distributions of a run with the anomaly scorer on (out of tpusketch_post)
+RECORD_STAGE = "gadget_record"
+DISTS_STAGE = "tpusketch_container_dists"
+OPTIONAL_STAGES = SHARD_STAGES + (RECORD_STAGE, DISTS_STAGE)
+# parts of tpusketch_harvest counted apart: they ride the same array but
+# are no stages (they tile nothing). The blocking read of the digest; and,
+# only with the anomaly scorer on, the scorer's dispatch, put and read-back
 HARVEST_WAIT = "harvest_wait"
-_TURN_SLOTS = TURN_STAGES + (HARVEST_WAIT,)
+ANOMALY_SCORE = "anomaly_score"
+_TURN_SLOTS = TURN_STAGES + (HARVEST_WAIT, ANOMALY_SCORE)
+_I_WAIT = _TURN_SLOTS.index(HARVEST_WAIT)
+_I_SCORE = _TURN_SLOTS.index(ANOMALY_SCORE)
 SLOW_TURNS = 4    # longest turns of a run kept with their stage split
 
 _tm_turn_seconds = counter(
     "ig_pipeline_turn_seconds_total",
-    "Loop-thread seconds per stage of the batch turn (harvest_wait is "
-    "the blocking digest read inside tpusketch_harvest)", ("stage",))
+    "Loop-thread seconds per stage of the batch turn (harvest_wait, the "
+    "blocking digest read, and anomaly_score, the scorer's dispatch and "
+    "read-back, are parts of tpusketch_harvest)", ("stage",))
 _tm_turns = counter(
     "ig_pipeline_turns_total", "Batch turns a gadget run's loop published")
 
@@ -174,6 +186,9 @@ class PipelineStats:
         self._turns = 0
         self._turn_wall_ns = 0
         self._turn_ns = [0] * len(_TURN_SLOTS)
+        # the OPTIONAL_STAGES this run opened (TurnClock.attach shares the
+        # clock's set): only they get a key in the `turn` block
+        self._opened: set[str] = set()
         self._slow: list[tuple[int, int, float, int, tuple[int, ...]]] = []
         self._slow_floor = 0   # a turn must outlast this to enter _slow
 
@@ -231,6 +246,7 @@ class PipelineStats:
         block and the stages of SHARD_STAGES."""
         with self._mu:
             self._lane_events = [0] * chips
+            self._opened.update(SHARD_STAGES)
 
     def note_lane_events(self, lane: int, events: int) -> None:
         with self._mu:
@@ -290,6 +306,7 @@ class PipelineStats:
                 "rounds_flushed": self._rounds_flushed,
                 "filler_lanes": self._filler_lanes,
                 "lane_events": list(self._lane_events)}} if sharded else {}
+            opened = self._opened
             return {
                 "stages": stages,
                 "host_lag_s": stages.get("pop", {}).get("watermark_s", 0.0),
@@ -307,8 +324,10 @@ class PipelineStats:
                     "wall_s": self._turn_wall_ns * 1e-9,
                     "stages": {n: v * 1e-9 for n, v in
                                zip(TURN_STAGES, self._turn_ns)
-                               if sharded or n not in SHARD_STAGES},
-                    "harvest_wait_s": self._turn_ns[-1] * 1e-9,
+                               if n not in OPTIONAL_STAGES or n in opened},
+                    "harvest_wait_s": self._turn_ns[_I_WAIT] * 1e-9,
+                    **({"anomaly_score_s": self._turn_ns[_I_SCORE] * 1e-9}
+                       if ANOMALY_SCORE in opened else {}),
                 },
                 "slow_turns": [
                     {"wall_s": wall * 1e-9, "cpu_s": cpu * 1e-9,
@@ -377,11 +396,13 @@ class TurnClock:
         self._ns = [0] * len(_TURN_SLOTS)
         self._stages = {name: _Stage(self._ns, i, "ig:" + name)
                         for i, name in enumerate(TURN_STAGES)}
-        # a sharding-only stage gets its counter child with its first
-        # nanosecond, so a one-chip run's registry has no such label
-        self._children = [None if name in SHARD_STAGES
+        # an optional stage gets its counter child with its first
+        # nanosecond, so the registry of a run that never opens it has no
+        # such label
+        self._children = [None if name in OPTIONAL_STAGES + (ANOMALY_SCORE,)
                           else _tm_turn_seconds.labels(stage=name)
                           for name in _TURN_SLOTS]
+        self._opened: set[str] = set()
         self._stats: PipelineStats | None = None
         self._seq = 0
         self.begin()
@@ -389,6 +410,8 @@ class TurnClock:
     def attach(self, stats: PipelineStats) -> None:
         """Published turns also go to `stats` (the run's `pipeline`
         block: run totals and the longest turns)."""
+        stats._opened |= self._opened
+        self._opened = stats._opened
         self._stats = stats
 
     def stage(self, name: str) -> _Stage:
@@ -396,8 +419,16 @@ class TurnClock:
         enter it every turn."""
         return self._stages[name]
 
+    def open_stages(self, *names: str) -> None:
+        """The run will time these OPTIONAL_STAGES (or ANOMALY_SCORE):
+        they get their keys in the `pipeline` block from now on."""
+        self._opened.update(names)
+
     def note_harvest_wait(self, ns: int) -> None:
-        self._ns[-1] += ns
+        self._ns[_I_WAIT] += ns
+
+    def note_anomaly_score(self, ns: int) -> None:
+        self._ns[_I_SCORE] += ns
 
     def begin(self) -> None:
         """The loop starts here: the first turn's wall runs from now."""

@@ -47,8 +47,8 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
     for line in lines[:-1]:
         phases[json.loads(line)["phase"]] = json.loads(line)
     assert list(phases) == ["acquire", "step-times", "local-runtime",
-                            "invertible", "quantiles", "narrow", "agent",
-                            "done"]
+                            "invertible", "quantiles", "narrow", "anomaly",
+                            "agent", "done"]
     # truthful about what ran: no kernel on the CPU, and it says so, at
     # the run's geometry and at the one where a TPU takes the kernel
     for times in (phases["step-times"], phases["step-times"]["narrow"]):
@@ -64,6 +64,13 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
     assert local["events_absorbed"] == local["events_offered"] - local["drops"]
     assert local["generator"] == "native C++ synthetic"
     assert phases["invertible"]["decoded_equals_exact"] is True
+    # the scorer met the plain replay, and each planted fault did not
+    anomaly = phases["anomaly"]
+    assert anomaly["histograms_exact"] and anomaly["profile_exact"]
+    assert anomaly["score_gap"] <= anomaly["tolerance"]
+    assert all(gap > anomaly["tolerance"]
+               for gap in anomaly["fault_gaps"].values())
+    assert (anomaly["containers"], anomaly["slots"]) == (64, 64)
     agent = phases["agent"]
     assert agent["checkpoints"] >= 2 and agent["checkpoint_failures"] == 0
     assert agent["generator"] == "native C++ synthetic"

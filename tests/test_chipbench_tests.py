@@ -29,6 +29,15 @@ LEFT_OUT = {
     # milliseconds a turn), tests/test_shard_host_rehearsal.py the
     # four-chip cell's names; a `benchmark` issue restates the bound.
     "test_every_turn_metric_comes_out_as_a_rehearsal",
+    # chipbench/tests/test_host4_cell.py holds `exec-host4` to the LAST
+    # place of `configs` and `workloads` and PR 27's five entries to the
+    # END of `per_layer`; `seccomp-node`, its cell and its five metrics
+    # follow them (ISSUE 31), as the contract orders new entries, so both
+    # fail by design. chipbench/tests/test_seccomp_cell.py holds the file
+    # to the data, every accepted entry included, by places counted from
+    # the front; a `benchmark` issue restates the two (PERF.md section 7).
+    "test_the_cell_and_its_configuration_are_the_files",
+    "test_the_metric_lists_are_what_the_files_give",
 }
 
 for _path in sorted((Path(__file__).resolve().parents[1]

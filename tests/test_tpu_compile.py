@@ -163,6 +163,26 @@ def test_seal_window_read_compiles_as_one_program(one_chip):
     jax.jit(_wcms_window_step).lower(wcms, cand).compile()
 
 
+def test_anomaly_step_compiles_at_the_configurations_size(one_chip):
+    """The scorer's one program a harvest (models/autoencoder.py
+    `anomaly_step`) at seccomp-node's size: 64 rows of 4,096 buckets
+    through 4096-256-64, bf16 products on f32 parameters, the scorer
+    donated so parameters and Adam's moments are updated in place."""
+    from inspektor_gadget_tpu.models.autoencoder import (AEConfig, ae_init,
+                                                         anomaly_step)
+    cfg = AEConfig(input_dim=4096, hidden_dim=256, latent_dim=64)
+    scorer = _on(one_chip, jax.eval_shape(lambda: ae_init(cfg)))
+    counts = jax.ShapeDtypeStruct((64, 4096), jnp.float32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+    new, scores = jax.eval_shape(anomaly_step, scorer, counts, mask)
+    assert scores.shape == (64,) and scores.dtype == jnp.float32
+    compiled = anomaly_step.lower(scorer, counts, mask).compile()
+    mem = compiled.memory_analysis()
+    # parameters and two moments, 25.5 MB, alias their outputs
+    state = 3 * 4 * (2 * (4096 * 256 + 256 * 64) + 256 + 64 + 256 + 4096)
+    assert mem.alias_size_in_bytes >= state
+
+
 def test_sharded_harvest_compiles_with_its_collectives(topo):
     """The collective harvest over a 4-chip (node) mesh: psum/pmax for the
     additive planes and registers, all-gather for the candidate union."""

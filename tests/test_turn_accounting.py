@@ -24,8 +24,9 @@ from inspektor_gadget_tpu.params import Collection
 from inspektor_gadget_tpu.runtime.local import LocalRuntime
 from inspektor_gadget_tpu.telemetry import snapshot, tracing
 from inspektor_gadget_tpu.telemetry.pipeline import (
+    ANOMALY_SCORE,
     HARVEST_WAIT,
-    SHARD_STAGES,
+    OPTIONAL_STAGES,
     SLOW_TURNS,
     TURN_STAGES,
     PipelineStats,
@@ -34,9 +35,11 @@ from inspektor_gadget_tpu.telemetry.pipeline import (
 from inspektor_gadget_tpu.telemetry.tracing import TRACER
 
 STEPS = 'ig_tpusketch_steps_total{gadget="trace/exec"}'
-# what a one-chip run carries, and of it what a run with history on and no
-# priority classes must have timed
-ONE_CHIP = set(TURN_STAGES) - set(SHARD_STAGES)
+# what a one-chip run of a gadget that records nothing, with the anomaly
+# scorer off, carries (the stages it had before ISSUE 31 named the optional
+# ones), and of it what a run with history on and no priority classes must
+# have timed
+ONE_CHIP = set(TURN_STAGES) - set(OPTIONAL_STAGES)
 TIMED = ONE_CHIP - {"source_wait", "tpusketch_inv_classes"}
 
 
@@ -115,13 +118,15 @@ def recorded_run():
     tracing._annotation_cls = RecordingAnnotation
     RecordingAnnotation.log = []
     TRACER.reset()
-    steps0 = snapshot().get(STEPS, 0.0)
+    before = snapshot()
+    steps0 = before.get(STEPS, 0.0)
     try:
         with tempfile.TemporaryDirectory(prefix="turn-hist-") as d:
             pipe, batches = run_once(150, d)
     finally:
         tracing._annotation_cls = saved
-    return {"pipe": pipe, "batches": batches,
+    return {"pipe": pipe, "batches": batches, "before": before,
+            "after": snapshot(),
             "steps": snapshot().get(STEPS, 0.0) - steps0,
             "annotations": list(RecordingAnnotation.log),
             "thread": threading.get_ident(),
@@ -179,6 +184,30 @@ def test_annotations_are_siblings_that_cover_the_loop(recorded_run):
     assert sum(kept) <= 500_000 * len(kept)
 
 
+def test_a_run_without_the_scorer_carries_none_of_its_names(recorded_run):
+    """`trace exec` records nothing of its own and the scorer is off: no
+    optional stage, counted part, `pipeline` key, counter child or
+    annotation of ISSUE 31 (or of shard-ingest) shows in the run."""
+    pipe = recorded_run["pipe"]
+    assert not set(OPTIONAL_STAGES) & set(pipe["turn"]["stages"])
+    assert "anomaly" not in pipe and "anomaly_score_s" not in pipe["turn"]
+    assert set(pipe["turn"]) == {"turns", "wall_s", "stages",
+                                 "harvest_wait_s"}
+    before, after = recorded_run["before"], recorded_run["after"]
+    for key, value in after.items():
+        mine = (any(f'stage="{s}"' in key
+                    for s in (*OPTIONAL_STAGES, ANOMALY_SCORE))
+                or (key.startswith("ig_tpusketch_anomaly")
+                    and 'gadget="trace/exec"' in key))
+        # another file's run in this process may have left such a child
+        # behind; this run added nothing to it
+        assert not mine or value == before.get(key), key
+    named = {n for n, _th, _a, _b in recorded_run["annotations"]}
+    assert not {"ig:" + s for s in OPTIONAL_STAGES} & named
+    assert all(t["stages"].keys() <= ONE_CHIP | {HARVEST_WAIT}
+               for t in pipe["slow_turns"])
+
+
 def test_nothing_per_stage_reaches_the_tracer_ring(recorded_run):
     names = [r.name for r in recorded_run["spans"]]
     turns = recorded_run["pipe"]["turn"]["turns"]
@@ -223,7 +252,7 @@ def test_a_slow_stage_leads_the_slowest_turn(monkeypatch):
 
 def test_slow_turns_keep_the_longest_four():
     stats = PipelineStats("run-turn-unit")
-    ns = [0] * (len(TURN_STAGES) + 1)
+    ns = [0] * (len(TURN_STAGES) + 2)
     for seq, wall in enumerate([5, 1, 9, 3, 7, 2, 8, 4, 6], start=1):
         ns[1] = wall
         stats.note_turn(ns, wall, wall, float(seq), seq)
