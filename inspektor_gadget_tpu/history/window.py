@@ -296,10 +296,14 @@ class WindowSlices:
     # -- the batch ----------------------------------------------------------
 
     def absorb(self, mntns: np.ndarray, kind: np.ndarray, hh: np.ndarray,
-               distinct: np.ndarray, dist: np.ndarray | None = None) -> None:
+               distinct: np.ndarray, dist: np.ndarray | None = None, *,
+               fold: bool = True) -> None:
         """Absorb one batch: lanes of equal length, one event each
         (weights are not the slices' business). Without `dist` the
-        distribution stream is the `distinct` lane, hashed once."""
+        distribution stream is the `distinct` lane, hashed once. A caller
+        that has to keep this call short says `fold=False`: a backlog that
+        has outgrown the table then waits for a later call (its sort is
+        10-20 ms at a few hundred thousand events)."""
         if not len(hh):
             return
         ordinal = self._batches
@@ -337,8 +341,8 @@ class WindowSlices:
         word |= np.uint64(ordinal - self._backlog_from)
         self._backlog.append(word)
         self._backlog_events += len(word)
-        if self._backlog_events > max(_BACKLOG_PER_ENTRY * len(self._hh_keys),
-                                      _MIN_BACKLOG_EVENTS):
+        if fold and self._backlog_events > max(
+                _BACKLOG_PER_ENTRY * len(self._hh_keys), _MIN_BACKLOG_EVENTS):
             self._compact()
 
     # -- heavy hitters ------------------------------------------------------

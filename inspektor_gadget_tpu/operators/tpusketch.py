@@ -139,6 +139,22 @@ _tm_slice_hh_entries = counter(
     "ig_history_slice_hh_entries_total",
     "(cell, key) entries of the slices' exact heavy-hitter table at each "
     "window seal: what the seal's slice work follows", ("gadget",))
+# the two halves of a seal (labelled likewise): who finished the window, the
+# boundaries that found the worker still on the one before, and what a
+# finish takes wherever it runs
+_tm_seals = counter(
+    "ig_tpusketch_seals_total",
+    "windows sealed, by who ran the finish: the seal worker (the served "
+    "path's interval-driven seals) or the caller of seal_window()",
+    ("gadget", "finish"))
+_tm_seal_waits = counter(
+    "ig_tpusketch_seal_waits_total",
+    "window boundaries at which the loop thread waited for the seal worker "
+    "to finish the window before", ("gadget",))
+_tm_seal_finish_s = histogram(
+    "ig_tpusketch_seal_finish_seconds",
+    "a window's finish off the loop thread's state: read-backs, sort, "
+    "slices, digest, append, hooks", ("gadget",))
 # anomaly scorer on only (labelled likewise)
 _tm_anomaly_steps = counter(
     "ig_tpusketch_anomaly_steps_total",
@@ -190,6 +206,18 @@ _ckpt_log = get_logger("ig-tpu.tpusketch")
 # the ladder is primed before the source starts (`pre_gadget_run`).
 STEP_ROWS_FLOOR = 8192
 
+# How long before a summary is due the history window's deferrable work
+# stands still (at most: a quarter of harvest-interval where that is
+# shorter): the seal worker starts no further step of a finish, and the
+# loop thread leaves the slices' backlog unfolded. Two threads of one
+# interpreter share its lock turn and turn about, so a finish in flight
+# doubles the loop's turn while it runs, and a summary whose turns it shares
+# reads 9-20 ms where the others read 6; a backlog fold is 10-20 ms of one
+# turn (exec-node.paced, PR 33). The worker asks between its steps, the
+# longest of which (the slices' fold) is 20-40 ms under that sharing at 131k
+# keys. A constant, not an option.
+SEAL_QUIET_S = 0.06
+
 # Rows the per-container distribution array starts with, and the scorer's
 # program is primed at: a power of two that doubles when the containers
 # outgrow it. A constant, not an option.
@@ -197,10 +225,9 @@ CONTAINER_SLOTS_FLOOR = 64
 
 # window-plane device steps (history sealing): the WindowedCMS ring
 # rotates at each boundary (current slot = this window's CMS) and a
-# fresh HLL per window tracks its distinct stream; entropy and
-# events/drops come as deltas of the cumulative bundle (additive state
-# is exactly subtractable, HLL is not)
-_wcms_advance_jit = jax.jit(wcms_advance, donate_argnums=0)
+# fresh HLL per window tracks its distinct stream (`_wcms_window_step`);
+# entropy and events/drops come as deltas of the cumulative bundle
+# (additive state is exactly subtractable, HLL is not)
 
 
 # The ingest step is ops.sketches.bundle_ingest_jit: staged uint32 weights
@@ -230,17 +257,53 @@ def _inv_class_ingest_step(s, keys, weights):
     return out, out.count[0, :1] + 0
 
 
-def _wcms_window_step(w, cand):
-    """What a seal reads of the window CMS, as one program: the ring's
-    current slot and the candidates' estimates against it. Eagerly the
-    hashes and gathers are some ninety dispatches a seal."""
-    return w.slots[w.epoch], wcms_query(w, cand, last_k=1)
+def _wcms_window_step(w, cand, h):
+    """A seal's share of the window planes, as one program: what its
+    finish reads of the window CMS (the ring's current slot and the
+    candidates' estimates against it: eagerly the hashes and gathers are
+    some ninety dispatches a seal), and the planes of the next window (the
+    ring advanced, an HLL of `h`'s shape with its registers at zero).
+    Nothing is donated: the slot and `h` are read after the capture."""
+    return (w.slots[w.epoch], wcms_query(w, cand, last_k=1), wcms_advance(w),
+            h.replace(registers=jnp.zeros_like(h.registers)))
+
+
+def _seal_snapshot(b):
+    """What a seal reads of the (merged) bundle, in the order `_snapshot_host`
+    unpacks: jitted, these are copies that the next ingest step, which
+    donates the bundle, leaves alone."""
+    inv = None if b.inv is None else (b.inv.count, b.inv.keysum, b.inv.fpsum)
+    qt = (None if b.quantiles is None else
+          (b.quantiles.counts, b.quantiles.zeros, b.quantiles.total))
+    return (b.events, b.drops, b.entropy.counts, b.topk.keys,
+            b.topk.overflow, inv, qt)
 
 
 _wcms_ingest_jit = jax.jit(_wcms_ingest_step, donate_argnums=0)
 _wcms_window_jit = jax.jit(_wcms_window_step)
+_seal_snapshot_jit = jax.jit(_seal_snapshot)
 _hll_ingest_jit = jax.jit(_hll_ingest_step, donate_argnums=0)
 _inv_class_jit = jax.jit(_inv_class_ingest_step, donate_argnums=0)
+
+
+@dataclasses.dataclass
+class _WindowCapture:
+    """What `_capture_window` takes of a window at its batch boundary, for
+    `_finish_window` to make a SealedWindow of on any thread: device arrays
+    that nothing donates, the structures it swapped out of the instance,
+    and copies of the little host state a seal reads."""
+
+    window: int
+    start_ts: float
+    end_ts: float
+    snap: tuple                 # _seal_snapshot of the (merged) bundle
+    cms: Any                    # the window CMS's slot of this window ...
+    counts: Any                 # ... and the candidates' estimates in it
+    hll: Any                    # the window HLL's registers
+    slices: Any                 # the window's WindowSlices
+    shadow: tuple | None        # window shadow sample: keys, weights, capacity
+    labels: tuple               # _labels_as_found: the label sample ring
+    names: dict[int, str]       # the names cache
 
 
 @dataclasses.dataclass
@@ -855,16 +918,30 @@ class TpuSketchInstance(OperatorInstance):
             self._win_hll = hll_init(p.get("hll-p").as_int())
             self._win_n = 0
             self._win_start = self._hist_clock()
-            self._win_events0 = 0.0
-            self._win_drops0 = 0.0
-            self._win_ent0 = np.asarray(self.bundle.entropy.counts).copy()
-            self._win_inv0 = self._inv_host(self.bundle)
-            self._win_qt0 = self._qt_host(self.bundle)
             from ..history import HISTORY, WindowSlices
             self._win_slices = WindowSlices(self._hist_max_slices)
             self._last_slices: dict[str, int] | None = None
-            self._m_slice_hh = _tm_slice_hh_entries.labels(
-                gadget=ctx.desc.full_name)
+            self._m_slice_hh = _tm_slice_hh_entries.labels(gadget=g)
+            # the two halves of a seal: `_capture_window` on the thread
+            # that owns the live state, `_finish_window` on the caller of
+            # seal_window() or, for the served path's interval-driven
+            # seals, on one worker thread a window, joined at the next
+            # boundary: it holds one window at most, and windows reach the
+            # store in order
+            self._seal_thread: threading.Thread | None = None
+            # set: the worker may start its next step (`_seal_step`); the
+            # loop thread clears it while a summary is nearly due
+            # (`_summary_near`, asked once a turn)
+            self._seal_clear = threading.Event()
+            self._seal_clear.set()
+            self._seal_quiet = min(SEAL_QUIET_S, self.harvest_interval / 4)
+            self._summary_near = False
+            self._seal_stats = {"worker": 0, "caller": 0, "waited": 0,
+                                "finish_ms_last": 0.0, "finish_ms_max": 0.0}
+            self._m_seals = {f: _tm_seals.labels(gadget=g, finish=f)
+                             for f in ("worker", "caller")}
+            self._m_seal_waits = _tm_seal_waits.labels(gadget=g)
+            self._m_seal_finish_s = _tm_seal_finish_s.labels(gadget=g)
             try:
                 self._hist_writer = HISTORY.writer_for(
                     self._hist_gadget, node=ctx.extra.get("node", "") or "",
@@ -955,13 +1032,14 @@ class TpuSketchInstance(OperatorInstance):
         self._ckpt_key = ctx.desc.full_name.replace("/", "-")
         self._resume()
         if self._hist_on:
-            # window-open snapshots AFTER resume: window deltas must
-            # exclude the prior state bundle_merge just absorbed
-            self._win_events0 = float(self.bundle.events)
-            self._win_drops0 = float(self.bundle.drops)
-            self._win_ent0 = np.asarray(self.bundle.entropy.counts).copy()
-            self._win_inv0 = self._inv_host(self.bundle)
-            self._win_qt0 = self._qt_host(self.bundle)
+            # the first window's baseline, AFTER resume: window deltas must
+            # exclude the prior state bundle_merge just absorbed. Every
+            # later one is the snapshot of the window before, which its
+            # finish leaves here (finishes run one at a time, in order;
+            # the loop thread never reads it). The empty-window test is
+            # the host's own count of events, so it reads nothing back
+            self._win_base = self._snapshot_host(_seal_snapshot(self.bundle))
+            self._win_host_events = self._stats.events
         with _live_mu:
             _live[ctx.run_id] = self
 
@@ -987,27 +1065,14 @@ class TpuSketchInstance(OperatorInstance):
         self._pstats.note_host_lag(pop_ts - oldest_ts, lane)
         self._pstats.note_device_lag(max(now - pop_ts, 0.0), lane)
 
-    # -- invertible plane helpers (ISSUE 15) --------------------------------
-
-    @staticmethod
-    def _inv_host(b) -> tuple | None:
-        """Host snapshot of the bundle's invertible lanes (window-open
-        baseline for seal deltas). Caller must hold _bundle_mu when `b`
-        is the live bundle (the next update donates its buffers)."""
-        if b.inv is None:
-            return None
-        return (np.asarray(b.inv.count).astype(np.int64).copy(),
-                np.asarray(b.inv.keysum).copy(),
-                np.asarray(b.inv.fpsum).copy())
-
     # -- latency quantile plane helpers (ISSUE 16) --------------------------
 
     @staticmethod
     def _qt_host(b) -> tuple | None:
         """Host snapshot of the bundle's DDSketch lanes (counts int64,
-        zeros, total) — window-open baseline for seal deltas and the
-        harvest's quantile read. Caller must hold _bundle_mu when `b` is
-        the live bundle (the next update donates its buffers)."""
+        zeros, total) for the harvest's quantile read. Caller must hold
+        _bundle_mu when `b` is the live bundle (the next update donates
+        its buffers)."""
         if b.quantiles is None:
             return None
         return (np.asarray(b.quantiles.counts).astype(np.int64).copy(),
@@ -1051,6 +1116,8 @@ class TpuSketchInstance(OperatorInstance):
         self._shadow.update(keys, weights)
         self._win_shadow.update(keys, weights)
         self._astats.note_fed(int(np.asarray(keys).size))
+
+    # -- invertible plane helpers (ISSUE 15) --------------------------------
 
     @staticmethod
     def _padded_mntns(batch: EventBatch, n: int, pad: int) -> np.ndarray:
@@ -1118,10 +1185,12 @@ class TpuSketchInstance(OperatorInstance):
         becomes the empty key in the top-k, so the state stays what it was
         (the filler lanes of a flushed sharded round rest on the same
         property) and nothing is counted. The sharded step has one shape
-        and compiles with the first round, as before. The anomaly scorer's
+        and compiles with the first round, as before (and a seal's programs
+        with its first seal). The anomaly scorer's
         one program is primed at the slots it starts with, on a copy of
         the scorer (the step donates what it is given, and a step on the
-        scorer itself would advance Adam's count)."""
+        scorer itself would advance Adam's count). With history on, the
+        two programs a seal's capture dispatches are run once too."""
         if not self.enabled:
             return
         if self._anomaly_step is not None:
@@ -1132,6 +1201,13 @@ class TpuSketchInstance(OperatorInstance):
             jax.block_until_ready(scores)
         if self._shard_on:
             return
+        if self._hist_on:
+            # the two programs a seal's capture dispatches, on the state
+            # as it stands: they donate nothing
+            with self._bundle_mu:
+                snap = _seal_snapshot_jit(self.bundle)
+            jax.block_until_ready(_wcms_window_jit(
+                self._wcms, self._beside_wcms(snap[3]), self._win_hll))
         rows = STEP_ROWS_FLOOR
         while rows <= self._pad and not self.ctx.done:
             z = jnp.asarray(np.zeros(rows, np.uint32))
@@ -1546,6 +1622,17 @@ class TpuSketchInstance(OperatorInstance):
         hh_d, distinct_d, dist_d, w_d, v_d = staged
         hh, distinct, w = host
         sharded = self._shard_on
+        if self._hist_on:
+            # a summary nearly due, or due in this turn: what can wait
+            # (the seal worker's next step, the slices' backlog fold)
+            # waits for the first turn behind it
+            self._summary_near = near = (
+                self._last_harvest + self.harvest_interval
+                - time.monotonic()) <= self._seal_quiet
+            if near:
+                self._seal_clear.clear()
+            else:
+                self._seal_clear.set()
         slot = stager.last_slot
         lane = self._next_lane if sharded else 0
         new_drops = max(drops - self._drops_seen, 0)
@@ -1612,13 +1699,16 @@ class TpuSketchInstance(OperatorInstance):
         if dists is not None:
             with self._st_dists:
                 dists()
-        if self._hist_on and self._hist_interval > 0 and \
-                self._hist_clock() - self._win_start >= self._hist_interval:
-            self.seal_window()
+        # both read this batch boundary: the summary goes first, so that
+        # not even a seal's capture stands in its way (history-interval 0
+        # orders them so inside harvest())
         now = time.monotonic()
         if now - self._last_harvest >= self.harvest_interval:
             self._last_harvest = now
             self.harvest()
+        if self._hist_on and self._hist_interval > 0 and \
+                self._hist_clock() - self._win_start >= self._hist_interval:
+            self._seal_at_boundary()
 
     def _window_planes(self, hh, distinct, w, slices) -> list:
         """The history window's share of a batch: the WindowedCMS's
@@ -1692,7 +1782,24 @@ class TpuSketchInstance(OperatorInstance):
                 self._lbl_comm[:rem] = 0
         self._lbl_i = (i + s) % cap
 
-    def _resolve_late(self, keys32) -> None:
+    def _labels_as_found(self) -> tuple:
+        """The label sample ring as a seal's finish reads it, maybe on
+        another thread: copies of its lanes, and the vocabulary's answers
+        for the rows the names cache does not cover, asked here in one
+        native crossing. The finish must not call into the gadget itself:
+        the run's teardown frees the sources' native handles before the
+        operators' `post_gadget_run` can wait for the worker."""
+        k32, k64 = self._lbl_k32.copy(), self._lbl_k64.copy()
+        by_k64: dict[int, str] = {}
+        bulk = getattr(self.gadget, "resolve_keys_bulk", None)
+        if bulk is not None:
+            known = np.fromiter(self._names, np.uint32, len(self._names))
+            ask = np.unique(k64[(k64 != 0) & ~np.isin(k32, known)])
+            by_k64 = dict(zip(ask.tolist(), bulk(ask)))
+        return k32, k64, self._lbl_comm.copy(), by_k64.get
+
+    def _resolve_late(self, keys32, ring: tuple | None = None,
+                      names: dict[int, str] | None = None) -> None:
         """Resolve display names for (few) heavy-hitter keys from the
         sample ring — runs once per harvest/seal tick, never per batch.
         A key ABSENT from the ring is left unresolved (not cached as
@@ -1700,24 +1807,30 @@ class TpuSketchInstance(OperatorInstance):
         cached placeholder would block resolution forever. A key found
         in the ring but yielding no vocab/comm name caches the hex
         fallback — that row really carried no name, matching the old
-        per-batch behavior."""
-        resolve = getattr(self.gadget, "resolve_key", None)
+        per-batch behavior. The harvest reads the live ring into the live
+        cache (loop thread); a seal's finish is handed what its capture
+        took of both (`ring` from `_labels_as_found`, `names` a copy)."""
+        lbl_k32, lbl_k64, lbl_comm, resolve = ring or (
+            self._lbl_k32, self._lbl_k64, self._lbl_comm,
+            getattr(self.gadget, "resolve_key", None))
+        if names is None:
+            names = self._names
         for k in keys32:
             k = int(k)
-            if not k or k in self._names:
+            if not k or k in names:
                 continue
-            j = np.flatnonzero(self._lbl_k32 == np.uint32(k))
+            j = np.flatnonzero(lbl_k32 == np.uint32(k))
             if not j.size:
                 continue  # not sampled yet — retry next tick
             jj = int(j[0])
-            k64 = int(self._lbl_k64[jj])
+            k64 = int(lbl_k64[jj])
             name = ""
             if resolve is not None and k64:
                 name = resolve(k64) or ""
             if not name:
-                comm = bytes(self._lbl_comm[jj])
+                comm = bytes(lbl_comm[jj])
                 name = comm.split(b"\0", 1)[0].decode("utf-8", "replace")
-            self._names[k] = name or f"0x{k:08x}"
+            names[k] = name or f"0x{k:08x}"
 
     def _accumulate_container_dists(self, batch: EventBatch, n: int) -> None:
         mntns = batch.cols["mntns"][:n]
@@ -1798,102 +1911,218 @@ class TpuSketchInstance(OperatorInstance):
             batch.cols["mntns"][:n], batch.cols["kind"][:n], hh[:n],
             distinct[:n],
             # one column for both streams is one lane, hashed once
-            None if self.dist_col == self.distinct_col else dist[:n])
+            None if self.dist_col == self.distinct_col else dist[:n],
+            fold=not self._summary_near)
 
     def seal_window(self) -> None:
         """Seal the open window into the history store: ONE frame, ONE
         O_APPEND write (a kill mid-seal tears at most this window, and
         the torn tail is dropped-and-accounted on read). Empty windows
         (no events since the last seal) are skipped — they carry no
-        state and would bloat the range index."""
+        state and would bloat the range index. Synchronous: whatever the
+        seal worker still holds is finished first, and the window is in
+        the store (and announced) when this returns."""
         with self._st_seal:
-            self._seal_window()
+            self._seal_drain()
+            cap = self._capture_window()
+            if cap is not None:
+                self._finish_window(cap, "caller")
 
-    def _seal_window(self) -> None:
-        from ..history import (HISTORY, SealedWindow, WindowSlices,
-                               window_digest)
+    def _seal_at_boundary(self) -> None:
+        """The served path's interval-driven seal: the capture here, on
+        the loop thread, the finish on a worker thread. No queue: a
+        boundary that finds the worker still on the window before waits
+        for it (counted), so at most one window is pending and windows
+        reach the store in order. The stage times the wait and the
+        capture: the stall a seal puts on the loop."""
+        with self._st_seal:
+            if self._seal_drain():
+                self._seal_stats["waited"] += 1
+                self._m_seal_waits.inc()
+            cap = self._capture_window()
+            if cap is not None:
+                self._seal_thread = threading.Thread(
+                    target=self._finish_on_worker, args=(cap,), daemon=True,
+                    name=f"tpusketch-seal-{self.ctx.run_id}")
+                self._seal_thread.start()
+
+    def _seal_drain(self) -> bool:
+        """Wait for the window the seal worker holds, if any (with its
+        gate open: the thread that waits here turns no batch meanwhile).
+        Says whether there was one to wait for."""
+        t = self._seal_thread
+        if t is None:
+            return False
+        busy = t.is_alive()
+        if busy:
+            self._seal_clear.set()
+        t.join()
+        self._seal_thread = None
+        return busy
+
+    def _beside_wcms(self, cand):
+        """The snapshot's candidate keys where the window CMS lives: under
+        shard-ingest the merged bundle is replicated over the mesh and the
+        window planes stay on chip 0."""
+        if not self._shard_on:
+            return cand
+        return jax.device_put(cand, self._wcms.slots.sharding)
+
+    def _capture_window(self) -> _WindowCapture | None:
+        """A seal's first half, on the thread that owns the live state:
+        everything that reads or swaps it, and nothing else. Two small
+        programs dispatched, no read-back, no sort, no file. None for an
+        empty window."""
+        from ..history import WindowSlices
         end = self._hist_clock()
-        with self._bundle_mu:
-            b = self._merged_locked()
-            events = float(b.events)
-            drops = float(b.drops)
-            ent_now = np.asarray(b.entropy.counts).copy()
-            cand = np.asarray(b.topk.keys).copy()
-            # the satellite bugfix: the candidate-overflow latch crosses
-            # the seal boundary — an overflowed run's windows carry
-            # approx=True so merged/historical answers stay tainted
-            overflow = bool(int(np.asarray(b.topk.overflow)))
-            inv_now = self._inv_host(b)
-            qt_now = self._qt_host(b)
-        win_events = int(events - self._win_events0)
-        if win_events <= 0 and not len(self._win_slices):
+        host_events = self._stats.events
+        if host_events == self._win_host_events and \
+                not len(self._win_slices):
             self._win_start = end
-            return
-        # window-only snapshots: the ring's CURRENT slot is this window's
-        # CMS; candidates re-estimated against it give the window top-k
-        cms, counts = _wcms_window_jit(self._wcms, jnp.asarray(cand))
-        cms = np.asarray(cms)
-        counts = np.asarray(counts).astype(np.int64)
+            return None
+        with self._bundle_mu:
+            # under shard-ingest the flush of the open round and the
+            # collective harvest: they are the state
+            snap = _seal_snapshot_jit(self._merged_locked())
+        # window-only state: the ring's CURRENT slot is this window's
+        # CMS; candidates re-estimated against it give the window top-k.
+        # The same program opens the next window's planes
+        hll = self._win_hll.registers
+        cms, counts, self._wcms, self._win_hll = _wcms_window_jit(
+            self._wcms, self._beside_wcms(snap[3]), self._win_hll)
+        self._win_n += 1
+        # accuracy audit plane: the WINDOW-scoped shadow sample rides the
+        # sealed window (copies: the live sample resets here)
+        shadow = None
+        if self._win_shadow is not None:
+            shadow = (self._win_shadow.keys.copy(),
+                      self._win_shadow.weights.copy(),
+                      int(self._win_shadow.capacity))
+            self._win_shadow.reset()
+        cap = _WindowCapture(
+            window=self._win_n, start_ts=float(self._win_start),
+            end_ts=float(end), snap=snap, cms=cms, counts=counts,
+            hll=hll, slices=self._win_slices,
+            shadow=shadow,
+            # the names a sealed window carries are resolved from the
+            # sample ring and the cache as this boundary found them
+            labels=self._labels_as_found(), names=dict(self._names))
+        self._win_slices = WindowSlices(self._hist_max_slices)
+        self._win_start = end
+        self._win_host_events = host_events
+        return cap
+
+    @staticmethod
+    def _snapshot_host(snap: tuple) -> tuple:
+        """A `_seal_snapshot` as the host values window deltas are taken
+        of: one blocking read of its arrays."""
+        events, drops, ent, cand, overflow, inv, qt = jax.device_get(snap)
+        if inv is not None:
+            inv = (inv[0].astype(np.int64), inv[1], inv[2])
+        if qt is not None:
+            qt = (qt[0].astype(np.int64), int(qt[1]), int(qt[2]))
+        # the candidate-overflow latch rides along: an overflowed run's
+        # windows carry approx=True, so merged and historical answers stay
+        # tainted across the seal boundary
+        return (float(events), float(drops), ent, cand, bool(int(overflow)),
+                inv, qt)
+
+    def _finish_on_worker(self, cap: _WindowCapture) -> None:
+        """The seal worker's thread: one window, then it ends. It opens no
+        turn stage and no `ig:` annotation (it is no part of a turn). A
+        window it fails on is counted and logged like a failed append."""
+        try:
+            self._finish_window(cap, "worker")
+        except Exception:  # noqa: BLE001 — nobody joins this thread for a result
+            from ..history import HISTORY_METRICS
+            HISTORY_METRICS.drops.labels(reason="seal").inc()
+            _ckpt_log.warning("window seal failed (window %d was dropped)",
+                              cap.window, exc_info=True)
+
+    def _seal_step(self, finish: str) -> None:
+        """Between the steps of a finish: the worker waits here while the
+        loop thread has a summary nearly due (`SEAL_QUIET_S`). A loop that
+        stopped turning holds it a second at most; a caller's finish never
+        waits."""
+        if finish == "worker":
+            self._seal_clear.wait(1.0)
+
+    def _finish_window(self, cap: _WindowCapture, finish: str) -> None:
+        """A seal's second half, on the caller's thread or the worker's:
+        everything that touches no live state. Blocks on the capture's
+        arrays, takes the deltas against the window before, sorts the
+        candidates, folds the slices, digests, appends, announces."""
+        from ..history import HISTORY, SealedWindow, window_digest
+        t0 = time.perf_counter()
+        self._seal_step(finish)
+        now = self._snapshot_host(cap.snap)
+        base, self._win_base = self._win_base, now
+        events0, drops0, ent0, _cand0, _overflow0, inv0, qt0 = base
+        events, drops, ent_now, cand, overflow, inv_now, qt_now = now
+        win_events = int(events - events0)
+        cms = np.asarray(cap.cms)
+        counts = np.asarray(cap.counts).astype(np.int64)
         order = np.argsort(-counts)
         keep = [(int(cand[i]), int(counts[i])) for i in order
                 if cand[i] != 0 and counts[i] > 0]
-        self._resolve_late([k for k, _ in keep[:32]])
-        self._win_n += 1
+        names = cap.names
+        self._resolve_late([k for k, _ in keep[:32]], cap.labels, names)
         # invertible plane rides the window as a cumulative-state DELTA:
         # the lanes are pure adds, so subtraction is exact (uint32 wrap
         # included) and merged windows decode like merged live state
         inv_kw = {}
-        if inv_now is not None and self._win_inv0 is not None:
+        if inv_now is not None and inv0 is not None:
             inv_kw = {
-                "inv_count": (inv_now[0]
-                              - self._win_inv0[0]).astype(np.int32),
-                "inv_keysum": inv_now[1] - self._win_inv0[1],
-                "inv_fpsum": inv_now[2] - self._win_inv0[2],
+                "inv_count": (inv_now[0] - inv0[0]).astype(np.int32),
+                "inv_keysum": inv_now[1] - inv0[1],
+                "inv_fpsum": inv_now[2] - inv0[2],
             }
         # DDSketch quantile plane rides the same cumulative-delta recipe:
         # bucket counts / zeros / total are pure integer adds, so the
         # window's latency distribution is an exact subtraction — merged
         # windows fold via dd_merge like merged live state
-        if qt_now is not None and self._win_qt0 is not None:
+        if qt_now is not None and qt0 is not None:
             inv_kw.update(
-                qt_counts=(qt_now[0] - self._win_qt0[0]).astype(np.int32),
-                qt_zeros=int(qt_now[1] - self._win_qt0[1]),
-                qt_total=int(qt_now[2] - self._win_qt0[2]),
+                qt_counts=(qt_now[0] - qt0[0]).astype(np.int32),
+                qt_zeros=int(qt_now[1] - qt0[1]),
+                qt_total=int(qt_now[2] - qt0[2]),
                 qt_alpha=float(self._qt_alpha),
                 qt_min_value=float(self._qt_minv),
             )
-        # accuracy audit plane: the WINDOW-scoped shadow sample rides the
-        # sealed window (copies — the live sample resets below); plane-off
-        # runs add no keys to the frame or the digest
-        if self._win_shadow is not None:
-            inv_kw.update(
-                rs_keys=self._win_shadow.keys.copy(),
-                rs_weights=self._win_shadow.weights.copy(),
-                rs_capacity=int(self._win_shadow.capacity),
-            )
+        # plane-off runs add no shadow keys to the frame or the digest
+        if cap.shadow is not None:
+            inv_kw.update(rs_keys=cap.shadow[0], rs_weights=cap.shadow[1],
+                          rs_capacity=cap.shadow[2])
+        self._seal_step(finish)
+        slices = cap.slices.seal()
+        self._seal_step(finish)
         win = SealedWindow(
             gadget=self._hist_gadget,
             node=self.ctx.extra.get("node", "") or "",
             run_id=self.ctx.run_id,
-            window=self._win_n,
-            start_ts=float(self._win_start),
-            end_ts=float(end),
+            window=cap.window,
+            start_ts=cap.start_ts,
+            end_ts=cap.end_ts,
             events=win_events,
-            drops=int(drops - self._win_drops0),
+            drops=int(drops - drops0),
             cms=cms.astype(np.int32),
-            hll=np.asarray(self._win_hll.registers).astype(np.int32),
-            ent=(ent_now - self._win_ent0).astype(np.float32),
+            hll=np.asarray(cap.hll).astype(np.int32),
+            ent=(ent_now - ent0).astype(np.float32),
             topk_keys=np.array([k for k, _ in keep], dtype=np.uint32),
             topk_counts=np.array([c for _, c in keep], dtype=np.int64),
-            slices=self._win_slices.seal(),
-            names={k: self._names[k] for k, _ in keep if k in self._names},
-            slices_dropped=self._win_slices.dropped,
+            slices=slices,
+            names={k: names[k] for k, _ in keep if k in names},
+            slices_dropped=cap.slices.dropped,
             approx=overflow,
             **inv_kw,
         )
+        # what this finish resolved goes back to the cache the harvests
+        # and the next capture read (one C-level update of a dict)
+        self._names.update(names)
         win.digest = window_digest(win)
+        self._seal_step(finish)
         try:
-            with self._span("tpusketch/seal-window", window=self._win_n,
+            with self._span("tpusketch/seal-window", window=cap.window,
                             events=win_events):
                 HISTORY.append_window(win, writer=self._hist_writer)
         except (OSError, ValueError) as e:
@@ -1904,7 +2133,7 @@ class TpuSketchInstance(OperatorInstance):
                 from ..history import HISTORY_METRICS
                 HISTORY_METRICS.drops.labels(reason="seal").inc()
             _ckpt_log.warning("window seal failed (window %d kept in "
-                              "memory was dropped): %r", self._win_n, e)
+                              "memory was dropped): %r", cap.window, e)
         else:
             # announce the sealed window on the run stream (header only,
             # no payload): summary-tier subscribers learn it exists and
@@ -1924,7 +2153,7 @@ class TpuSketchInstance(OperatorInstance):
             # ad-hoc recompute over what's actually fetchable
             if self._sq_engine is not None:
                 try:
-                    pubs = self._sq_engine.on_seal(win, now=float(end))
+                    pubs = self._sq_engine.on_seal(win, now=cap.end_ts)
                 except Exception as qe:  # noqa: BLE001 — observe only
                     _ckpt_log.warning("standing-query refresh failed: "
                                       "%r", qe)
@@ -1946,22 +2175,17 @@ class TpuSketchInstance(OperatorInstance):
                 self._hist_engine.maybe_compact(self._hist_writer.path)
             except (OSError, ValueError) as e:
                 _ckpt_log.warning("compaction pass failed: %r", e)
-        # open the next window: rotate the ring, fresh HLL, new deltas
-        self._wcms = _wcms_advance_jit(self._wcms)
-        self._win_hll = hll_init(self._win_hll.p)
-        self._win_start = end
-        self._win_events0 = events
-        self._win_drops0 = drops
-        self._win_ent0 = ent_now
-        self._win_inv0 = inv_now
-        self._win_qt0 = qt_now
-        if self._win_shadow is not None:
-            self._win_shadow.reset()
-        self._last_slices = {"slices": len(self._win_slices),
-                             "cells": self._win_slices.cells,
-                             "hh_entries": self._win_slices.hh_entries}
-        self._m_slice_hh.inc(self._win_slices.hh_entries)
-        self._win_slices = WindowSlices(self._hist_max_slices)
+        self._last_slices = {"slices": len(cap.slices),
+                             "cells": cap.slices.cells,
+                             "hh_entries": cap.slices.hh_entries}
+        self._m_slice_hh.inc(cap.slices.hh_entries)
+        took = time.perf_counter() - t0
+        self._m_seals[finish].inc()
+        self._m_seal_finish_s.observe(took)
+        stats = self._seal_stats
+        stats[finish] += 1
+        stats["finish_ms_last"] = took * 1e3
+        stats["finish_ms_max"] = max(stats["finish_ms_max"], took * 1e3)
 
     # harvest ---------------------------------------------------------------
 
@@ -2125,9 +2349,16 @@ class TpuSketchInstance(OperatorInstance):
         # steps by the rows they ran at (string keys: the block rides JSON)
         pipe_out["step_rows"] = {str(rows): steps for rows, steps
                                  in sorted(self._steps_by_rows.items())}
-        if self._hist_on and self._last_slices is not None:
-            # what the last sealed window's slice store held
-            pipe_out["slices"] = dict(self._last_slices)
+        if self._hist_on:
+            if self._last_slices is not None:
+                # what the last sealed window's slice store held
+                pipe_out["slices"] = dict(self._last_slices)
+            # who finished the windows so far, the boundaries that waited
+            # for the worker, and whether it holds a window now
+            worker = self._seal_thread
+            pipe_out["seal"] = {
+                **self._seal_stats,
+                "pending": int(worker is not None and worker.is_alive())}
         if self._anomaly_step is not None:
             pipe_out["anomaly"] = {"steps": self._anomaly_steps,
                                    "containers": len(self._containers),
@@ -2211,8 +2442,9 @@ class TpuSketchInstance(OperatorInstance):
                 self.harvest()
             if self._hist_on:
                 # final partial window (no-op when the last harvest
-                # already sealed it), then seal the store's active
-                # segment so these windows get index rows
+                # already sealed it; what the seal worker holds lands
+                # first), then seal the store's active segment so these
+                # windows get index rows
                 self.seal_window()
                 from ..history import HISTORY
                 HISTORY.release(self._hist_writer)

@@ -95,9 +95,15 @@ def served():
         with inst._bundle_mu:
             kept["leaves"].append(leaves_of(inst._merged_locked()))
 
-    def on_sealed(header: dict) -> None:
-        tap.on_sealed(header)
-        kept["seal_marks"].append(tap.batches)
+    # a window is announced from the seal worker, some batches after the
+    # boundary that closed it: the boundary is where the capture was taken
+    capture = tpusketch.TpuSketchInstance._capture_window
+
+    def marked_capture(inst):
+        cap = capture(inst)
+        if cap is not None:
+            kept["seal_marks"].append(tap.batches)
+        return cap
 
     def on_batch(batch) -> None:
         kept["batches"].append(copy.deepcopy(batch))
@@ -111,12 +117,16 @@ def served():
         ctx = GadgetContext(desc, gadget_params=params, operator_params=ops,
                             timeout=120.0,
                             extra={"on_sketch_summary": on_summary,
-                                   "on_window_sealed": on_sealed})
+                                   "on_window_sealed":
+                                       lambda header: tap.on_sealed(header)})
         tap = Tap(seconds=1.5, capacity_events=1 << 21,
                   capacity_batches=1 << 12, cancel=ctx.cancel,
                   snapshot=snapshot)
         try:
-            result = LocalRuntime().run_gadget(ctx, on_batch=on_batch)
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(tpusketch.TpuSketchInstance, "_capture_window",
+                           marked_capture)
+                result = LocalRuntime().run_gadget(ctx, on_batch=on_batch)
         finally:
             HISTORY.close_all()
     assert not result.errors(), result.errors()
@@ -164,12 +174,12 @@ def test_served_sharded_run_equals_the_one_chip_fold(served):
         try:
             for i, batch in enumerate(batches):
                 inst.enrich_batch(batch)
-                if i in seal_at:
-                    inst.seal_window()
                 if i in harvest_at:
                     inst.harvest()
                     with inst._bundle_mu:
                         leaves.append(leaves_of(inst.bundle))
+                if i in seal_at:
+                    inst.seal_window()
             inst.post_gadget_run()      # the teardown harvest and seal
             with inst._bundle_mu:
                 leaves.append(leaves_of(inst.bundle))

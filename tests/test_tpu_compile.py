@@ -150,17 +150,27 @@ def test_bundle_digest_compiles(one_chip):
 
 
 def test_seal_window_read_compiles_as_one_program(one_chip):
-    """What a seal reads of the window CMS (the current slot and the
-    candidates' estimates): one program, at the history plane's defaults."""
-    from inspektor_gadget_tpu.operators.tpusketch import _wcms_window_step
+    """What a seal's capture dispatches on the window planes (the current
+    slot and the candidates' estimates for its finish, the ring advanced
+    and a fresh HLL for the next window): one program, at the history
+    plane's defaults; and the snapshot of the bundle beside it."""
+    from inspektor_gadget_tpu.operators.tpusketch import (_seal_snapshot,
+                                                          _wcms_window_step)
+    from inspektor_gadget_tpu.ops.hll import hll_init
     from inspektor_gadget_tpu.ops.window import wcms_init
     wcms = _on(one_chip, jax.eval_shape(
         lambda: wcms_init(n_slots=8, depth=4, log2_width=12)))
+    hll = _on(one_chip, jax.eval_shape(lambda: hll_init(GEOMETRY["hll_p"])))
     cand = jax.ShapeDtypeStruct((GEOMETRY["k"],), jnp.uint32,
                                 sharding=one_chip)
-    table, counts = jax.eval_shape(_wcms_window_step, wcms, cand)
+    table, counts, ring, fresh = jax.eval_shape(
+        _wcms_window_step, wcms, cand, hll)
     assert table.shape == (4, 1 << 12) and counts.shape == (GEOMETRY["k"],)
-    jax.jit(_wcms_window_step).lower(wcms, cand).compile()
+    assert ring.slots.shape == wcms.slots.shape
+    assert fresh.registers.shape == hll.registers.shape
+    jax.jit(_wcms_window_step).lower(wcms, cand, hll).compile()
+    bundle = _on(one_chip, jax.eval_shape(lambda: bundle_init(**GEOMETRY)))
+    jax.jit(_seal_snapshot).lower(bundle).compile()
 
 
 def test_anomaly_step_compiles_at_the_configurations_size(one_chip):
@@ -195,6 +205,13 @@ def test_sharded_harvest_compiles_with_its_collectives(topo):
     text = make_bundle_harvest_sharded(mesh, like).lower(
         stacked).compile().as_text()
     assert "all-reduce" in text and "all-gather" in text
+    # what a seal's capture takes of the harvest's output, which is
+    # replicated over the mesh (ISSUE 33)
+    from inspektor_gadget_tpu.operators.tpusketch import _seal_snapshot
+    merged = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, P())), like)
+    jax.jit(_seal_snapshot).lower(merged).compile()
 
 
 @pytest.mark.parametrize("geometry,arm", [

@@ -224,6 +224,33 @@ def test_nothing_per_stage_reaches_the_tracer_ring(recorded_run):
                              "tpusketch/seal-window")) for n in rest), rest
 
 
+def test_a_seal_books_its_capture_and_the_worker_times_nothing(recorded_run):
+    """An interval-driven seal leaves `tpusketch_seal` the capture alone
+    (and a wait, had there been one): a window's share of the stage stays
+    under what its finish took on the worker, whose thread opens no `ig:`
+    annotation and so no stage of the turn (ISSUE 33)."""
+    pipe = recorded_run["pipe"]
+    seal = pipe["seal"]         # the teardown's summary: before its seal
+    assert seal["worker"] >= 2 and seal["caller"] == 0
+    before, after = recorded_run["before"], recorded_run["after"]
+
+    def moved(name: str) -> float:
+        key = name + '{gadget="trace/exec"}'
+        return after[key] - before.get(key, 0.0)
+
+    windows = moved("ig_tpusketch_seal_finish_seconds_count")
+    assert windows >= seal["worker"] + seal["pending"]
+    finish = moved("ig_tpusketch_seal_finish_seconds_sum") / windows
+    captures = seal["worker"] + seal["pending"]
+    if not seal["waited"]:
+        assert pipe["turn"]["stages"]["tpusketch_seal"] / captures < finish
+    assert {th for n, th, _a, _b in recorded_run["annotations"]
+            if n.startswith("ig:")} == {recorded_run["thread"]}
+    sealed = [r for r in recorded_run["spans"]
+              if r.name == "tpusketch/seal-window"]
+    assert len(sealed) == windows
+
+
 def test_a_slow_stage_leads_the_slowest_turn(monkeypatch):
     real = tpusketch.TpuSketchInstance._accumulate_slices
     calls = [0]

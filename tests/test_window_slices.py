@@ -199,6 +199,22 @@ def test_early_folds_of_the_backlog_change_nothing(name, monkeypatch):
     assert store._batches > 1 << 2
 
 
+@pytest.mark.parametrize("name", ["skewed", "ties"])
+def test_a_fold_put_off_changes_nothing(name, monkeypatch):
+    """`absorb(..., fold=False)` (a summary is nearly due, ISSUE 33)
+    leaves a backlog over its threshold unfolded; the next call that may
+    fold folds it, and the window is what it would have been."""
+    monkeypatch.setattr(window_mod, "_MIN_BACKLOG_EVENTS", 0)
+    monkeypatch.setattr(window_mod, "_BACKLOG_PER_ENTRY", 0)
+    batches = stream(name)
+    reference, _loop, want = both(batches)
+    store = WindowSlices(4096)
+    for i, lanes in enumerate(batches):
+        store.absorb(*lanes, fold=i % 3 == 2)
+        assert len(store._backlog) == (0 if i % 3 == 2 else i % 3 + 1)
+    assert_same_window(store.seal(), want, store.dropped, reference.dropped)
+
+
 @pytest.mark.parametrize("cap", [0, 4, 8, 130])
 def test_the_cap_admits_what_the_loop_admitted(cap):
     store, loop, sealed = both(stream("skewed"), cap)
