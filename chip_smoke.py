@@ -32,6 +32,11 @@ raises, so the exit code is non-zero and no result line is printed):
                  histograms and syscall sets exact; then the same run with
                  each of three faults planted in the scorer's step, which
                  must each read over the tolerance
+  anomaly-dense  the same phase on chipbench/configs/dense-node.json's
+                 stream (1,024 containers x 335 syscalls) with history on
+                 at `history-max-slices 4096`: besides the above, every
+                 sealed window's per-container slices against the exact
+                 event counts of its batches, none dropped
   agent          one RunGadget from AgentClient against the agent service
                  (`agent.main serve`'s AgentServer) with --checkpoint-dir
                  semantics: the checkpointer thread reads device state
@@ -96,6 +101,12 @@ ZIPF = 1.2
 # past the tolerance. Scores are judged on the first `head` summaries and
 # the last; the replay steps through every one
 ANOMALY = dict(vocab=64 * 335, harvest="100ms", harvests=40, head=8)
+# the same phase on chipbench/configs/dense-node.json's stream: upstream's
+# cap of 1,024 containers x 335 syscalls, history on with room for a slice
+# a container (2 x 1,024 + 1), a window a second; every sealed window's
+# per-container slices are held to the exact event counts of its batches
+DENSE = dict(containers=1024, vocab=1024 * 335, max_slices=4096,
+             window="1s")
 INV_VOCAB = 500        # inside every decode capacity used here: complete
 SHARD_VOCAB = 24       # < top-k on both sizes: the candidate table stays
 #                        exact on every path (tests/test_sharded_ingest.py)
@@ -640,13 +651,37 @@ def reference_scorer():
     return ref
 
 
+def slices_against_stream(windows: list, mntns: list[np.ndarray]) -> dict:
+    """Sealed windows (in order, each whole batches of `mntns`, the run's
+    batches) held to the exact per-container event counts: every container
+    a window absorbed has its `mntns:<ns>` slice with exactly its events,
+    no other container has one, and nothing was dropped."""
+    at, exact, dropped = 0, True, 0
+    for win in windows:
+        events, held = 0, []
+        while events < win.events and at < len(mntns):
+            held.append(mntns[at])
+            events += len(mntns[at])
+            at += 1
+        ids, counts = np.unique(np.concatenate(held), return_counts=True)
+        got = {k: s["events"] for k, s in win.slices.items()
+               if k.startswith("mntns:") and "|" not in k}
+        exact &= events == win.events and got == {
+            f"mntns:{ns}": c for ns, c in zip(ids.tolist(), counts.tolist())}
+        dropped += win.slices_dropped
+    return {"slices_exact": bool(exact), "slices_dropped": dropped,
+            "windows": len(windows)}
+
+
 def anomaly_run(cfg: dict, seed: int, fault: str = "",
-                extra: dict | None = None) -> dict:
+                extra: dict | None = None, dense: bool = False) -> dict:
     """`advise seccomp-profile` on the native synthetic source with
     tpusketch and its anomaly scorer on, until `ANOMALY["harvests"]`
     summaries came, recorded by chipbench/reference_scorer.py's Recorder
-    and held to its three answers. Returns the readings, the last
-    summary's `pipeline` block and the emitted profile."""
+    and held to its three answers. `dense` runs DENSE's stream with
+    history on and holds the sealed windows' per-container slices to the
+    stream too. Returns the readings, the last summary's `pipeline` block
+    and the emitted profile."""
     import jax
     import inspektor_gadget_tpu.all_gadgets  # noqa: F401
     from inspektor_gadget_tpu.gadgets import GadgetContext, get
@@ -659,15 +694,22 @@ def anomaly_run(cfg: dict, seed: int, fault: str = "",
 
     desc = get("advise", "seccomp-profile")
     params = desc.params().to_params()
+    stream = ({"containers": str(DENSE["containers"]),
+               "vocab": str(DENSE["vocab"])} if dense
+              else {"vocab": str(ANOMALY["vocab"])})
     for k, v in {"source": "synthetic", "rate": str(cfg["rate"]),
-                 "batch-size": str(cfg["batch"]),
-                 "vocab": str(ANOMALY["vocab"]), "zipf": str(ZIPF),
-                 "seed": str(seed)}.items():
+                 "batch-size": str(cfg["batch"]), **stream,
+                 "zipf": str(ZIPF), "seed": str(seed)}.items():
         params.set(k, v)
+    history_dir = tempfile.mkdtemp(prefix="ig-smoke-dense-") if dense else ""
+    history = ({"history": "true", "history-interval": DENSE["window"],
+                "history-max-slices": str(DENSE["max_slices"]),
+                "history-dir": history_dir} if dense else {})
     op_params = Collection()
     op_params["operator.tpusketch."] = sketch_params(
         {**cfg, "harvest": ANOMALY["harvest"]},
-        {"anomaly": "true", "audit-sample": "0", **(extra or {})})
+        {"anomaly": "true", "audit-sample": "0", **history,
+         **(extra or {})})
     rec = ref.Recorder()
     last: dict = {}
 
@@ -682,8 +724,18 @@ def anomaly_run(cfg: dict, seed: int, fault: str = "",
     ctx = GadgetContext(desc, gadget_params=params,
                         operator_params=op_params, timeout=cfg["deadline"],
                         extra={"on_sketch_summary": on_summary})
-    with scorer_fault(fault):
-        result = LocalRuntime().run_gadget(ctx, on_batch=rec.on_batch)
+    from inspektor_gadget_tpu.history import HISTORY, decode_window
+    windows = []
+    try:
+        with scorer_fault(fault):
+            result = LocalRuntime().run_gadget(ctx, on_batch=rec.on_batch)
+        if dense:
+            windows = [decode_window(h, p) for h, p in HISTORY.fetch_windows(
+                base_dir=history_dir, gadget=desc.full_name)]
+    finally:
+        if dense:
+            HISTORY.close_all()
+            shutil.rmtree(history_dir, ignore_errors=True)
     require(not result.errors(), f"gadget run failed: {result.errors()}")
     require(len(rec.summaries) > ANOMALY["harvests"],
             f"only {len(rec.summaries)} harvests inside {cfg['deadline']}s")
@@ -694,14 +746,23 @@ def anomaly_run(cfg: dict, seed: int, fault: str = "",
     judged = list(range(ANOMALY["head"])) + [len(rec.summaries) - 1]
     readings = ref.compare(rec, start, dim, profile=last["profile"],
                            counts=last["counts"], summaries=judged)
+    if dense:
+        readings.update(slices_against_stream(windows, rec.mntns))
     return {"readings": readings, "tolerance": ref.TOLERANCE,
+            "windows": windows,
             "pipeline": last["pipeline"], "emitted": result.first(),
             "recorded": rec, "events": sum(len(a) for a in rec.mntns)}
 
 
-def phase_anomaly(cfg, platform, seed, clock) -> None:
-    sound = anomaly_run(cfg, seed + 4)
+def phase_anomaly(cfg, platform, seed, clock, dense: bool = False) -> None:
+    sound = anomaly_run(cfg, seed + 4, dense=dense)
     r, tol = sound["readings"], sound["tolerance"]
+    if dense:
+        require(r["windows"] >= 2, f"only {r['windows']} windows sealed")
+        require(r["slices_exact"], "a sealed window's per-container slices "
+                "differ from the exact event counts of its batches")
+        require(r["slices_dropped"] == 0,
+                f"{r['slices_dropped']} slices dropped under the cap")
     require(r["score_keys_equal"], "a summary's scores name other "
             "containers than the stream held")
     require(r["score_gap"] <= tol,
@@ -716,12 +777,14 @@ def phase_anomaly(cfg, platform, seed, clock) -> None:
             f"{block['steps']} scorer steps for {r['harvests']} harvests")
     faults = {}
     for i, fault in enumerate(SCORER_FAULTS):
-        gap = anomaly_run(cfg, seed + 5 + i, fault)["readings"]["score_gap"]
+        gap = anomaly_run(cfg, seed + 5 + i, fault,
+                          dense=dense)["readings"]["score_gap"]
         require(gap > tol, f"the planted fault {fault!r} reads {gap:.4f}, "
                 f"inside the tolerance {tol}: the comparison is blind to it")
         faults[fault] = gap
     stages = sound["pipeline"]["turn"]
-    say(phase="anomaly", events=sound["events"], **r, tolerance=tol,
+    say(phase="anomaly-dense" if dense else "anomaly",
+        events=sound["events"], **r, tolerance=tol,
         **block, fault_gaps=faults,
         anomaly_score_ms_per_harvest=round(
             1e3 * stages["anomaly_score_s"] / block["steps"], 3),
@@ -1004,6 +1067,7 @@ def main(argv: list[str] | None = None) -> int:
         phase_quantiles(cfg, platform, args.seed, clock)
         phase_narrow(cfg, platform, args.seed, clock)
         phase_anomaly(cfg, platform, args.seed, clock)
+        phase_anomaly(cfg, platform, args.seed + 10, clock, dense=True)
         phase_agent(cfg, platform, args.seed, clock)
     say(phase="done", seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {

@@ -44,6 +44,18 @@ _tm_rows = counter("ig_display_rows_total",
                    ("gadget",))
 
 
+# upstream's cap on traced containers a node
+# (pkg/tracer-collection/tracer-collection.go:29 MaxContainersPerNode)
+MAX_CONTAINERS_PER_NODE = 1024
+
+
+def _validate_containers(value: str) -> None:
+    if not 1 <= int(value) <= MAX_CONTAINERS_PER_NODE:
+        raise ValueError(
+            f"{value} is outside 1..{MAX_CONTAINERS_PER_NODE} "
+            "(upstream's MaxContainersPerNode)")
+
+
 def source_params() -> ParamDescs:
     """Params shared by every capture-backed gadget."""
     return ParamDescs([
@@ -54,6 +66,11 @@ def source_params() -> ParamDescs:
                   description="synthetic event rate/sec"),
         ParamDesc(key="vocab", default="1000", type_hint=TypeHint.INT),
         ParamDesc(key="zipf", default="1.2", type_hint=TypeHint.FLOAT),
+        ParamDesc(key="containers", default="64", type_hint=TypeHint.INT,
+                  validator=_validate_containers,
+                  description="fake containers the synthetic stream is "
+                              "spread over (mntns = base + key rank % "
+                              "containers)"),
         ParamDesc(key="seed", default="0", type_hint=TypeHint.INT),
         ParamDesc(key="batch-size", default="8192", type_hint=TypeHint.INT),
     ])
@@ -284,6 +301,8 @@ class SourceTraceGadget:
         self._rate = p.get("rate").as_float() if "rate" in p else 100000.0
         self._vocab = p.get("vocab").as_int() if "vocab" in p else 1000
         self._zipf = p.get("zipf").as_float() if "zipf" in p else 1.2
+        self._synth_containers = (p.get("containers").as_int()
+                            if "containers" in p else 64)
         self._seed = p.get("seed").as_int() if "seed" in p else 0
         self._batch_size = p.get("batch-size").as_int() if "batch-size" in p else 8192
         self.source = None
@@ -311,6 +330,13 @@ class SourceTraceGadget:
         src = self.source
         if src is not None and isinstance(src, NativeCapture):
             src.set_filter(mntns_ids)
+
+    def expected_containers(self) -> int:
+        """Containers the stream is known to hold before it starts: the
+        synthetic source's `containers`; 0 for a live source, whose node
+        the container collection counts at attach."""
+        return (self._synth_containers
+                if self._mode in ("synthetic", "pysynthetic") else 0)
 
     # source selection ------------------------------------------------------
 
@@ -394,7 +420,8 @@ class SourceTraceGadget:
             src = NativeCapture(self.synth_kind, seed=self._seed,
                                 rate=self._rate, vocab=self._vocab,
                                 zipf_s=self._zipf, ring_pow2=20,
-                                batch_size=self._batch_size)
+                                batch_size=self._batch_size,
+                                containers=self._synth_containers)
             if self._mntns_filter is not None:
                 src.set_filter(self._mntns_filter)
             src.start()
@@ -403,7 +430,8 @@ class SourceTraceGadget:
         self._threaded = False
         return PySyntheticSource(kind=self.synth_kind, seed=self._seed,
                                  vocab=self._vocab, zipf_s=self._zipf,
-                                 batch_size=self._batch_size)
+                                 batch_size=self._batch_size,
+                                 containers=self._synth_containers)
 
     # per-container attach (ref: localmanager.go:230-260 Attacher path) -----
 

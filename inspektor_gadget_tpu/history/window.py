@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import io
+import itertools
 import json
 from typing import Iterable
 
@@ -45,7 +46,7 @@ import numpy as np
 # bit-identical to the device fmix32) so slice sketches and the
 # invertible decode can never fork their hash family
 from ..ops.hashing import fmix32_np as _fmix32_np
-from ..utils.grouping import table_codes
+from ..utils.grouping import SlotTable, find_sorted, table_codes
 
 WINDOW_SCHEMA = "ig-tpu/sketch-window/v1"
 
@@ -145,6 +146,10 @@ def _run_starts(a: np.ndarray) -> np.ndarray:
 
 
 _KEY32 = np.uint64(0xFFFFFFFF)
+# a slice of more entries than this is cut by selection before the sort
+_SELECT_OVER = 1024
+# a cell's word: the container's ordinal above this many bits of the kind's
+_KIND_ID_BITS = 24
 _U32 = np.uint64(32)
 # the heavy-hitter backlog is folded into the merged table once it holds
 # more events than this many times the table's entries (a fold costs a
@@ -198,8 +203,14 @@ class WindowSlices:
         # feeds no admitted slice; _feeds[row] = (mntns, cross, kind) slices.
         # A row is a cell of an admitted container, or the one row of its
         # kind's other cells (_kind_rows): the rows, and with them the open
-        # window's memory, follow the admitted slices
-        self._cells: dict[tuple[int, int], int] = {}
+        # window's memory, follow the admitted slices. The cells seen are
+        # kept as sorted words (container's ordinal, kind's ordinal) beside
+        # their rows, so a batch finds its cells' rows by one
+        # `searchsorted`, and only the cells it brings for the first time
+        # are walked
+        self._ns_ids, self._kind_ids = SlotTable(), SlotTable()
+        self._cell_words = np.zeros(0, dtype=np.int64)
+        self._cell_rows = np.zeros(0, dtype=np.int64)
         self._feeds: list[tuple[int, int, int]] = []
         self._kind_rows: dict[int, int] = {}
         rows = 64
@@ -249,23 +260,29 @@ class WindowSlices:
         self._keys.append(key)
         return len(self._keys) - 1
 
-    def _rows_of(self, cells: list[tuple[int, int]]) -> np.ndarray:
+    def _rows_of(self, cell_ns: np.ndarray,
+                 cell_kind: np.ndarray) -> np.ndarray:
         """Rows of a batch's distinct cells (ascending by container, then
         kind), admitting what the batch brings for the first time in the
-        order the per-subpopulation loop did."""
-        rows = []
+        order the per-subpopulation loop did. Cells seen before are an
+        array lookup; the walk is over the new ones alone."""
+        words = (self._ns_ids.slots_of(cell_ns).astype(np.int64)
+                 << _KIND_ID_BITS) | self._kind_ids.slots_of(cell_kind)
+        at, found = find_sorted(self._cell_words, words)
+        if found.all():
+            return self._cell_rows[at]
+        rows = np.full(len(words), -1, dtype=np.int64)
+        rows[found] = self._cell_rows[at[found]]
+        new = np.flatnonzero(~found)
         fresh = []
-        for ns, k in cells:
-            row = self._cells.get((ns, k))
-            if row is None:
-                if ns not in self._ns:
-                    self._ns[ns] = self._decide(f"mntns:{ns}")
-                fresh.append((len(rows), ns, k,
-                              self._decide(f"mntns:{ns}|kind:{k}")))
-            rows.append(row)
-        for k in sorted({k for _ns, k in cells} - self._kinds.keys()):
+        for i, ns, k in zip(new.tolist(), cell_ns[new].tolist(),
+                            cell_kind[new].tolist()):
+            if ns not in self._ns:
+                self._ns[ns] = self._decide(f"mntns:{ns}")
+            fresh.append((i, ns, k, self._decide(f"mntns:{ns}|kind:{k}")))
+        for k in sorted(set(cell_kind[new].tolist()) - self._kinds.keys()):
             self._kinds[k] = self._decide(f"kind:{k}")
-        for at, ns, k, cross in fresh:
+        for i, ns, k, cross in fresh:
             feeds = (self._ns[ns], cross, self._kinds[k])
             if max(feeds) < 0:
                 row = -1
@@ -276,10 +293,15 @@ class WindowSlices:
                 row = len(self._feeds)
             if row == len(self._feeds):
                 self._feeds.append(feeds)
-            self._cells[(ns, k)] = rows[at] = row
+            rows[i] = row
+        # the new words ascend with their cells: one stable merge
+        merged = np.concatenate([self._cell_words, words[new]])
+        order = np.argsort(merged, kind="stable")
+        self._cell_words = merged[order]
+        self._cell_rows = np.concatenate([self._cell_rows, rows[new]])[order]
         if len(self._feeds) > len(self._events):
             self._grow()
-        return np.array(rows, dtype=np.int64)
+        return rows
 
     def _grow(self) -> None:
         # the backlog's words hold rows in the bits of the old capacity
@@ -309,7 +331,7 @@ class WindowSlices:
         ordinal = self._batches
         self._batches += 1
         cell_ns, cell_kind, code = _cell_codes(mntns, kind)
-        rows = self._rows_of(list(zip(cell_ns.tolist(), cell_kind.tolist())))
+        rows = self._rows_of(cell_ns, cell_kind)
         row = rows[code]
         held = rows >= 0
         if not held.all():
@@ -409,20 +431,26 @@ class WindowSlices:
         held_by = (self._hh_keys >> _U32).astype(np.intp)
         keys = self._hh_keys & _KEY32
         counts, first = self._hh_counts, self._hh_first
-        # a row's entries stand together: ends[row] .. ends[row + 1]
-        ends = np.searchsorted(held_by, np.arange(rows + 1)).tolist()
         feeds = np.array(self._feeds, dtype=np.int64).reshape(rows, 3)
+        row_hh = None
         for to in feeds.T:
             fed = np.flatnonzero(to >= 0)
+            if np.bincount(to[fed], minlength=1).max() <= 1:
+                # every slice of this kind is one cell: its state is the
+                # cell's own, and so is its table, which is cut once for
+                # all such kinds
+                events[to[fed]] = self._events[fed]
+                hll[to[fed]] = self._hll[fed]
+                ent[to[fed]] = self._ent[fed]
+                if row_hh is None:
+                    row_hh = [[] for _ in range(rows)]
+                    _top_hh(row_hh, held_by, keys, counts, first)
+                for row, i in zip(fed.tolist(), to[fed].tolist()):
+                    hh[i] = list(row_hh[row])
+                continue
             np.add.at(events, to[fed], self._events[fed])
             np.maximum.at(hll, to[fed], self._hll[fed])
             np.add.at(ent, to[fed], self._ent[fed])
-            if np.bincount(to[fed], minlength=1).max() <= 1:
-                # every slice of this kind is one cell
-                for row, i in zip(fed.tolist(), to[fed].tolist()):
-                    a, b = ends[row], ends[row + 1]
-                    hh[i] = _top_hh(keys[a:b], counts[a:b], first[a:b])
-                continue
             # cells of one slice hold the same keys: add them up first
             into = to[held_by]
             pairs = (into.astype(np.uint64) << _U32) | keys
@@ -435,27 +463,50 @@ class WindowSlices:
             g_counts = np.add.reduceat(counts[order], starts)
             g_first = np.minimum.reduceat(first[order], starts)
             pairs = pairs[starts]
-            g_keys = pairs & _KEY32
-            which = (pairs >> _U32).astype(np.intp)
-            bounds = np.append(_run_starts(which), len(which)).tolist()
-            for a, b in zip(bounds[:-1], bounds[1:]):
-                hh[which[a]] = _top_hh(g_keys[a:b], g_counts[a:b],
-                                       g_first[a:b])
+            _top_hh(hh, (pairs >> _U32).astype(np.intp), pairs & _KEY32,
+                    g_counts, g_first)
         return {key: {"events": int(events[i]), "hll": hll[i],
                       "ent": ent[i], "hh": hh[i]}
                 for i, key in enumerate(self._keys)}
 
 
-def _top_hh(keys: np.ndarray, counts: np.ndarray,
-            first: np.ndarray) -> list[tuple[int, int]]:
-    """The `SLICE_HH_K` largest of one slice's entries, given by ascending
-    key: count descending, ties by first batch, then by key."""
-    over = len(counts) - SLICE_HH_K
-    if over > 0:
-        cand = np.flatnonzero(counts >= np.partition(counts, over)[over])
-        keys, counts, first = keys[cand], counts[cand], first[cand]
-    top = np.lexsort((first, -counts))[:SLICE_HH_K]    # a stable sort
-    return list(zip(keys[top].tolist(), counts[top].tolist()))
+def _top_hh(hh: list, which: np.ndarray, keys: np.ndarray,
+            counts: np.ndarray, first: np.ndarray) -> None:
+    """The `SLICE_HH_K` largest entries of every slice at once, into
+    `hh[slice]`: `which` names each entry's slice, ascending, and within a
+    slice the entries come by ascending key. Count descending, ties by
+    first batch, then by key. One sort over all the slices' entries and
+    one cut by rank within the slice: no Python step a slice but the list
+    it is handed. A slice of very many entries (a kind's, over a whole
+    node) is first cut to those at or over its K-th largest count, which a
+    selection finds without sorting them."""
+    if not len(which):
+        return
+    starts = _run_starts(which)
+    sizes = np.diff(starts, append=len(which))
+    large = np.flatnonzero(sizes > _SELECT_OVER)
+    if len(large):
+        keep = np.ones(len(which), dtype=bool)
+        for a, n in zip(starts[large].tolist(), sizes[large].tolist()):
+            c = counts[a:a + n]
+            keep[a:a + n] = c >= np.partition(c, n - SLICE_HH_K)[
+                n - SLICE_HH_K]
+        which, keys, counts, first = (which[keep], keys[keep], counts[keep],
+                                      first[keep])
+    # slice ascending, count descending; the stable sorts keep first-batch
+    # order among equal counts and key order among equal first batches
+    order = np.lexsort((first, -counts, which))
+    which = which[order]
+    starts = _run_starts(which)
+    sizes = np.diff(starts, append=len(which))
+    rank = np.arange(len(which)) - np.repeat(starts, sizes)
+    top = order[rank < SLICE_HH_K]
+    top_keys, top_counts = keys[top].tolist(), counts[top].tolist()
+    at = 0
+    for i, n in zip(which[starts].tolist(),
+                    np.minimum(sizes, SLICE_HH_K).tolist()):
+        hh[i] = list(zip(top_keys[at:at + n], top_counts[at:at + n]))
+        at += n
 
 
 def slice_hll_estimate(registers: np.ndarray) -> float:
@@ -559,6 +610,17 @@ class SealedWindow:
         return sorted(self.slices)
 
 
+def _hh_pairs(tables: list[list]) -> tuple[np.ndarray, list[int]]:
+    """Heavy-hitter tables (lists of (key, count), whatever integers they
+    hold) as one `[pairs, 2]` int64 array, table after table, and each
+    table's length: one pass over all of a window's slices, where a Python
+    step a pair was most of a dense window's digest."""
+    flat = itertools.chain.from_iterable
+    lens = [len(t) for t in tables]
+    return (np.fromiter(flat(flat(tables)), dtype=np.int64,
+                        count=2 * sum(lens)).reshape(-1, 2), lens)
+
+
 def window_digest(win: SealedWindow) -> str:
     """Content digest of one sealed window: sha256 over the canonical
     JSON of the decoded state with every array hashed by VALUE. Wall
@@ -568,6 +630,10 @@ def window_digest(win: SealedWindow) -> str:
     def arr(a: np.ndarray) -> str:
         return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()
 
+    by_key = sorted(win.slices.items())
+    pairs, lens = _hh_pairs([s["hh"] for _key, s in by_key])
+    ends = np.cumsum(lens).tolist()
+    pairs = pairs.tolist()          # plain integers, as JSON wants them
     doc = {
         "schema": WINDOW_SCHEMA,
         "gadget": win.gadget,
@@ -612,9 +678,9 @@ def window_digest(win: SealedWindow) -> str:
                 "events": int(s["events"]),
                 "hll": arr(s["hll"]),
                 "ent": arr(s["ent"]),
-                "hh": [[int(k), int(c)] for k, c in s["hh"]],
+                "hh": pairs[end - n:end],
             }
-            for key, s in sorted(win.slices.items())
+            for (key, s), n, end in zip(by_key, lens, ends)
         },
     }
     blob = json.dumps(doc, sort_keys=True, separators=(",", ":"))
@@ -651,11 +717,12 @@ def encode_window(win: SealedWindow) -> tuple[dict, bytes]:
             [win.slices[k]["ent"] for k in skeys]).astype(np.int64)
         hh_keys = np.zeros((len(skeys), SLICE_HH_K), dtype=np.uint32)
         hh_counts = np.zeros((len(skeys), SLICE_HH_K), dtype=np.int64)
-        for i, k in enumerate(skeys):
-            pairs = win.slices[k]["hh"][:SLICE_HH_K]
-            for j, (key32, c) in enumerate(pairs):
-                hh_keys[i, j] = key32
-                hh_counts[i, j] = c
+        pairs, lens = _hh_pairs(
+            [win.slices[k]["hh"][:SLICE_HH_K] for k in skeys])
+        row = np.repeat(np.arange(len(skeys)), lens)
+        col = np.arange(len(row)) - np.repeat(np.cumsum(lens) - lens, lens)
+        hh_keys[row, col] = pairs[:, 0]
+        hh_counts[row, col] = pairs[:, 1]
         arrays["slice_hh_keys"] = hh_keys
         arrays["slice_hh_counts"] = hh_counts
     buf = io.BytesIO()

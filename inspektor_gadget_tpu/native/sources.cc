@@ -248,13 +248,15 @@ inline void fill_proc_identity(Event& ev, Vocab& vocab, uint32_t pid) {
 class SyntheticSource : public Source {
  public:
   SyntheticSource(size_t ring_pow2, uint32_t kind, uint64_t seed,
-                  double rate_per_sec, uint32_t vocab_size, double zipf_s)
+                  double rate_per_sec, uint32_t vocab_size, double zipf_s,
+                  uint32_t containers)
       : Source(ring_pow2),
         kind_(kind),
         rng_(seed ? seed : 0x9E3779B97F4A7C15ull),
         rate_(rate_per_sec),
         vocab_size_(vocab_size ? vocab_size : 1000),
-        zipf_s_(zipf_s > 0 ? zipf_s : 1.2) {
+        zipf_s_(zipf_s > 0 ? zipf_s : 1.2),
+        containers_(containers ? containers : 64) {
     // Zipf sampling via Walker's alias method: O(1) per draw (one random,
     // one table probe) instead of a CDF binary search — keeps the host
     // generation path well above the device-feed requirement.
@@ -355,7 +357,7 @@ class SyntheticSource : public Source {
     ev.ppid = 1;
     ev.uid = (uint32_t)(next_rand() % 4);
     ev.kind = kind_;
-    ev.mntns = 4026531840ull + idx % 64;  // 64 fake containers
+    ev.mntns = 4026531840ull + idx % containers_;  // fake containers
     ev.aux1 = next_rand();                // e.g. addresses / bytes
     ev.aux2 = next_rand() & 0xFFFF;       // e.g. port / flags
     const std::string& nm = names_[idx];
@@ -369,6 +371,7 @@ class SyntheticSource : public Source {
   double rate_;
   uint32_t vocab_size_;
   double zipf_s_;
+  uint32_t containers_;
   std::vector<double> alias_prob_;
   std::vector<uint32_t> alias_idx_;
   std::vector<std::string> names_;

@@ -119,6 +119,11 @@ class LocalManagerInstance(OperatorInstance):
             return  # the scoped manager instance owns this run
         # ref: localmanager.go:208-228 — register tracer, inject filter
         op.tc.add_tracer(self._tracer_id, self.selector)
+        # what the run will see of the node, for operators that size
+        # per-container state before the source starts (tpusketch primes
+        # the anomaly scorer's program at the slots that hold them)
+        self.ctx.extra["containers_at_attach"] = len(
+            {c.mntns for c in op.cc.get_all(self.selector) if c.mntns})
         if isinstance(self.gadget, MountNsFilterSetter):
             # filter only when a container selector is active; a bare local
             # run traces everything including host (ref: localmanager.go

@@ -139,6 +139,11 @@ _tm_slice_hh_entries = counter(
     "ig_history_slice_hh_entries_total",
     "(cell, key) entries of the slices' exact heavy-hitter table at each "
     "window seal: what the seal's slice work follows", ("gadget",))
+_tm_slices = counter(
+    "ig_history_slices_total",
+    "subpopulation slices of the sealed windows by the decision their "
+    "window's store made at their first appearance: admitted, or dropped "
+    "over history-max-slices", ("gadget", "decision"))
 # the two halves of a seal (labelled likewise): who finished the window, the
 # boundaries that found the worker still on the one before, and what a
 # finish takes wherever it runs
@@ -218,9 +223,10 @@ STEP_ROWS_FLOOR = 8192
 # keys. A constant, not an option.
 SEAL_QUIET_S = 0.06
 
-# Rows the per-container distribution array starts with, and the scorer's
-# program is primed at: a power of two that doubles when the containers
-# outgrow it. A constant, not an option.
+# Fewest rows of the per-container distribution array, which the scorer's
+# program runs at: a power of two, sized before the source starts to hold
+# the containers the run is known to reach (`_expected_containers`) and
+# doubled when more appear than it holds. A constant, not an option.
 CONTAINER_SLOTS_FLOOR = 64
 
 # window-plane device steps (history sealing): the WindowedCMS ring
@@ -742,8 +748,9 @@ class TpuSketchInstance(OperatorInstance):
         self.scorer = None
         # per-container distributions (`ae` / `vae`): one [slots, dim]
         # float32 array behind a mntns -> slot table; the slots are a power
-        # of two and double when a container more than they hold appears,
-        # which is the only time the scorer's program changes shape
+        # of two, sized before the source starts to hold the containers the
+        # run is known to reach (`pre_gadget_run`) and doubled when more
+        # appear, which is the only time the scorer's program changes shape
         self._containers = SlotTable()
         self._container_counts: np.ndarray | None = None
         self._anomaly_step = None
@@ -775,6 +782,7 @@ class TpuSketchInstance(OperatorInstance):
                 self._m_anomaly_slots = _tm_anomaly_slots.labels(gadget=g)
                 self._m_anomaly_slots.set(CONTAINER_SLOTS_FLOOR)
                 self._anomaly_steps = 0
+                self._primed_slots = 0      # set by pre_gadget_run
         self._drops_seen = 0
         self._last_harvest = time.monotonic()
         self._epoch = 0
@@ -922,6 +930,9 @@ class TpuSketchInstance(OperatorInstance):
             self._win_slices = WindowSlices(self._hist_max_slices)
             self._last_slices: dict[str, int] | None = None
             self._m_slice_hh = _tm_slice_hh_entries.labels(gadget=g)
+            self._m_slices = {
+                d: _tm_slices.labels(gadget=g, decision=d)
+                for d in ("admitted", "dropped")}
             # the two halves of a seal: `_capture_window` on the thread
             # that owns the live state, `_finish_window` on the caller of
             # seal_window() or, for the served path's interval-driven
@@ -1187,13 +1198,18 @@ class TpuSketchInstance(OperatorInstance):
         property) and nothing is counted. The sharded step has one shape
         and compiles with the first round, as before (and a seal's programs
         with its first seal). The anomaly scorer's
-        one program is primed at the slots it starts with, on a copy of
-        the scorer (the step donates what it is given, and a step on the
-        scorer itself would advance Adam's count). With history on, the
-        two programs a seal's capture dispatches are run once too."""
+        one program is primed at the slots that hold the containers the
+        run is known to reach (`_expected_containers`; never fewer than
+        CONTAINER_SLOTS_FLOOR), on a copy of the scorer (the step donates
+        what it is given, and a step on the scorer itself would advance
+        Adam's count); containers that appear beyond them double the slots
+        inside the run, as before. With history on, the two programs a
+        seal's capture dispatches are run once too."""
         if not self.enabled:
             return
         if self._anomaly_step is not None:
+            self._hold_containers(self._expected_containers())
+            self._primed_slots = len(self._container_counts)
             counts = np.zeros_like(self._container_counts)
             _scorer, scores = self._anomaly_step(
                 jax.tree.map(jnp.array, self.scorer), counts,
@@ -1832,6 +1848,30 @@ class TpuSketchInstance(OperatorInstance):
                 name = comm.split(b"\0", 1)[0].decode("utf-8", "replace")
             names[k] = name or f"0x{k:08x}"
 
+    def _expected_containers(self) -> int:
+        """Containers the run is known to reach before its source starts:
+        what the gadget says of its own stream (a synthetic source's
+        `containers`), or the node's at attach as localmanager counted
+        them. 0 where neither knows."""
+        own = getattr(self.gadget, "expected_containers", None)
+        return max(own() if own is not None else 0,
+                   int(self.ctx.extra.get("containers_at_attach", 0)))
+
+    def _hold_containers(self, containers: int) -> None:
+        """Rows for `containers` slots: the power of two that holds them,
+        doubled from what the array has (never shrunk). The one place the
+        scorer's program changes shape."""
+        counts = self._container_counts
+        rows = len(counts)
+        while rows < containers:
+            rows *= 2
+        if rows == len(counts):
+            return
+        grown = np.zeros((rows, counts.shape[1]), dtype=np.float32)
+        grown[:len(counts)] = counts
+        self._container_counts = grown
+        self._m_anomaly_slots.set(rows)
+
     def _accumulate_container_dists(self, batch: EventBatch, n: int) -> None:
         mntns = batch.cols["mntns"][:n]
         keys = batch.cols[self.dist_col][:n]
@@ -1850,20 +1890,14 @@ class TpuSketchInstance(OperatorInstance):
         # table, then one scatter-add into the flat [slots * dim] array
         dim = self._ae_cfg.input_dim
         slot = self._containers.slots_of(mntns)
-        counts = self._container_counts
-        if len(self._containers) > len(counts):
-            rows = len(counts)
-            while rows < len(self._containers):
-                rows *= 2
-            grown = np.zeros((rows, dim), dtype=np.float32)
-            grown[:len(counts)] = counts
-            counts = self._container_counts = grown
-            self._m_anomaly_slots.set(rows)
+        # containers the priming did not know of: the slots double
+        self._hold_containers(len(self._containers))
         flat = slot * dim
-        flat += (keys % np.uint64(dim)).astype(np.intp)
+        # dim is a power of two: key % dim
+        flat += (keys & keys.dtype.type(dim - 1)).astype(np.intp)
         # the addend in the array's own type: a Python 1.0 is a float64,
         # which sends `ufunc.at` down its casting path, thirty times slower
-        np.add.at(counts.reshape(-1), flat, np.float32(1.0))
+        np.add.at(self._container_counts.reshape(-1), flat, np.float32(1.0))
 
     def container_distributions(self) -> tuple[list[int], np.ndarray]:
         """The containers seen (mntns, by slot) and a copy of their
@@ -2177,8 +2211,12 @@ class TpuSketchInstance(OperatorInstance):
                 _ckpt_log.warning("compaction pass failed: %r", e)
         self._last_slices = {"slices": len(cap.slices),
                              "cells": cap.slices.cells,
-                             "hh_entries": cap.slices.hh_entries}
+                             "hh_entries": cap.slices.hh_entries,
+                             "admitted": len(cap.slices),
+                             "dropped": cap.slices.dropped}
         self._m_slice_hh.inc(cap.slices.hh_entries)
+        self._m_slices["admitted"].inc(len(cap.slices))
+        self._m_slices["dropped"].inc(cap.slices.dropped)
         took = time.perf_counter() - t0
         self._m_seals[finish].inc()
         self._m_seal_finish_s.observe(took)
@@ -2362,7 +2400,8 @@ class TpuSketchInstance(OperatorInstance):
         if self._anomaly_step is not None:
             pipe_out["anomaly"] = {"steps": self._anomaly_steps,
                                    "containers": len(self._containers),
-                                   "slots": len(self._container_counts)}
+                                   "slots": len(self._container_counts),
+                                   "primed_slots": self._primed_slots}
         for stage, row in pipe_out["stages"].items():
             with self._span(f"tpusketch/stage/{stage}",
                             watermark_s=row["watermark_s"],

@@ -17,8 +17,10 @@ from .batch import EventBatch
 
 class PySyntheticSource:
     def __init__(self, kind: int = 1, *, seed: int = 0, vocab: int = 1000,
-                 zipf_s: float = 1.2, batch_size: int = 8192):
+                 zipf_s: float = 1.2, batch_size: int = 8192,
+                 containers: int = 64):
         self.kind = kind
+        self._containers = containers
         self.batch_size = batch_size
         self._rng = np.random.default_rng(seed or 42)
         self._names = [f"proc-{i}" for i in range(vocab)]
@@ -45,7 +47,7 @@ class PySyntheticSource:
         b = EventBatch.alloc(n, with_comm=False)
         b.cols["ts"][:] = time.time_ns()
         b.cols["key_hash"][:] = self._hashes[idx]
-        b.cols["mntns"][:] = np.uint64(4026531840) + (idx % 64).astype(np.uint64)
+        b.cols["mntns"][:] = np.uint64(4026531840) + (idx % self._containers).astype(np.uint64)
         b.cols["pid"][:] = self._rng.integers(1000, 51000, n, dtype=np.uint32)
         b.cols["uid"][:] = self._rng.integers(0, 4, n, dtype=np.uint32)
         b.cols["kind"][:] = self.kind

@@ -41,29 +41,52 @@ def group_codes(ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return vals.astype(ids.dtype) + lo, code
 
 
+def find_sorted(known: np.ndarray, vals: np.ndarray
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """Where each of `vals` stands in the ascending `known` (an index into
+    it, clipped to its last element) and whether it is there."""
+    if not len(known):
+        return (np.zeros(len(vals), dtype=np.intp),
+                np.zeros(len(vals), dtype=bool))
+    at = np.minimum(np.searchsorted(known, vals), len(known) - 1)
+    return at, known[at] == vals
+
+
 class SlotTable:
     """Ids given dense slots in the order they first appear: a batch's new
     ids ascending, as a `dict` filled from `np.unique` of each batch would
-    order them. The slots index the rows of an array the owner keeps."""
+    order them. The slots index the rows of an array the owner keeps. The
+    lookup is an array pass: the ids seen are kept sorted beside their
+    slots, a batch's distinct ids are found among them by one
+    `searchsorted`, and only a batch that brings new ids rebuilds the pair
+    (a node's containers are all seen within its first batches)."""
 
     def __init__(self):
-        self._slot: dict[int, int] = {}
+        self._ids: list[int] = []                      # by slot
+        self._sorted = np.zeros(0, dtype=np.uint64)    # the ids, ascending
+        self._slots = np.zeros(0, dtype=np.intp)       # their slots
 
     def __len__(self) -> int:
-        return len(self._slot)
+        return len(self._ids)
 
     def ids(self) -> list[int]:
         """The ids seen, by slot."""
-        return list(self._slot)
+        return list(self._ids)
 
     def slots_of(self, ids: np.ndarray) -> np.ndarray:
         """Each element's slot; ids not seen before take the next ones."""
         vals, code = group_codes(ids)
-        table = self._slot
-        slots = np.empty(len(vals), dtype=np.intp)
-        for i, v in enumerate(vals.tolist()):
-            slot = table.get(v)
-            if slot is None:
-                slot = table[v] = len(table)
-            slots[i] = slot
-        return slots[code]
+        vals = vals.astype(np.uint64, copy=False)
+        at, found = find_sorted(self._sorted, vals)
+        if not found.all():
+            fresh = vals[~found]
+            slots = np.arange(len(self._ids), len(self._ids) + len(fresh),
+                              dtype=np.intp)
+            self._ids.extend(fresh.tolist())
+            # both runs ascend: one stable merge keeps the pair sorted
+            merged = np.concatenate([self._sorted, fresh])
+            order = np.argsort(merged, kind="stable")
+            self._sorted = merged[order]
+            self._slots = np.concatenate([self._slots, slots])[order]
+            at = np.searchsorted(self._sorted, vals)
+        return self._slots[at][code]

@@ -193,7 +193,7 @@ def test_a_65th_container_doubles_the_slots_and_compiles_once(
     first = inst.harvest()               # the digest compiles here, once
     assert len(first.anomaly) == 64
     assert first.pipeline["anomaly"] == {"steps": 1, "containers": 64,
-                                         "slots": 64}
+                                         "slots": 64, "primed_slots": 64}
     base = compiles()
     inst.enrich_batch(container_batch(rng, 64))
     assert len(inst.harvest().anomaly) == 64

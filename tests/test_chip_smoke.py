@@ -48,7 +48,7 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
         phases[json.loads(line)["phase"]] = json.loads(line)
     assert list(phases) == ["acquire", "step-times", "local-runtime",
                             "invertible", "quantiles", "narrow", "anomaly",
-                            "agent", "done"]
+                            "anomaly-dense", "agent", "done"]
     # truthful about what ran: no kernel on the CPU, and it says so, at
     # the run's geometry and at the one where a TPU takes the kernel
     for times in (phases["step-times"], phases["step-times"]["narrow"]):
@@ -71,6 +71,17 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
     assert all(gap > anomaly["tolerance"]
                for gap in anomaly["fault_gaps"].values())
     assert (anomaly["containers"], anomaly["slots"]) == (64, 64)
+    # the same on a node of 1,024 containers, the scorer primed at the
+    # slots that hold them and every window's slices exact
+    dense = phases["anomaly-dense"]
+    assert dense["histograms_exact"] and dense["profile_exact"]
+    assert dense["score_gap"] <= dense["tolerance"]
+    assert all(gap > dense["tolerance"]
+               for gap in dense["fault_gaps"].values())
+    assert (dense["containers"], dense["slots"], dense["primed_slots"]) == (
+        1024, 1024, 1024)
+    assert dense["slices_exact"] and dense["slices_dropped"] == 0
+    assert dense["windows"] >= 2
     agent = phases["agent"]
     assert agent["checkpoints"] >= 2 and agent["checkpoint_failures"] == 0
     assert agent["generator"] == "native C++ synthetic"

@@ -60,6 +60,10 @@ def topo():
     yield desc
     jax.config.update("jax_enable_compilation_cache", was_on)
     cc.reset_cache()
+    # programs traced here while a test steered jax.default_backend() hold
+    # the TPU's kernels; a later file in this worker that traced the same
+    # shapes would be handed them on the CPU
+    jax.clear_caches()
 
 
 @pytest.fixture(scope="module")
@@ -173,19 +177,22 @@ def test_seal_window_read_compiles_as_one_program(one_chip):
     jax.jit(_seal_snapshot).lower(bundle).compile()
 
 
-def test_anomaly_step_compiles_at_the_configurations_size(one_chip):
+@pytest.mark.parametrize("slots", [64, 1024])
+def test_anomaly_step_compiles_at_the_configurations_size(one_chip, slots):
     """The scorer's one program a harvest (models/autoencoder.py
-    `anomaly_step`) at seccomp-node's size: 64 rows of 4,096 buckets
-    through 4096-256-64, bf16 products on f32 parameters, the scorer
-    donated so parameters and Adam's moments are updated in place."""
+    `anomaly_step`) at seccomp-node's size and at dense-node's: 64 and
+    1,024 rows of 4,096 buckets through 4096-256-64, bf16 products on f32
+    parameters, the scorer donated so parameters and Adam's moments are
+    updated in place."""
     from inspektor_gadget_tpu.models.autoencoder import (AEConfig, ae_init,
                                                          anomaly_step)
     cfg = AEConfig(input_dim=4096, hidden_dim=256, latent_dim=64)
     scorer = _on(one_chip, jax.eval_shape(lambda: ae_init(cfg)))
-    counts = jax.ShapeDtypeStruct((64, 4096), jnp.float32, sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+    counts = jax.ShapeDtypeStruct((slots, 4096), jnp.float32,
+                                  sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one_chip)
     new, scores = jax.eval_shape(anomaly_step, scorer, counts, mask)
-    assert scores.shape == (64,) and scores.dtype == jnp.float32
+    assert scores.shape == (slots,) and scores.dtype == jnp.float32
     compiled = anomaly_step.lower(scorer, counts, mask).compile()
     mem = compiled.memory_analysis()
     # parameters and two moments, 25.5 MB, alias their outputs

@@ -245,7 +245,7 @@ def test_the_cap_reached_after_the_kinds_were_admitted():
         store, loop, sealed = both(batches, cap)
         assert "kind:1" in sealed
         held = {k.split("|")[0] for k in sealed if k.startswith("mntns:")}
-        assert store.cells <= 3 * (len(held) + 1) < len(store._cells)
+        assert store.cells <= 3 * (len(held) + 1) < len(store._cell_words)
         assert sealed["kind:1"]["events"] > sum(
             s["events"] for k, s in sealed.items() if k.endswith("|kind:1"))
 
@@ -411,7 +411,8 @@ def test_served_windows_hold_what_the_loop_would_have_sealed():
             assert g["hh"] == w["hh"], key
         assert win.slices_dropped == 0
         stats.append({"slices": len(want), "cells": sum(
-            "|" in k for k in want), "hh_entries": loop.hh_entries})
+            "|" in k for k in want), "hh_entries": loop.hh_entries,
+            "admitted": len(want), "dropped": 0})
     assert at == len(tapped)
     # one kind: a slice a container, one a cell, and the kind's
     assert all(s["slices"] == 2 * s["cells"] + 1 for s in stats)
