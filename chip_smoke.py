@@ -4,6 +4,7 @@ starts on the chip.
     python chip_smoke.py [--seed N]            one TPU chip (what the driver runs)
     python chip_smoke.py --chips 4             the sharded-ingest path, four chips
     python chip_smoke.py --platform cpu        tiny rehearsal for the tests
+    python chip_smoke.py --only eventtime      one phase of the one-chip run
 
 One process owns the chip for the whole run, so every phase runs in THIS
 process — the agent phase serves in-process on a unix socket with its
@@ -37,6 +38,15 @@ raises, so the exit code is non-zero and no result line is printed):
                  at `history-max-slices 4096`: besides the above, every
                  sealed window's per-container slices against the exact
                  event counts of its batches, none dropped
+  eventtime      `trace exec` on chipbench/configs/exec-eventtime.json's
+                 stream (NEXmark's delayed events) with event-time windows
+                 behind a watermark: a recorder keeps every event's (key,
+                 ts), and every sealed window is held to
+                 chipbench/reference_windows.py (exact count, none late,
+                 aligned, every window sealed, top-k never under, HLL and
+                 entropy within their envelopes); then the same run with a
+                 window sealed early (no lateness in the watermark), which
+                 must fail that comparison
   agent          one RunGadget from AgentClient against the agent service
                  (`agent.main serve`'s AgentServer) with --checkpoint-dir
                  semantics: the checkpointer thread reads device state
@@ -107,6 +117,17 @@ ANOMALY = dict(vocab=64 * 335, harvest="100ms", harvests=40, head=8)
 # per-container slices are held to the exact event counts of its batches
 DENSE = dict(containers=1024, vocab=1024 * 335, max_slices=4096,
              window="1s")
+# the eventtime phase: chipbench/configs/exec-eventtime.json as it is on the
+# chip; on the CPU its stream and windows at a tenth of the time (1 s
+# windows, delays and lateness of 300 ms) at the rehearsal's geometry. A run
+# ends after `seals` windows sealed on the watermark; the teardown seals the
+# rest
+EVENTTIME_CONFIG = (Path(__file__).resolve().parent / "chipbench" / "configs"
+                    / "exec-eventtime.json")
+EVENTTIME_CPU = {"history-interval": "1s", "history-lateness": "300ms",
+                 "occasional-delay": "300ms"}
+EVENTTIME_SEALS = 2
+EVENTTIME_FAULTS = ("early", "processing")
 INV_VOCAB = 500        # inside every decode capacity used here: complete
 SHARD_VOCAB = 24       # < top-k on both sizes: the candidate table stays
 #                        exact on every path (tests/test_sharded_ingest.py)
@@ -796,6 +817,157 @@ def phase_anomaly(cfg, platform, seed, clock, dense: bool = False) -> None:
 
 
 # ---------------------------------------------------------------------------
+# phase: event-time windows against the plain reference of every event
+# ---------------------------------------------------------------------------
+
+def reference_windows():
+    """chipbench/reference_windows.py, imported as reference_scorer() is."""
+    reference_scorer()
+    import reference_windows as ref
+    return ref
+
+
+@contextlib.contextmanager
+def window_fault(name: str):
+    """A fault planted in the operator's event-time windows: `early` seals
+    a window once the newest event time passes its end (the watermark
+    without its lateness), `processing` routes every event of a batch into
+    the newest open window, as the processing clock would."""
+    from inspektor_gadget_tpu.operators import tpusketch
+    cls = tpusketch.TpuSketchInstance
+    real = {"early": cls._watermark_ns, "processing": cls._route}.get(name)
+
+    def early(self):
+        return real(self) + self._late_ns
+
+    def processing(self, ts, rows, span):
+        age, _groups = real(self, ts, rows, span)
+        age[:len(ts)] = 0
+        self._win_events[self._ev_hi] = (
+            self._win_events.get(self._ev_hi, 0) + len(ts))
+        return age, [(self._ev_hi, None)]
+
+    if name:
+        setattr(cls, {"early": "_watermark_ns",
+                      "processing": "_route"}[name], locals()[name])
+    try:
+        yield
+    finally:
+        if name:
+            setattr(cls, {"early": "_watermark_ns",
+                          "processing": "_route"}[name], real)
+
+
+def eventtime_settings(cfg: dict, platform: str, **source) -> tuple:
+    """(gadget parameters, operator parameters, window seconds) of the
+    eventtime phase: the configuration's own on a TPU, at the rehearsal's
+    sizes and a tenth of the time on the CPU."""
+    from inspektor_gadget_tpu.params.params import parse_duration
+    config = json.loads(EVENTTIME_CONFIG.read_text())
+    gadget = {**config["gadget_params"], "rate": str(cfg["rate"])}
+    operator = dict(config["operator"])
+    if platform == "cpu":
+        gadget.update({"batch-size": str(cfg["batch"]),
+                       "vocab": str(cfg["vocab"]),
+                       "occasional-delay": EVENTTIME_CPU["occasional-delay"]})
+        operator.update({**cfg["geometry"],
+                         "harvest-interval": cfg["harvest"],
+                         "history-interval": EVENTTIME_CPU["history-interval"],
+                         "history-lateness": EVENTTIME_CPU["history-lateness"]})
+    gadget.update(source)
+    return (gadget, operator, parse_duration(operator["history-interval"]),
+            config["limits"])
+
+
+def eventtime_run(cfg: dict, platform: str, seed: int, fault: str = "",
+                  seals: int = EVENTTIME_SEALS, behind_s: float = 0.0,
+                  **source) -> dict:
+    """`trace exec` with event-time windows until `seals` windows sealed on
+    the watermark, every event's folded key and ts recorded, and the
+    sealed windows (the teardown's too) read back from the store and held
+    to chipbench/reference_windows.py. `source` overrides gadget
+    parameters (a delay past the lateness: late events expected);
+    `behind_s` sets the operator's clock that far ahead of the events'
+    own, as a source that runs behind the clock sees it."""
+    import inspektor_gadget_tpu.all_gadgets  # noqa: F401
+    from inspektor_gadget_tpu.gadgets import GadgetContext, get
+    from inspektor_gadget_tpu.history import HISTORY, decode_window
+    from inspektor_gadget_tpu.params import Collection
+    from inspektor_gadget_tpu.params.params import parse_duration
+    from inspektor_gadget_tpu.runtime import LocalRuntime
+
+    gadget, operator, width, limits = eventtime_settings(cfg, platform,
+                                                         **source)
+    desc = get("trace", "exec")
+    params = desc.params().to_params()
+    for k, v in {**gadget, "seed": str(seed)}.items():
+        params.set(k, str(v))
+    history_dir = tempfile.mkdtemp(prefix="ig-smoke-eventtime-")
+    op_params = Collection()
+    op_params["operator.tpusketch."] = sketch_params(
+        {**cfg, "harvest": operator["harvest-interval"]},
+        {**operator, "audit-sample": "0", "history-dir": history_dir})
+    keys: list[np.ndarray] = []
+    stamps: list[np.ndarray] = []
+    sealed: list[dict] = []
+    pipeline: dict = {}
+
+    def on_batch(batch) -> None:
+        n = batch.count
+        keys.append(fold32(batch.cols["key_hash"][:n]))
+        stamps.append(batch.cols["ts"][:n].copy())
+
+    def on_sealed(header: dict) -> None:
+        sealed.append(header)
+        if len(sealed) >= seals:
+            ctx.cancel()
+
+    extra = {"on_window_sealed": on_sealed,
+             "on_sketch_summary": lambda s: pipeline.update(s.pipeline)}
+    if behind_s:
+        extra["history_clock"] = lambda: time.time() + behind_s
+    ctx = GadgetContext(desc, gadget_params=params, operator_params=op_params,
+                        timeout=cfg["deadline"], extra=extra)
+    try:
+        with window_fault(fault):
+            result = LocalRuntime().run_gadget(ctx, on_batch=on_batch)
+        windows = [decode_window(h, p) for h, p in HISTORY.fetch_windows(
+            base_dir=history_dir, gadget=desc.full_name)]
+    finally:
+        HISTORY.close_all()
+        shutil.rmtree(history_dir, ignore_errors=True)
+    require(not result.errors(), f"gadget run failed: {result.errors()}")
+    keys_all, ts_all = np.concatenate(keys), np.concatenate(stamps)
+    geometry = {k: int(operator.get(k, d)) for k, d in (
+        ("hll-p", 14), ("entropy-log2-width", 12))}
+    delay = parse_duration(gadget.get("occasional-delay", "0s"))
+    late_expected = delay > parse_duration(operator["history-lateness"])
+    ok, rows = reference_windows().compare(
+        windows, keys_all, ts_all, width_s=width, geometry=geometry,
+        limits=limits, expect_late=late_expected)
+    return {"ok": ok, "compared": rows, "windows": windows,
+            "events": len(keys_all), "width_s": width,
+            "announced": sealed, "keys": keys_all, "ts": ts_all,
+            "pipeline": pipeline}
+
+
+def phase_eventtime(cfg, platform, seed, clock) -> None:
+    sound = eventtime_run(cfg, platform, seed + 20)
+    rows = sound["compared"]
+    require(sound["ok"], "sealed event-time windows differ from the plain "
+            f"reference: {rows}")
+    early = eventtime_run(cfg, platform, seed + 21, fault="early", seals=1)
+    require(not early["ok"], "a window sealed before its late events came "
+            f"passes the comparison: {early['compared']}")
+    say(phase="eventtime", events=sound["events"],
+        windows=len(sound["windows"]), width_s=sound["width_s"],
+        compared={k: v["value"] for k, v in rows.items()},
+        early_fault={k: v["value"] for k, v in early["compared"].items()
+                     if v["limit"] is not None
+                     and v["value"] > v["limit"]}, **clock.take())
+
+
+# ---------------------------------------------------------------------------
 # phase: through the agent (in-process service, client on its own thread)
 # ---------------------------------------------------------------------------
 
@@ -1020,6 +1192,11 @@ def phase_sharded(cfg, platform, seed, chips, clock) -> None:
 
 # ---------------------------------------------------------------------------
 
+ONE_CHIP_PHASES = ("step-times", "local-runtime", "invertible", "quantiles",
+                   "narrow", "anomaly", "anomaly-dense", "eventtime",
+                   "agent")
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(prog="chip_smoke.py")
     ap.add_argument("--seed", type=int, default=20240921)
@@ -1029,6 +1206,10 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run ONLY the sharded-ingest path across four "
                          "chips and its single-chip comparison")
+    ap.add_argument("--only", action="append", default=[],
+                    choices=ONE_CHIP_PHASES,
+                    help="run only this phase of the one-chip run "
+                         "(repeatable)")
     args = ap.parse_args(argv)
 
     import jax
@@ -1061,14 +1242,15 @@ def main(argv: list[str] | None = None) -> int:
         # the native source is part of the served path: building it (make
         # decides) must work here, loudly
         NativeCapture(1).close()
-        phase_step_times(cfg, platform, args.seed, clock)
-        phase_local_runtime(cfg, platform, args.seed, clock)
-        phase_invertible(cfg, platform, args.seed, clock)
-        phase_quantiles(cfg, platform, args.seed, clock)
-        phase_narrow(cfg, platform, args.seed, clock)
-        phase_anomaly(cfg, platform, args.seed, clock)
-        phase_anomaly(cfg, platform, args.seed + 10, clock, dense=True)
-        phase_agent(cfg, platform, args.seed, clock)
+        for name in ONE_CHIP_PHASES:
+            if args.only and name not in args.only:
+                continue
+            if name == "anomaly-dense":
+                phase_anomaly(cfg, platform, args.seed + 10, clock,
+                              dense=True)
+            else:
+                globals()["phase_" + name.replace("-", "_")](
+                    cfg, platform, args.seed, clock)
     say(phase="done", seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": jax.devices()[0].platform,

@@ -90,6 +90,14 @@ class BatchHandlerSetter(Protocol):
 
 
 @runtime_checkable
+class IdleHandlerSetter(Protocol):
+    """Called on a turn of the gadget's loop that found no event, so an
+    operator can act on time alone (an idle source's windows seal)."""
+
+    def set_idle_handler(self, handler: Callable[[], None]) -> None: ...
+
+
+@runtime_checkable
 class MountNsFilterSetter(Protocol):
     """ref: tracer SetMountNsMap (pkg/gadgets/trace/exec/tracer/tracer.go:
     SetMountNsMap) — the container-filter injection point. Here a set of

@@ -56,6 +56,11 @@ def _validate_containers(value: str) -> None:
             "(upstream's MaxContainersPerNode)")
 
 
+def _validate_probability(value: str) -> None:
+    if not 0.0 <= float(value) <= 1.0:
+        raise ValueError(f"{value} is outside 0..1")
+
+
 def source_params() -> ParamDescs:
     """Params shared by every capture-backed gadget."""
     return ParamDescs([
@@ -71,6 +76,16 @@ def source_params() -> ParamDescs:
                   description="fake containers the synthetic stream is "
                               "spread over (mntns = base + key rank % "
                               "containers)"),
+        # NEXmark's delayed events (Beam NexmarkConfiguration
+        # probDelayedEvent / occasionalDelaySec): off by default
+        ParamDesc(key="prob-delayed-event", default="0",
+                  type_hint=TypeHint.FLOAT, validator=_validate_probability,
+                  description="share of synthetic events whose event time "
+                              "(ts) is older than their emission"),
+        ParamDesc(key="occasional-delay", default="0s",
+                  type_hint=TypeHint.DURATION,
+                  description="largest delay of a delayed event; each "
+                              "delay is uniform on (0, occasional-delay]"),
         ParamDesc(key="seed", default="0", type_hint=TypeHint.INT),
         ParamDesc(key="batch-size", default="8192", type_hint=TypeHint.INT),
     ])
@@ -275,6 +290,7 @@ class SourceTraceGadget:
         self.ctx = ctx
         self._event_handler: Callable[[Any], None] | None = None
         self._batch_handler: Callable[[EventBatch], None] | None = None
+        self._idle_handler: Callable[[], None] | None = None
         self._mntns_filter: set[int] | None = None
         # display filters pushed down by the CLI (ctx.extra) so the hot
         # loop only materializes surviving rows (ref: the tracer hot-loop
@@ -303,6 +319,11 @@ class SourceTraceGadget:
         self._zipf = p.get("zipf").as_float() if "zipf" in p else 1.2
         self._synth_containers = (p.get("containers").as_int()
                             if "containers" in p else 64)
+        self._synth_delays = {
+            "prob_delayed_event": (p.get("prob-delayed-event").as_float()
+                                   if "prob-delayed-event" in p else 0.0),
+            "occasional_delay": (p.get("occasional-delay").as_duration()
+                                 if "occasional-delay" in p else 0.0)}
         self._seed = p.get("seed").as_int() if "seed" in p else 0
         self._batch_size = p.get("batch-size").as_int() if "batch-size" in p else 8192
         self.source = None
@@ -321,6 +342,9 @@ class SourceTraceGadget:
 
     def set_batch_handler(self, handler: Callable[[EventBatch], None]) -> None:
         self._batch_handler = handler
+
+    def set_idle_handler(self, handler: Callable[[], None]) -> None:
+        self._idle_handler = handler
 
     def set_mntns_filter(self, mntns_ids: set[int] | None) -> None:
         self._mntns_filter = mntns_ids
@@ -421,7 +445,8 @@ class SourceTraceGadget:
                                 rate=self._rate, vocab=self._vocab,
                                 zipf_s=self._zipf, ring_pow2=20,
                                 batch_size=self._batch_size,
-                                containers=self._synth_containers)
+                                containers=self._synth_containers,
+                                **self._synth_delays)
             if self._mntns_filter is not None:
                 src.set_filter(self._mntns_filter)
             src.start()
@@ -431,7 +456,8 @@ class SourceTraceGadget:
         return PySyntheticSource(kind=self.synth_kind, seed=self._seed,
                                  vocab=self._vocab, zipf_s=self._zipf,
                                  batch_size=self._batch_size,
-                                 containers=self._synth_containers)
+                                 containers=self._synth_containers,
+                                 **self._synth_delays)
 
     # per-container attach (ref: localmanager.go:230-260 Attacher path) -----
 
@@ -536,6 +562,8 @@ class SourceTraceGadget:
                 if got == 0:
                     if self._source_done():
                         break  # e.g. traced command exited, ring drained
+                    if self._idle_handler is not None:
+                        self._idle_handler()
                     with st_wait:
                         done = ctx.sleep_or_done(0.01)
                     if done:

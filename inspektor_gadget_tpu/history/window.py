@@ -604,6 +604,13 @@ class SealedWindow:
     rs_keys: np.ndarray | None = None
     rs_weights: np.ndarray | None = None
     rs_capacity: int = 0
+    # -- event-time windows (tpusketch history-lateness) ------------------
+    # start_ts/end_ts are then the window's event-time bounds, and `late`
+    # the events that came after the window they belong to had sealed
+    # since the seal before this one: absorbed by the cumulative state, in
+    # no window. It enters the digest and the header only when nonzero, so
+    # every processing-time window is byte-identical to before.
+    late: int = 0
 
     @property
     def slice_keys(self) -> list[str]:
@@ -668,6 +675,7 @@ def window_digest(win: SealedWindow) -> str:
             "rs_weights": arr(win.rs_weights),
             "rs_capacity": int(win.rs_capacity)}
            if win.rs_keys is not None else {}),
+        **({"late": int(win.late)} if win.late else {}),
         "cms": arr(win.cms),
         "hll": arr(win.hll),
         "ent": arr(win.ent),
@@ -761,6 +769,8 @@ def encode_window(win: SealedWindow) -> tuple[dict, bytes]:
         header["approx"] = True
     if win.rs_keys is not None:
         header["rs_capacity"] = int(win.rs_capacity)
+    if win.late:
+        header["late"] = int(win.late)
     return header, buf.getvalue()
 
 
@@ -812,6 +822,7 @@ def decode_window(header: dict, payload: bytes) -> SealedWindow:
         rs_keys=arrays.get("rs_keys"),
         rs_weights=arrays.get("rs_weights"),
         rs_capacity=int(header.get("rs_capacity", 0)),
+        late=int(header.get("late", 0)),
     )
 
 

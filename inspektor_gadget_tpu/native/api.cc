@@ -69,21 +69,25 @@ enum {
 
 uint64_t ig_source_create(uint32_t kind, uint64_t seed, double rate,
                           uint32_t vocab, double zipf_s, uint32_t ring_pow2,
-                          uint32_t containers) {
+                          uint32_t containers, double prob_delayed,
+                          uint64_t max_delay_ns) {
   size_t cap = 1ull << (ring_pow2 ? ring_pow2 : 20);
   Source* s = nullptr;
   switch (kind) {
     case IG_SRC_SYNTH_EXEC:
       s = new SyntheticSource(cap, EV_EXEC, seed, rate, vocab, zipf_s,
-                              containers);
+                              containers, prob_delayed,
+                              max_delay_ns);
       break;
     case IG_SRC_SYNTH_TCP:
       s = new SyntheticSource(cap, EV_TCP_CONNECT, seed, rate, vocab, zipf_s,
-                              containers);
+                              containers, prob_delayed,
+                              max_delay_ns);
       break;
     case IG_SRC_SYNTH_DNS:
       s = new SyntheticSource(cap, EV_DNS, seed, rate, vocab, zipf_s,
-                              containers);
+                              containers, prob_delayed,
+                              max_delay_ns);
       break;
 #ifdef __linux__
     case IG_SRC_PROC_EXEC:

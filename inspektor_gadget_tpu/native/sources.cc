@@ -249,14 +249,17 @@ class SyntheticSource : public Source {
  public:
   SyntheticSource(size_t ring_pow2, uint32_t kind, uint64_t seed,
                   double rate_per_sec, uint32_t vocab_size, double zipf_s,
-                  uint32_t containers)
+                  uint32_t containers, double prob_delayed = 0.0,
+                  uint64_t max_delay_ns = 0)
       : Source(ring_pow2),
         kind_(kind),
         rng_(seed ? seed : 0x9E3779B97F4A7C15ull),
         rate_(rate_per_sec),
         vocab_size_(vocab_size ? vocab_size : 1000),
         zipf_s_(zipf_s > 0 ? zipf_s : 1.2),
-        containers_(containers ? containers : 64) {
+        containers_(containers ? containers : 64),
+        prob_delayed_(max_delay_ns ? prob_delayed : 0.0),
+        max_delay_ns_(max_delay_ns) {
     // Zipf sampling via Walker's alias method: O(1) per draw (one random,
     // one table probe) instead of a CDF binary search — keeps the host
     // generation path well above the device-feed requirement.
@@ -363,6 +366,17 @@ class SyntheticSource : public Source {
     const std::string& nm = names_[idx];
     size_t n = nm.size() < sizeof(ev.comm) ? nm.size() : sizeof(ev.comm) - 1;
     memcpy(ev.comm, nm.data(), n);
+    if (prob_delayed_ > 0.0) {
+      // NEXmark's delayed events: with probability p the event carries an
+      // event time d older than its emission, d uniform on (0, max delay]
+      // in ns. Drawn after every other field and only when p > 0, so a
+      // stream without delays is the one it always was
+      double u = (double)(next_rand() >> 11) * (1.0 / 9007199254740992.0);
+      if (u < prob_delayed_) {
+        uint64_t d = 1 + next_rand() % max_delay_ns_;
+        ev.ts_ns = ev.ts_ns > d ? ev.ts_ns - d : 0;
+      }
+    }
     return ev;
   }
 
@@ -372,6 +386,8 @@ class SyntheticSource : public Source {
   uint32_t vocab_size_;
   double zipf_s_;
   uint32_t containers_;
+  double prob_delayed_;
+  uint64_t max_delay_ns_;
   std::vector<double> alias_prob_;
   std::vector<uint32_t> alias_idx_;
   std::vector<std::string> names_;

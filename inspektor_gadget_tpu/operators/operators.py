@@ -82,6 +82,9 @@ class OperatorInstance:
     def enrich_batch(self, batch: Any) -> None:
         pass
 
+    def idle(self) -> None:
+        """The gadget's loop found no event on this turn."""
+
 
 class Operators(list):
     """Ordered list of OperatorInstance with the enrich chain."""
@@ -130,6 +133,10 @@ class Operators(list):
         if events is not None and n:
             events.inc(n)
         return batch
+
+    def idle(self) -> None:
+        for inst in self:
+            inst.idle()
 
 
 _REGISTRY: dict[str, Operator] = {}

@@ -17,6 +17,7 @@ qnames, `trace tcp` counts flows, with zero per-gadget code.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import threading
 import time
@@ -35,7 +36,6 @@ from ..gadgets.context import GadgetContext
 from ..gadgets.interface import GadgetDesc
 from ..models.autoencoder import AEConfig, ae_init, anomaly_step
 from ..ops import bundle_init, fold64_to_32
-from ..ops.hll import hll_init, hll_update
 from ..ops.invertible import (InvSketch, class_weights, inv_capacity,
                               inv_decode, inv_init, inv_update,
                               parse_priority_classes,
@@ -44,7 +44,9 @@ from ..ops.sketches import (bundle_digest_jit, bundle_ingest_jit,
                             bundle_stack_sharded, decode_digest,
                             make_bundle_harvest_sharded,
                             make_bundle_ingest_sharded, update_arm)
-from ..ops.window import wcms_advance, wcms_init, wcms_query, wcms_update
+from ..ops.window import (LATE_AGE, window_capture, window_close,
+                          window_open, window_planes_init,
+                          window_planes_step)
 from ..params import ParamDesc, ParamDescs, ParamError, Params, TypeHint
 from ..params.validators import validate_int_range
 from ..sources.batch import BATCH_COLUMNS, EventBatch, FoldedBatch
@@ -156,6 +158,23 @@ _tm_slices = counter(
     "subpopulation slices of the sealed windows by the decision their "
     "window's store made at their first appearance: admitted, or dropped "
     "over history-max-slices", ("gadget", "decision"))
+# the windows themselves (labelled likewise): how many are open, how long
+# after its end a window's capture came, and the events that came after
+# their window had sealed (event-time windows only: history-lateness > 0)
+_tm_open_windows = gauge(
+    "ig_history_open_windows",
+    "history windows open: one on the processing clock; on event time "
+    "every window whose end the watermark has not passed", ("gadget",))
+_tm_seal_delay_s = histogram(
+    "ig_history_seal_delay_seconds",
+    "a boundary seal's capture, wall time, less its window's end: a turn "
+    "on the processing clock, history-lateness plus the backlog on event "
+    "time", ("gadget",))
+_tm_late_events = counter(
+    "ig_history_late_events_total",
+    "events older than the oldest open event-time window: absorbed into "
+    "the cumulative sketches, in no window, carried in the next sealed "
+    "window's `late`", ("gadget",))
 # the two halves of a seal (labelled likewise): who finished the window, the
 # boundaries that found the worker still on the one before, and what a
 # finish takes wherever it runs
@@ -241,11 +260,13 @@ SEAL_QUIET_S = 0.06
 # doubled when more appear than it holds. A constant, not an option.
 CONTAINER_SLOTS_FLOOR = 64
 
-# window-plane device steps (history sealing): the WindowedCMS ring
-# rotates at each boundary (current slot = this window's CMS) and a
-# fresh HLL per window tracks its distinct stream (`_wcms_window_step`);
-# entropy and events/drops come as deltas of the cumulative bundle
-# (additive state is exactly subtractable, HLL is not)
+# window-plane device programs (history sealing, ops/window.py): one
+# step a batch puts each event into the planes of its window (count-min
+# ring slot, HLL, entropy, exact count); a seal's capture reads one
+# window's planes and, where the next window opens, `window_open` zeroes
+# the plane it reuses (on the processing clock both are one program,
+# `window_close`). drops and the invertible/quantile planes come as
+# deltas of the cumulative bundle (additive state is exactly subtractable)
 
 
 # The ingest step is ops.sketches.bundle_ingest_jit: staged uint32 weights
@@ -257,33 +278,12 @@ CONTAINER_SLOTS_FLOOR = 64
 _ingest_jit = bundle_ingest_jit
 
 
-def _wcms_ingest_step(w, keys, weights):
-    out = wcms_update(w, keys, weights)
-    return out, out.slots[0, 0, :1] + 0
-
-
-def _hll_ingest_step(h, keys, mask):
-    out = hll_update(h, keys, mask)
-    return out, out.registers[:1] + 0
-
-
 def _inv_class_ingest_step(s, keys, weights):
     """One priority class absorbing its share of a staged batch (weights
     zeroed outside the class's tenants). Second output is the fence
     token (fresh, never donated downstream) — the PR-7 contract."""
     out = inv_update(s, keys, weights)
     return out, out.count[0, :1] + 0
-
-
-def _wcms_window_step(w, cand, h):
-    """A seal's share of the window planes, as one program: what its
-    finish reads of the window CMS (the ring's current slot and the
-    candidates' estimates against it: eagerly the hashes and gathers are
-    some ninety dispatches a seal), and the planes of the next window (the
-    ring advanced, an HLL of `h`'s shape with its registers at zero).
-    Nothing is donated: the slot and `h` are read after the capture."""
-    return (w.slots[w.epoch], wcms_query(w, cand, last_k=1), wcms_advance(w),
-            h.replace(registers=jnp.zeros_like(h.registers)))
 
 
 def _seal_snapshot(b):
@@ -293,14 +293,14 @@ def _seal_snapshot(b):
     inv = None if b.inv is None else (b.inv.count, b.inv.keysum, b.inv.fpsum)
     qt = (None if b.quantiles is None else
           (b.quantiles.counts, b.quantiles.zeros, b.quantiles.total))
-    return (b.events, b.drops, b.entropy.counts, b.topk.keys,
-            b.topk.overflow, inv, qt)
+    return b.drops, b.topk.keys, b.topk.overflow, inv, qt
 
 
-_wcms_ingest_jit = jax.jit(_wcms_ingest_step, donate_argnums=0)
-_wcms_window_jit = jax.jit(_wcms_window_step)
+_window_step_jit = jax.jit(window_planes_step, donate_argnums=0)
+_window_capture_jit = jax.jit(window_capture)
+_window_open_jit = jax.jit(window_open)
+_window_close_jit = jax.jit(window_close)
 _seal_snapshot_jit = jax.jit(_seal_snapshot)
-_hll_ingest_jit = jax.jit(_hll_ingest_step, donate_argnums=0)
 _inv_class_jit = jax.jit(_inv_class_ingest_step, donate_argnums=0)
 
 
@@ -317,7 +317,10 @@ class _WindowCapture:
     snap: tuple                 # _seal_snapshot of the (merged) bundle
     cms: Any                    # the window CMS's slot of this window ...
     counts: Any                 # ... and the candidates' estimates in it
-    hll: Any                    # the window HLL's registers
+    hll: Any                    # the window's HLL registers ...
+    ent: Any                    # ... entropy histogram ...
+    events: Any                 # ... and exact count of events
+    late: int                   # late events since the seal before
     slices: Any                 # the window's WindowSlices
     shadow: tuple | None        # window shadow sample: keys, weights, capacity
     labels: tuple               # _labels_as_found: the label sample ring
@@ -555,6 +558,14 @@ class TpuSketch(Operator):
                       type_hint=TypeHint.DURATION,
                       description="window length; 0 seals one window per "
                                   "harvest (the deterministic-replay mode)"),
+            ParamDesc(key="history-lateness", default="0s",
+                      type_hint=TypeHint.DURATION,
+                      description="0: windows cut on the processing clock; "
+                                  "> 0: windows of history-interval aligned "
+                                  "to event time (each event's ts), sealed "
+                                  "once the watermark (the newest event "
+                                  "time less this lateness) passes their "
+                                  "end; older events are counted late"),
             ParamDesc(key="history-dir", default="",
                       description="override the node history area for this "
                                   "run ($IG_HISTORY_DIR / agent "
@@ -670,13 +681,13 @@ class TpuSketchInstance(OperatorInstance):
         # on the profiler's clock, seconds into the run's TurnClock
         self.times_own_stages = True
         self._turn = turn = ctx.turn
-        (self._st_fold, self._st_h2d, self._st_restage, self._st_update,
-         self._st_planes, self._st_slices, self._st_inv, self._st_post,
-         self._st_seal, self._st_merge, self._st_harvest) = (
+        (self._st_fold, self._st_route, self._st_h2d, self._st_restage,
+         self._st_update, self._st_planes, self._st_slices, self._st_inv,
+         self._st_post, self._st_seal, self._st_merge, self._st_harvest) = (
              turn.stage("tpusketch_" + n) for n in (
-                 "fold", "h2d", "shard_restage", "update", "window_planes",
-                 "slices", "inv_classes", "post", "seal", "shard_merge",
-                 "harvest"))
+                 "fold", "window_route", "h2d", "shard_restage", "update",
+                 "window_planes", "slices", "inv_classes", "post", "seal",
+                 "shard_merge", "harvest"))
         # the timed waits of the loop thread: the digest's read, the
         # stager's retire, the seal drain's join
         self._w_harvest = turn.wait(HARVEST_WAIT)
@@ -740,6 +751,38 @@ class TpuSketchInstance(OperatorInstance):
             raise ParamError(
                 f"param 'quantile-field': {self._qt_field!r} is not a "
                 f"wire column (one of {', '.join(BATCH_COLUMNS)})")
+        # -- event-time history windows -----------------------------------
+        # The same loud validation, before the first batch. The invertible
+        # and quantile planes ride a window as deltas of the cumulative
+        # bundle between two boundaries, which no event time gives
+        lateness = (p.get("history-lateness").as_duration()
+                    if "history-lateness" in p else 0.0) or 0.0
+        self._event_time = lateness > 0
+        if lateness < 0:
+            raise ParamError("param 'history-lateness': must not be negative")
+        if self._event_time:
+            if not (p.get("history").as_bool() if "history" in p else False):
+                raise ParamError("param 'history-lateness': needs 'history "
+                                 "true' — it decides when windows seal")
+            if self._inv_on or self._qt_on:
+                raise ParamError(
+                    "param 'history-lateness': not with 'invertible' or "
+                    "'quantiles' — their window planes are deltas of the "
+                    "cumulative state between two processing-time "
+                    "boundaries, not per event-time window")
+            interval = (p.get("history-interval").as_duration()
+                        if "history-interval" in p else 10.0) or 0.0
+            if interval <= 0:
+                raise ParamError(
+                    "param 'history-lateness': needs a history-interval > 0 "
+                    "(0 seals a window a harvest, on the processing clock)")
+            slots = (p.get("history-slots").as_int()
+                     if "history-slots" in p else 8)
+            if slots < 2 + math.ceil(lateness / interval):
+                raise ParamError(
+                    f"param 'history-slots': {slots} ring slots cannot hold "
+                    f"the {2 + math.ceil(lateness / interval)} windows "
+                    f"history-lateness keeps open")
         # -- accuracy audit plane (ISSUE 19) ------------------------------
         # Host-side deterministic bottom-k shadow sample: run-scoped for
         # harvest audits, window-scoped for sealed-window rs lanes. Off
@@ -927,6 +970,8 @@ class TpuSketchInstance(OperatorInstance):
             self._astats.register()
         # -- sketch-history plane (sealed windows, history/) --------------
         self._hist_on = p.get("history").as_bool() if "history" in p else False
+        # the window age of a batch on the processing clock: the open one
+        self._age0 = jnp.zeros((), jnp.int32) if self._hist_on else None
         if self._hist_on:
             self._hist_interval = (p.get("history-interval").as_duration()
                                    if "history-interval" in p else 10.0) or 0.0
@@ -945,14 +990,40 @@ class TpuSketchInstance(OperatorInstance):
                                  or ctx.desc.full_name)
             self._hist_clock = (ctx.extra.get("history_clock")
                                 or ctx.extra.get("alerts_clock") or time.time)
-            self._wcms = wcms_init(n_slots=self._hist_slots,
-                                   depth=p.get("depth").as_int(),
-                                   log2_width=self._hist_log2w)
-            self._win_hll = hll_init(p.get("hll-p").as_int())
+            # planes for every window that can be open at once: one on the
+            # processing clock; on event time the window the watermark is
+            # in, those the lateness spans, and the newest
+            n_open = (2 + math.ceil(lateness / self._hist_interval)
+                      if self._event_time else 1)
+            self._wplanes = window_planes_init(
+                n_open, self._hist_slots, p.get("depth").as_int(),
+                self._hist_log2w, p.get("hll-p").as_int(),
+                p.get("entropy-log2-width").as_int())
             self._win_n = 0
             self._win_start = self._hist_clock()
+            # event time, in ns of the events' own clock: windows
+            # [k W, (k+1) W); `_ev_lo` the oldest the watermark has not
+            # passed, `_ev_hi` the newest opened (below _ev_lo: none open)
+            self._win_ns = round(self._hist_interval * 1e9)
+            self._late_ns = round(lateness * 1e9)
+            self._ev_lo: int | None = None
+            self._ev_hi = -1
+            self._ev_newest_ns = self._ev_batch_newest = 0
+            # the clock at the last batch: how long the source has been idle
+            self._ev_batch_at = self._win_start
+            self._late_pending = 0
+            self._late_total = 0
+            # events routed to each open window, and its subpopulation
+            # store (processing time: window 0, the open one)
+            self._win_events: dict[int, int] = {}
+            self._win_slices: dict[int, Any] = {}
+            self._m_open_windows = _tm_open_windows.labels(gadget=g)
+            self._m_open_windows.set(0 if self._event_time else 1)
+            self._m_seal_delay = _tm_seal_delay_s.labels(gadget=g)
+            if self._event_time:
+                self._m_late_events = _tm_late_events.labels(gadget=g)
             from ..history import HISTORY, WindowSlices
-            self._win_slices = WindowSlices(self._hist_max_slices)
+            self._slices_cls = WindowSlices
             self._last_slices: dict[str, int] | None = None
             self._m_slice_hh = _tm_slice_hh_entries.labels(gadget=g)
             self._m_slices = {
@@ -1228,8 +1299,9 @@ class TpuSketchInstance(OperatorInstance):
         CONTAINER_SLOTS_FLOOR), on a copy of the scorer (the step donates
         what it is given, and a step on the scorer itself would advance
         Adam's count); containers that appear beyond them double the slots
-        inside the run, as before. With history on, the two programs a
-        seal's capture dispatches are run once too."""
+        inside the run, as before. With history on, the programs a seal
+        dispatches (the bundle's snapshot, the window's capture and the
+        opening of the next) are run once too, their outputs dropped."""
         if not self.enabled:
             return
         if self._anomaly_step is not None:
@@ -1243,12 +1315,15 @@ class TpuSketchInstance(OperatorInstance):
         if self._shard_on:
             return
         if self._hist_on:
-            # the two programs a seal's capture dispatches, on the state
-            # as it stands: they donate nothing
+            # what a seal dispatches, on the state as it stands: nothing
+            # here is donated, and the outputs are dropped
             with self._bundle_mu:
                 snap = _seal_snapshot_jit(self.bundle)
-            jax.block_until_ready(_wcms_window_jit(
-                self._wcms, self._beside_wcms(snap[3]), self._win_hll))
+            cand = self._beside_planes(snap[1])
+            jax.block_until_ready(
+                (_window_capture_jit(self._wplanes, cand, np.int32(0)),
+                 _window_open_jit(self._wplanes)) if self._event_time
+                else _window_close_jit(self._wplanes, cand))
         rows = STEP_ROWS_FLOOR
         while rows <= self._pad and not self.ctx.done:
             z = jnp.asarray(np.zeros(rows, np.uint32))
@@ -1262,7 +1337,9 @@ class TpuSketchInstance(OperatorInstance):
                     self._inv_classes[i] = (c, s)
                     toks.append(tok)
             if self._hist_on:
-                toks += self._window_steps(z, z, z)
+                toks.append(self._window_step(
+                    z, z, z, z, jnp.asarray(np.full(rows, LATE_AGE, np.uint8))
+                    if self._event_time else self._age0))
             jax.block_until_ready(toks)
             rows *= 2
 
@@ -1576,19 +1653,34 @@ class TpuSketchInstance(OperatorInstance):
                 tmin = float(batch.cols["ts"][:n].min())
                 if tmin > 0.0:
                     oldest = tmin / 1e9
+        # the history window each event lands in: on the processing clock
+        # the open one (a scalar age); on event time one age a row (a
+        # narrow lane staged with the others) and the windows the batch's
+        # slices go to. A jump of the event clock past what the planes hold
+        # seals the oldest window first, in the seal's own stage
+        age, groups = None, None
+        if self._hist_on:
+            ts = batch.cols["ts"][:n]
+            span = None
+            if self._event_time:
+                with self._st_route:
+                    span = int(ts.min()), int(ts.max())
+                self._make_room(*span)
+            with self._st_route:
+                age, groups = self._route(ts, rows, span)
         # ONE async device put per distinct lane (shared columns stage
         # once); the transfer of this batch overlaps device compute of
         # the previous one — the block returns to the pool only after
         # the consumer fence completes
         with self._st_h2d:
-            puts = stager.stage(
-                block, list(lanes.values()) + [w]
-                + ([vals] if vals is not None else []))
+            extra = [a for a in (vals, age) if a is not None]
+            puts = stager.stage(block, list(lanes.values()) + [w] + extra)
             nk = len(lanes)
             by_col = dict(zip(lanes, puts))
             staged = (by_col[self.hh_col], by_col[self.distinct_col],
                       by_col[self.dist_col], puts[nk],
-                      puts[nk + 1] if vals is not None else None)
+                      puts[nk + 1] if vals is not None else None,
+                      puts[-1] if age is not None else self._age0)
 
         def late() -> None:
             # late enrichment (display-only work off the ingest path): two
@@ -1597,10 +1689,11 @@ class TpuSketchInstance(OperatorInstance):
             self._label_sample(batch, hh, n)
 
         self._absorb_staged(
-            stager, staged, (hh, distinct, w), n, t0, drops=batch.drops,
-            pop_ts=batch.pop_ts, oldest_ts=oldest, mntns=mntns, vals=vals,
+            stager, staged, (hh, distinct, dist, w, age), n, t0,
+            drops=batch.drops, pop_ts=batch.pop_ts, oldest_ts=oldest,
+            mntns=mntns, vals=vals,
             slices=lambda: self._accumulate_slices(batch, n, hh, distinct,
-                                                   dist),
+                                                   dist, groups),
             late=late,
             dists=((lambda: self._accumulate_container_dists(batch, n))
                    if self.anomaly_on else None))
@@ -1616,9 +1709,14 @@ class TpuSketchInstance(OperatorInstance):
         arrays, so sealed windows stay correct — but they carry NO
         subpopulation slices (the wire's kind column does not exist on
         the folded path) and no anomaly distributions; gadgets that need
-        either must ingest through enrich_batch."""
+        either must ingest through enrich_batch, and so must a run with
+        event-time windows, which routes each event by its ts."""
         if not self.enabled or fb.count == 0:
             return
+        if self._hist_on and self._event_time:
+            raise ValueError("history-lateness routes each event by its ts, "
+                             "which a folded batch does not carry: ingest "
+                             "through enrich_batch")
         n = fb.count
         rows = self._step_rows(n, fb.capacity)
         t0 = time.perf_counter()
@@ -1636,8 +1734,10 @@ class TpuSketchInstance(OperatorInstance):
                     lane[n:] = 0
             k_d, w_d, *v_d = stager.stage(fb.lanes, host)
         self._absorb_staged(
-            stager, (k_d, k_d, k_d, w_d, v_d[0] if v_d else None),
-            (keys, keys, weights), n, t0, drops=fb.drops, pop_ts=fb.pop_ts,
+            stager, (k_d, k_d, k_d, w_d, v_d[0] if v_d else None,
+                     self._age0),
+            (keys, keys, keys, weights, None), n, t0, drops=fb.drops,
+            pop_ts=fb.pop_ts,
             oldest_ts=fb.oldest_ts, mntns=fb.mntns[:rows], vals=vals)
 
     def _absorb_staged(self, stager: H2DStager, staged: tuple, host: tuple,
@@ -1647,11 +1747,12 @@ class TpuSketchInstance(OperatorInstance):
                        late=None, dists=None) -> None:
         """The one dispatch of a staged batch of `n` events, whichever
         adapter staged it (at `t0`): `staged` is the device arrays (hh,
-        distinct, dist, weights, values or None) of the stager's last
-        slot, `host` the pinned lanes (hh, distinct, weights) they were
-        put from, `drops` the source's cumulative count. Their length is
-        the rows the adapter chose (`_step_rows`), and every program here
-        takes the shape it is given. On one chip the
+        distinct, dist, weights, values or None, the window ages: a lane
+        on event time, else a scalar or None) of the stager's last slot,
+        `host` the host lanes (hh, distinct, dist, weights, ages or None)
+        they were put from, `drops` the source's cumulative count. Their
+        length is the rows the adapter chose (`_step_rows`), and every
+        program here takes the shape it is given. On one chip the
         update is dispatched first and the window planes and class
         sketches ride the same staged arrays; under shard-ingest the
         staged arrays live on the batch's lane chip while those planes
@@ -1660,8 +1761,8 @@ class TpuSketchInstance(OperatorInstance):
         fence. The stages are siblings: `ig:tpusketch_update` covers the
         step's dispatch only, so an idle gap under the window planes or
         the slices is named after them and not after the update."""
-        hh_d, distinct_d, dist_d, w_d, v_d = staged
-        hh, distinct, w = host
+        hh_d, distinct_d, dist_d, w_d, v_d, age_d = staged
+        hh, distinct, dist, w, age = host
         sharded = self._shard_on
         if self._hist_on:
             # a summary nearly due, or due in this turn: what can wait
@@ -1691,8 +1792,13 @@ class TpuSketchInstance(OperatorInstance):
                     jnp.float32(new_drops),
                     *((v_d,) if self._qt_on else ()))
             fence.append(tok)
-        fence += (self._window_planes(hh, distinct, w, slices) if sharded
-                  else self._window_planes(hh_d, distinct_d, w_d, slices))
+        fence += (self._window_planes(hh, distinct, dist, w,
+                                      self._age0 if age is None
+                                      else age,
+                                      slices)
+                  if sharded else
+                  self._window_planes(hh_d, distinct_d, dist_d, w_d, age_d,
+                                      slices))
         if self._inv_classes:
             # the staged keys are reused on one chip (only the
             # per-class WEIGHT vectors need a transfer). Under
@@ -1746,39 +1852,47 @@ class TpuSketchInstance(OperatorInstance):
         if now - self._last_harvest >= self.harvest_interval:
             self._last_harvest = now
             self.harvest()
-        if self._hist_on and self._hist_interval > 0 and \
-                self._hist_clock() - self._win_start >= self._hist_interval:
-            self._seal_at_boundary()
+        if self._hist_on and self._hist_interval > 0:
+            if self._event_time:
+                self._advance_watermark()
+            elif self._hist_clock() - self._win_start >= self._hist_interval:
+                self._seal_at_boundary()
 
-    def _window_planes(self, hh, distinct, w, slices) -> list:
-        """The history window's share of a batch: the WindowedCMS's
-        current slot and the per-window HLL absorb it, so a seal reads
+    def _window_planes(self, hh, distinct, dist, w, age, slices) -> list:
+        """The history window's share of a batch: each event into the
+        planes of its window (`window_planes_step`), so a seal reads
         window-only state, then the adapter's `slices`. Fed the staged
-        arrays on one chip; under shard-ingest the host lanes, each put
-        once on the default device. Returns the steps' fence tokens (on
-        CPU PJRT the restaged arrays may alias the pinned block)."""
+        arrays on one chip; under shard-ingest the host lanes, each
+        distinct one put once on the default device (the age too where it
+        is a lane). Returns the step's fence token (on CPU PJRT the
+        restaged arrays may alias the pinned block)."""
         if not self._hist_on:
             return []
         if self._shard_on:
             with self._st_restage:
-                hh_w = jnp.asarray(hh)
-                distinct = hh_w if distinct is hh else jnp.asarray(distinct)
-                hh, w = hh_w, jnp.asarray(w)
+                put = {}
+
+                def on_chip0(a):
+                    if id(a) not in put:
+                        put[id(a)] = jnp.asarray(a)
+                    return put[id(a)]
+                hh, distinct, dist, w = (on_chip0(a)
+                                         for a in (hh, distinct, dist, w))
+                if isinstance(age, np.ndarray):
+                    age = jnp.asarray(age)
         with self._st_planes:
-            toks = self._window_steps(hh, distinct, w)
+            tok = self._window_step(hh, distinct, dist, w, age)
         if slices is not None:
             with self._st_slices:
                 slices()
-        return toks
+        return [tok]
 
-    def _window_steps(self, hh, distinct, w) -> list:
-        """The two window-plane programs and their eager casts on device
-        arrays of one length (a batch's, or the zeros priming feeds)."""
-        self._wcms, wtok = _wcms_ingest_jit(
-            self._wcms, hh, w.astype(jnp.int32))
-        self._win_hll, htok = _hll_ingest_jit(
-            self._win_hll, distinct, w > 0)
-        return [wtok, htok]
+    def _window_step(self, hh, distinct, dist, w, age):
+        """The window program on device arrays of one length (a batch's,
+        or the zeros priming feeds); returns its fence token."""
+        self._wplanes, tok = _window_step_jit(
+            self._wplanes, hh, distinct, dist, w, age)
+        return tok
 
     def folded_block(self) -> np.ndarray:
         """A pinned (4+, pad) staging block for pop_folded (rows 0..2 are
@@ -1956,49 +2070,181 @@ class TpuSketchInstance(OperatorInstance):
 
     def _accumulate_slices(self, batch: EventBatch, n: int,
                            hh: np.ndarray, distinct: np.ndarray,
-                           dist: np.ndarray) -> None:
-        """Hydra-lite subpopulation accumulation for the open window:
+                           dist: np.ndarray, groups: list | None) -> None:
+        """Hydra-lite subpopulation accumulation for the open windows:
         per-mntns (container/pod identity), per-kind (syscall), and the
-        mntns×kind cross product. The batch is grouped once by its
-        (mntns, kind) cell and absorbed by the window's one array store
-        (history/window.py WindowSlices); the per-mntns and per-kind
-        slices are folds of the cells, taken at the seal. Bounded by
-        history-max-slices; overflow is dropped AND accounted in the
-        sealed window's header."""
-        self._win_slices.absorb(
-            batch.cols["mntns"][:n], batch.cols["kind"][:n], hh[:n],
-            distinct[:n],
-            # one column for both streams is one lane, hashed once
-            None if self.dist_col == self.distinct_col else dist[:n],
-            fold=not self._summary_near)
+        mntns×kind cross product. The events of each window (`groups`, from
+        `_route`; None: the whole batch, into the one open window) are
+        grouped once by their (mntns, kind) cell and absorbed by the
+        window's array store (history/window.py WindowSlices); the
+        per-mntns and per-kind slices are folds of the cells, taken at the
+        seal. Bounded by history-max-slices; overflow is dropped AND
+        accounted in the sealed window's header."""
+        cols = [batch.cols["mntns"][:n], batch.cols["kind"][:n], hh[:n],
+                distinct[:n],
+                # one column for both streams is one lane, hashed once
+                None if self.dist_col == self.distinct_col else dist[:n]]
+        for k, sel in groups or [(0, None)]:
+            store = self._win_slices.get(k)
+            if store is None:
+                store = self._win_slices[k] = self._slices_cls(
+                    self._hist_max_slices)
+            store.absorb(*(c if sel is None or c is None else c[sel]
+                           for c in cols), fold=not self._summary_near)
+
+    # event-time windows ------------------------------------------------------
+
+    def _make_room(self, oldest: int, newest: int) -> None:
+        """Before a batch whose events span [oldest, newest] (ns): where
+        its newest event's window would outnumber the planes beside the
+        open ones (a jump of the event clock), the oldest seal early."""
+        if self._ev_lo is None:             # the first batch: none late
+            self._ev_lo = oldest // self._win_ns
+            self._ev_hi = self._ev_lo - 1
+        else:                               # where the source was idle
+            self._advance_watermark(batch=False)
+        while newest // self._win_ns - self._ev_lo >= self._wplanes.n_open:
+            self._seal_oldest()
+
+    def _route(self, ts: np.ndarray, rows: int,
+               span: tuple[int, int] | None) -> tuple:
+        """The windows of a batch's events. On the processing clock every
+        event is in the open window: (None, None), a scalar age and
+        nothing to stage. On event time each event's window is that of its
+        own `ts` (`span`: the batch's oldest and newest, ns), against the
+        watermark as it stood before this batch (`_advance_watermark`
+        moves it after), so an event delayed by at most history-lateness
+        is never late; the windows the batch reaches are opened. Returns
+        the age lane of `rows` rows (0 = the newest open window; LATE_AGE
+        for an event older than the oldest open window, and for pad rows)
+        and the windows the events went to: [(window, their rows, or None
+        for all of them)]."""
+        if span is None:
+            return None, None
+        oldest, newest = span
+        width = self._win_ns
+        n = len(ts)
+        self._ev_batch_newest = newest
+        kmax = newest // width
+        self._ev_hi = max(self._ev_hi, self._ev_lo - 1)
+        while self._ev_hi < kmax:
+            self._wplanes = _window_open_jit(self._wplanes)
+            self._ev_hi += 1
+        lo, hi = self._ev_lo, self._ev_hi
+        self._m_open_windows.set(hi - lo + 1)
+        age = np.full(rows, LATE_AGE, np.uint8)
+        if oldest >= hi * width:            # all of it in the newest window
+            age[:n] = 0
+            self._win_events[hi] = self._win_events.get(hi, 0) + n
+            return age, [(hi, None)]
+        a = age[:n]
+        a[:] = 0
+        for j in range(1, hi - lo + 1):
+            a += ts < np.uint64((hi - j + 1) * width)
+        late = ts < np.uint64(lo * width)
+        a[late] = LATE_AGE
+        groups = []
+        for j in range(hi - lo + 1):
+            sel = np.flatnonzero(a == j)
+            if len(sel):
+                groups.append((hi - j, sel))
+                self._win_events[hi - j] = (
+                    self._win_events.get(hi - j, 0) + len(sel))
+        nlate = int(np.count_nonzero(late))
+        if nlate:
+            self._late_pending += nlate
+            self._late_total += nlate
+            self._m_late_events.inc(nlate)
+        return age, groups
+
+    def _watermark_ns(self) -> int:
+        """The newest event time absorbed less history-lateness. Where no
+        batch has come for a whole window, it moves on from there with the
+        clock by the idle time past that window, so an idle node's last
+        windows seal; a source that keeps sending is held to its own event
+        time, however far it runs behind the clock."""
+        idle = round((self._hist_clock() - self._ev_batch_at) * 1e9)
+        return (self._ev_newest_ns + max(idle - self._win_ns, 0)
+                - self._late_ns)
+
+    def _advance_watermark(self, batch: bool = True) -> None:
+        """The watermark moves, and every open window whose end it has
+        passed seals, oldest first: after a batch (by its newest event),
+        and before one and on an idle turn (by the idle time alone)."""
+        if batch:
+            self._ev_newest_ns = max(self._ev_newest_ns,
+                                     self._ev_batch_newest)
+            self._ev_batch_at = self._hist_clock()
+        wm = self._watermark_ns()
+        width = self._win_ns
+        while (self._ev_lo + 1) * width <= wm:
+            if self._ev_lo > self._ev_hi:   # none open: skip to the mark
+                self._ev_lo = max(self._ev_lo, wm // width)
+                self._ev_hi = self._ev_lo - 1
+                break
+            self._seal_oldest()
+        self._m_open_windows.set(max(self._ev_hi - self._ev_lo + 1, 0))
+
+    def idle(self) -> None:
+        """The gadget's loop found no event on this turn: on event time an
+        idle source's watermark follows the clock (`_watermark_ns`)."""
+        if (self.enabled and self._hist_on and self._event_time
+                and self._ev_lo is not None):
+            self._advance_watermark(batch=False)
+
+    def _seal_oldest(self) -> None:
+        """The served path's seal of the oldest open event-time window,
+        and the watermark's window moved past it."""
+        if self._ev_lo <= self._ev_hi:
+            self._seal_at_boundary(self._ev_lo)
+        self._ev_lo += 1
+
+    # sealing ---------------------------------------------------------------
 
     def seal_window(self) -> None:
-        """Seal the open window into the history store: ONE frame, ONE
-        O_APPEND write (a kill mid-seal tears at most this window, and
-        the torn tail is dropped-and-accounted on read). Empty windows
-        (no events since the last seal) are skipped — they carry no
-        state and would bloat the range index. Synchronous: whatever the
-        seal worker still holds is finished first, and the window is in
-        the store (and announced) when this returns."""
+        """Seal the open window into the history store (on event time
+        every open window, oldest first): ONE frame, ONE O_APPEND write
+        each (a kill mid-seal tears at most this window, and the torn tail
+        is dropped-and-accounted on read). Empty windows (no events since
+        the last seal, and no late events to carry) are skipped — they
+        carry no state and would bloat the range index. Synchronous: whatever the seal worker still holds
+        is finished first, and the windows are in the store (and
+        announced) when this returns."""
         with self._st_seal:
             self._seal_drain()
-            cap = self._capture_window()
-            if cap is not None:
-                self._finish_window(cap, "caller")
+            if not self._event_time:
+                windows = [None]
+            elif self._ev_lo is None:
+                windows = []
+            else:
+                windows = list(range(self._ev_lo, self._ev_hi + 1))
+                self._ev_lo = self._ev_hi + 1
+                self._m_open_windows.set(0)
+            for k in windows:
+                cap = (self._capture_window() if k is None
+                       else self._capture_window(k))
+                if cap is not None:
+                    self._finish_window(cap, "caller")
 
-    def _seal_at_boundary(self) -> None:
-        """The served path's interval-driven seal: the capture here, on
-        the loop thread, the finish on a worker thread. No queue: a
-        boundary that finds the worker still on the window before waits
-        for it (counted), so at most one window is pending and windows
-        reach the store in order. The stage times the wait and the
-        capture: the stall a seal puts on the loop."""
+    def _seal_at_boundary(self, window: int | None = None) -> None:
+        """The served path's seal (of the processing-time window, or of
+        event-time `window`): the capture here, on the loop thread, the
+        finish on a worker thread. No queue: a boundary that finds the
+        worker still on the window before waits for it (counted), so at
+        most one window is pending and windows reach the store in order.
+        The stage times the wait and the capture: the stall a seal puts on
+        the loop."""
         with self._st_seal:
             if self._seal_drain():
                 self._seal_stats["waited"] += 1
                 self._m_seal_waits.inc()
-            cap = self._capture_window()
+            cap = (self._capture_window() if window is None
+                   else self._capture_window(window))
             if cap is not None:
+                due = (cap.end_ts if self._event_time
+                       else cap.start_ts + self._hist_interval)
+                self._m_seal_delay.observe(
+                    max(self._hist_clock() - due, 0.0))
                 self._seal_thread = threading.Thread(
                     target=self._finish_on_worker, args=(cap,), daemon=True,
                     name=f"tpusketch-seal-{self.ctx.run_id}")
@@ -2019,36 +2265,50 @@ class TpuSketchInstance(OperatorInstance):
         self._seal_thread = None
         return busy
 
-    def _beside_wcms(self, cand):
-        """The snapshot's candidate keys where the window CMS lives: under
-        shard-ingest the merged bundle is replicated over the mesh and the
-        window planes stay on chip 0."""
+    def _beside_planes(self, cand):
+        """The snapshot's candidate keys where the window planes live:
+        under shard-ingest the merged bundle is replicated over the mesh
+        and the window planes stay on chip 0."""
         if not self._shard_on:
             return cand
-        return jax.device_put(cand, self._wcms.slots.sharding)
+        return jax.device_put(cand, self._wplanes.count.sharding)
 
-    def _capture_window(self) -> _WindowCapture | None:
+    def _capture_window(self, window: int | None = None
+                        ) -> _WindowCapture | None:
         """A seal's first half, on the thread that owns the live state:
-        everything that reads or swaps it, and nothing else. Two small
-        programs dispatched, no read-back, no sort, no file. None for an
-        empty window."""
-        from ..history import WindowSlices
-        end = self._hist_clock()
-        host_events = self._stats.events
-        if host_events == self._win_host_events and \
-                not len(self._win_slices):
+        everything that reads or swaps it, and nothing else. Small
+        programs dispatched, no read-back, no sort, no file. `window` is
+        the event-time window's index (None: the processing-time window,
+        which this capture closes and the next opens). None for an empty
+        window."""
+        if self._event_time:
+            start = window * self._win_ns / 1e9
+            end = (window + 1) * self._win_ns / 1e9
+            age, key = self._ev_hi - window, window
+            slices = self._win_slices.pop(key, None)
+            if not self._win_events.pop(key, 0) and not slices and \
+                    not self._late_pending:
+                return None
+        else:
+            start, end, age, key = self._win_start, self._hist_clock(), 0, 0
+            host_events = self._stats.events
+            slices = self._win_slices.pop(key, None)
+            if host_events == self._win_host_events and not slices:
+                self._win_start = end
+                return None
             self._win_start = end
-            return None
+            self._win_host_events = host_events
         with self._bundle_mu:
             # under shard-ingest the flush of the open round and the
             # collective harvest: they are the state
             snap = _seal_snapshot_jit(self._merged_locked())
-        # window-only state: the ring's CURRENT slot is this window's
-        # CMS; candidates re-estimated against it give the window top-k.
-        # The same program opens the next window's planes
-        hll = self._win_hll.registers
-        cms, counts, self._wcms, self._win_hll = _wcms_window_jit(
-            self._wcms, self._beside_wcms(snap[3]), self._win_hll)
+        # window-only state: the window's ring slot is its CMS;
+        # candidates re-estimated against it give the window top-k
+        cand = self._beside_planes(snap[1])
+        if self._event_time:
+            planes = _window_capture_jit(self._wplanes, cand, np.int32(age))
+        else:
+            planes, self._wplanes = _window_close_jit(self._wplanes, cand)
         self._win_n += 1
         # accuracy audit plane: the WINDOW-scoped shadow sample rides the
         # sealed window (copies: the live sample resets here)
@@ -2058,24 +2318,22 @@ class TpuSketchInstance(OperatorInstance):
                       self._win_shadow.weights.copy(),
                       int(self._win_shadow.capacity))
             self._win_shadow.reset()
-        cap = _WindowCapture(
-            window=self._win_n, start_ts=float(self._win_start),
-            end_ts=float(end), snap=snap, cms=cms, counts=counts,
-            hll=hll, slices=self._win_slices,
+        late, self._late_pending = self._late_pending, 0
+        return _WindowCapture(
+            window=self._win_n, start_ts=float(start), end_ts=float(end),
+            snap=snap, cms=planes[0], counts=planes[1], hll=planes[2],
+            ent=planes[3], events=planes[4], late=late,
+            slices=slices or self._slices_cls(self._hist_max_slices),
             shadow=shadow,
             # the names a sealed window carries are resolved from the
             # sample ring and the cache as this boundary found them
             labels=self._labels_as_found(), names=dict(self._names))
-        self._win_slices = WindowSlices(self._hist_max_slices)
-        self._win_start = end
-        self._win_host_events = host_events
-        return cap
 
     @staticmethod
     def _snapshot_host(snap: tuple) -> tuple:
         """A `_seal_snapshot` as the host values window deltas are taken
         of: one blocking read of its arrays."""
-        events, drops, ent, cand, overflow, inv, qt = jax.device_get(snap)
+        drops, cand, overflow, inv, qt = jax.device_get(snap)
         if inv is not None:
             inv = (inv[0].astype(np.int64), inv[1], inv[2])
         if qt is not None:
@@ -2083,8 +2341,7 @@ class TpuSketchInstance(OperatorInstance):
         # the candidate-overflow latch rides along: an overflowed run's
         # windows carry approx=True, so merged and historical answers stay
         # tainted across the seal boundary
-        return (float(events), float(drops), ent, cand, bool(int(overflow)),
-                inv, qt)
+        return float(drops), cand, bool(int(overflow)), inv, qt
 
     def _finish_on_worker(self, cap: _WindowCapture) -> None:
         """The seal worker's thread: one window, then it ends. It opens no
@@ -2126,11 +2383,12 @@ class TpuSketchInstance(OperatorInstance):
         self._seal_step(finish)
         now = self._snapshot_host(cap.snap)
         base, self._win_base = self._win_base, now
-        events0, drops0, ent0, _cand0, _overflow0, inv0, qt0 = base
-        events, drops, ent_now, cand, overflow, inv_now, qt_now = now
-        win_events = int(events - events0)
-        cms = np.asarray(cap.cms)
-        counts = np.asarray(cap.counts).astype(np.int64)
+        drops0, _cand0, _overflow0, inv0, qt0 = base
+        drops, cand, overflow, inv_now, qt_now = now
+        cms, counts, hll, ent, win_events = jax.device_get(
+            (cap.cms, cap.counts, cap.hll, cap.ent, cap.events))
+        win_events = int(win_events)
+        counts = counts.astype(np.int64)
         order = np.argsort(-counts)
         keep = [(int(cand[i]), int(counts[i])) for i in order
                 if cand[i] != 0 and counts[i] > 0]
@@ -2175,14 +2433,15 @@ class TpuSketchInstance(OperatorInstance):
             events=win_events,
             drops=int(drops - drops0),
             cms=cms.astype(np.int32),
-            hll=np.asarray(cap.hll).astype(np.int32),
-            ent=(ent_now - ent0).astype(np.float32),
+            hll=hll.astype(np.int32),
+            ent=ent.astype(np.float32),
             topk_keys=np.array([k for k, _ in keep], dtype=np.uint32),
             topk_counts=np.array([c for _, c in keep], dtype=np.int64),
             slices=slices,
             names={k: names[k] for k, _ in keep if k in names},
             slices_dropped=cap.slices.dropped,
             approx=overflow,
+            late=cap.late,
             **inv_kw,
         )
         # what this finish resolved goes back to the cache the harvests
@@ -2213,7 +2472,7 @@ class TpuSketchInstance(OperatorInstance):
                     hook({"gadget": win.gadget, "window": win.window,
                           "start_ts": win.start_ts, "end_ts": win.end_ts,
                           "events": win.events, "drops": win.drops,
-                          "digest": win.digest})
+                          "late": win.late, "digest": win.digest})
                 except Exception as he:  # noqa: BLE001 — announce only
                     _ckpt_log.warning("window announce failed: %r", he)
             # standing queries fold the window ONLY after a successful
@@ -2432,6 +2691,16 @@ class TpuSketchInstance(OperatorInstance):
             pipe_out["seal"] = {
                 **self._seal_stats,
                 "pending": int(worker is not None and worker.is_alive())}
+            # the windows open now, how far the watermark trails the clock
+            # (0 on the processing clock) and the late events so far
+            lag = 0.0
+            if self._event_time and self._ev_lo is not None:
+                lag = self._hist_clock() - self._watermark_ns() / 1e9
+            pipe_out["windows"] = {
+                "open": (max(self._ev_hi - self._ev_lo + 1, 0)
+                         if self._event_time and self._ev_lo is not None
+                         else int(not self._event_time)),
+                "watermark_lag_s": lag, "late": self._late_total}
         if self._anomaly_step is not None:
             pipe_out["anomaly"] = {"steps": self._anomaly_steps,
                                    "containers": len(self._containers),

@@ -38,14 +38,18 @@ def _alpha(m: int) -> float:
     return 0.7213 / (1 + 1.079 / m)
 
 
-def hll_update(state: HLL, keys: jnp.ndarray, mask: jnp.ndarray | None = None) -> HLL:
+def hll_index_rank(keys: jnp.ndarray, p: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Each key's register (the hash's top p bits) and rank (leading zeros
+    of the remaining 32-p bits, +1)."""
     h = fmix32(keys.astype(jnp.uint32))
-    p = state.p
     idx = (h >> (32 - p)).astype(jnp.int32)
-    # rank = leading zeros of the remaining (32-p) bits, +1
     rest = (h << p) | jnp.uint32((1 << p) - 1)  # pad low bits so clz ≤ 32-p
     rank = jnp.clip(jax.lax.clz(rest.astype(jnp.int32)), 0, 32 - p) + 1
-    rank = rank.astype(jnp.int32)
+    return idx, rank.astype(jnp.int32)
+
+
+def hll_update(state: HLL, keys: jnp.ndarray, mask: jnp.ndarray | None = None) -> HLL:
+    idx, rank = hll_index_rank(keys, state.p)
     if mask is not None:
         rank = jnp.where(mask, rank, 0)
     return state.replace(registers=state.registers.at[idx].max(rank))

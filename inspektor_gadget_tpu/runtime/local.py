@@ -20,6 +20,7 @@ from ..gadgets.context import GadgetContext
 from ..gadgets.interface import (
     EventHandlerArraySetter,
     BatchHandlerSetter,
+    IdleHandlerSetter,
     EventHandlerSetter,
     RunWithResult,
 )
@@ -102,6 +103,9 @@ class LocalRuntime(Runtime):
                     with st_deliver:
                         on_batch(batch)
             gadget.set_batch_handler(handle_batch)
+
+        if isinstance(gadget, IdleHandlerSetter):
+            gadget.set_idle_handler(instances.idle)
 
         instances.pre_gadget_run()
         # the timeout is the gadget's run: what the operators do ahead of

@@ -119,7 +119,8 @@ def _load_and_bind():
                           ctypes.c_double)
     p64 = ctypes.POINTER(ctypes.c_uint64)
     p32 = ctypes.POINTER(ctypes.c_uint32)
-    lib.ig_source_create.argtypes = [u32, u64, f64, u32, f64, u32, u32]
+    lib.ig_source_create.argtypes = [u32, u64, f64, u32, f64, u32, u32,
+                                     f64, u64]
     lib.ig_source_create.restype = u64
     lib.ig_source_create_cfg.argtypes = [u32, ctypes.c_char_p, u32]
     lib.ig_source_create_cfg.restype = u64
@@ -321,7 +322,8 @@ class NativeCapture:
     def __init__(self, kind: int, *, seed: int = 0, rate: float = 0.0,
                  vocab: int = 1000, zipf_s: float = 1.2, ring_pow2: int = 20,
                  batch_size: int = 8192, cfg: str = "",
-                 containers: int = 64):
+                 containers: int = 64, prob_delayed_event: float = 0.0,
+                 occasional_delay: float = 0.0):
         lib = _load()
         if lib is None:
             raise RuntimeError(f"native capture unavailable: {_lib_err}")
@@ -330,8 +332,9 @@ class NativeCapture:
             self._h = lib.ig_source_create_cfg(
                 kind, cfg.encode("utf-8", "replace"), ring_pow2)
         else:
-            self._h = lib.ig_source_create(kind, seed, rate, vocab, zipf_s,
-                                           ring_pow2, containers)
+            self._h = lib.ig_source_create(
+                kind, seed, rate, vocab, zipf_s, ring_pow2, containers,
+                prob_delayed_event, round(occasional_delay * 1e9))
         if self._h == 0:
             raise ValueError(f"unknown source kind {kind}")
         self.batch_size = batch_size

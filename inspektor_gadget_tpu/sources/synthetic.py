@@ -18,9 +18,12 @@ from .batch import EventBatch
 class PySyntheticSource:
     def __init__(self, kind: int = 1, *, seed: int = 0, vocab: int = 1000,
                  zipf_s: float = 1.2, batch_size: int = 8192,
-                 containers: int = 64):
+                 containers: int = 64, prob_delayed_event: float = 0.0,
+                 occasional_delay: float = 0.0):
         self.kind = kind
         self._containers = containers
+        self._max_delay_ns = round(occasional_delay * 1e9)
+        self._prob_delayed = prob_delayed_event if self._max_delay_ns else 0.0
         self.batch_size = batch_size
         self._rng = np.random.default_rng(seed or 42)
         self._names = [f"proc-{i}" for i in range(vocab)]
@@ -53,6 +56,13 @@ class PySyntheticSource:
         b.cols["kind"][:] = self.kind
         b.cols["aux1"][:] = self._rng.integers(0, 2**63, n, dtype=np.uint64)
         b.cols["aux2"][:] = self._rng.integers(0, 2**16, n, dtype=np.uint64)
+        if self._prob_delayed > 0.0:
+            # the native source's delayed events: with probability p an
+            # event time older by d, d uniform on (0, occasional-delay]
+            late = self._rng.random(n) < self._prob_delayed
+            d = self._rng.integers(1, self._max_delay_ns + 1, n,
+                                   dtype=np.uint64)
+            b.cols["ts"][late] -= d[late]
         b.count = n
         b.seq = self._seq
         self._seq += n

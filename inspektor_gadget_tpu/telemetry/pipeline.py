@@ -81,8 +81,9 @@ _tm_occupancy = gauge(
 # annotation `ig:<name>` on the profiler's clock (siblings, never nested)
 TURN_STAGES = (
     "source_wait", "source_pop", "source_filter", "gadget_record",
-    "operator_other", "tpusketch_fold", "tpusketch_h2d",
-    "tpusketch_shard_restage", "tpusketch_update", "tpusketch_window_planes",
+    "operator_other", "tpusketch_fold", "tpusketch_window_route",
+    "tpusketch_h2d", "tpusketch_shard_restage", "tpusketch_update",
+    "tpusketch_window_planes",
     "tpusketch_slices", "tpusketch_inv_classes", "tpusketch_post",
     "tpusketch_container_dists", "tpusketch_seal", "tpusketch_shard_merge",
     "tpusketch_harvest", "runtime_deliver")

@@ -48,7 +48,7 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
         phases[json.loads(line)["phase"]] = json.loads(line)
     assert list(phases) == ["acquire", "step-times", "local-runtime",
                             "invertible", "quantiles", "narrow", "anomaly",
-                            "anomaly-dense", "agent", "done"]
+                            "anomaly-dense", "eventtime", "agent", "done"]
     # truthful about what ran: no kernel on the CPU, and it says so, at
     # the run's geometry and at the one where a TPU takes the kernel
     for times in (phases["step-times"], phases["step-times"]["narrow"]):
@@ -82,6 +82,13 @@ def test_cpu_rehearsal_passes_and_last_line_parses():
         1024, 1024, 1024)
     assert dense["slices_exact"] and dense["slices_dropped"] == 0
     assert dense["windows"] >= 2
+    # event-time windows against the plain reference of every event, and
+    # a window sealed early caught by it
+    eventtime = phases["eventtime"]
+    assert eventtime["windows"] >= 2 and eventtime["width_s"] == 1.0
+    assert eventtime["compared"]["late"] == 0
+    assert eventtime["compared"]["events_gap"] == 0
+    assert eventtime["early_fault"]["late"] > 0
     agent = phases["agent"]
     assert agent["checkpoints"] >= 2 and agent["checkpoint_failures"] == 0
     assert agent["generator"] == "native C++ synthetic"

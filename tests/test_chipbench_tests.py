@@ -38,6 +38,15 @@ LEFT_OUT = {
     # the front; a `benchmark` issue restates the two (PERF.md section 7).
     "test_the_cell_and_its_configuration_are_the_files",
     "test_the_metric_lists_are_what_the_files_give",
+    # chipbench/tests/test_loop_metrics.py holds the five loop metrics'
+    # `workloads` to the six cells they were accepted with, while
+    # test_benchmark_json_names_files_that_exist holds every list to the
+    # cells that read it: every cell reads the loop metrics, so a seventh
+    # (`exec-eventtime.saturate`) is appended to their lists and the
+    # pinned six fail by design; test_eventtime_cell.py holds the lists to
+    # the data, places counted from the front, and the metrics' files to
+    # no `when` (PERF.md section 7)
+    "test_the_six_are_appended_behind_the_accepted_entries",
 }
 
 for _path in sorted((Path(__file__).resolve().parents[1]

@@ -473,7 +473,7 @@ def _drive_entry(entry: str, params: dict, stream, tmp_path) -> dict:
             inst.enrich_batch(b)
         if hist and i in (4, len(stream) - 1):
             if i == 4:
-                got["window_planes"] = _leaves((inst._wcms, inst._win_hll))
+                got["window_planes"] = _leaves(inst._wplanes)
             inst.seal_window()
     with inst._bundle_mu:
         got["bundle"] = _leaves(inst._merged_locked())

@@ -124,6 +124,128 @@ def test_py_synthetic_parity():
     assert b.mask().sum() == 5000
 
 
+# -- NEXmark's delayed events (prob-delayed-event, occasional-delay) ----------
+
+DELAY_NS = 3_000_000_000
+
+
+def popped(n: int, **kw) -> dict[str, np.ndarray]:
+    """The first `n` events the producer thread emits, every column."""
+    src = NativeCapture(SRC_SYNTH_EXEC, batch_size=65536, **kw)
+    src.start()
+    got: dict[str, list] = {}
+    count, deadline = 0, time.monotonic() + 30
+    while count < n and time.monotonic() < deadline:
+        b = src.pop()
+        if not b.count:
+            time.sleep(0.005)
+            continue
+        for c, a in b.cols.items():
+            got.setdefault(c, []).append(a[:b.count].copy())
+        count += b.count
+    src.stop()
+    assert src.drops() == 0 and count >= n
+    src.close()
+    return {c: np.concatenate(a)[:n] for c, a in got.items()}
+
+
+def delays_of(ts: np.ndarray) -> np.ndarray:
+    """Each event's age behind the newest emission before it: its delay,
+    less the microseconds between two emissions."""
+    ts = ts.astype(np.int64)
+    return np.maximum.accumulate(ts) - ts
+
+
+@needs_native
+def test_the_delayed_share_and_the_largest_delay():
+    n, p = 200_000, 0.1
+    d = delays_of(popped(n, seed=2**31 + 77, rate=400_000, vocab=500,
+                         prob_delayed_event=p, occasional_delay=3.0)["ts"])
+    # delays under 2 ms (2/3000 of them) hide among the emission gaps
+    share = np.count_nonzero(d > 2_000_000) / n
+    sigma = (p * (1 - p) / n) ** 0.5
+    assert abs(share - p * (1 - 2e-3 / 3)) < 5 * sigma
+    assert d.max() <= DELAY_NS
+    # uniform on (0, 3 s]: each third holds a third of the delayed
+    thirds = np.histogram(d[d > 2_000_000], bins=3,
+                          range=(0, DELAY_NS))[0] / np.count_nonzero(
+                              d > 2_000_000)
+    assert np.all(np.abs(thirds - 1 / 3) < 0.02)
+
+
+@needs_native
+def test_delays_are_the_seeds():
+    kw = dict(seed=91, rate=400_000, vocab=500, prob_delayed_event=0.1,
+              occasional_delay=3.0)
+    a, b = popped(50_000, **kw), popped(50_000, **kw)
+    np.testing.assert_array_equal(a["key_hash"], b["key_hash"])
+    da, db = delays_of(a["ts"]), delays_of(b["ts"])
+    late_a, late_b = da > 2_000_000, db > 2_000_000
+    # an emission gap of a busy host shifts an estimate across 2 ms now
+    # and then: a few of 5,000
+    assert np.count_nonzero(late_a ^ late_b) <= 20
+    both = late_a & late_b
+    assert np.count_nonzero(both) > 4000
+    # the same draws, emitted at another wall time: equal but for the
+    # emission gaps (microseconds; milliseconds where the producer thread
+    # waited for a core)
+    gap = np.abs(da[both] - db[both])
+    assert np.quantile(gap, 0.99) < 1_000_000 and gap.max() < 50_000_000
+
+
+# sha256 of the first 4,096 events' columns, recorded from the source before
+# it had either parameter (commit 52bad71, tests/test_dense_node_served.py):
+# through `generate` (key_hash, mntns, pid, uid) and through the producer
+# thread and `pop` (every column but the clock's)
+GENERATED = "dc6f11d0fc18037eaaed5208d74e50735f0b2eefffaa30f2cd708b45391807f5"
+POPPED = "41668eb7b18766cae91efb82f01664653230741ad5e5c4bf1b464c45ef927c68"
+
+
+@needs_native
+@pytest.mark.parametrize("delays", [
+    {}, {"prob_delayed_event": 0.0, "occasional_delay": 3.0},
+    {"prob_delayed_event": 0.1, "occasional_delay": 0.0}])
+def test_without_delays_the_stream_is_the_recorded_one(delays):
+    import hashlib
+
+    def digest(cols, names):
+        h = hashlib.sha256()
+        for c in names:
+            h.update(np.ascontiguousarray(cols[c][:4096]).tobytes())
+        return h.hexdigest()
+
+    kw = dict(seed=7, vocab=21440, zipf_s=1.2, **delays)
+    src = NativeCapture(SRC_SYNTH_EXEC, batch_size=4096, **kw)
+    assert digest(src.generate(4096).cols,
+                  ("key_hash", "mntns", "pid", "uid")) == GENERATED
+    src.close()
+    cols = popped(4096, rate=40_000, **kw)
+    assert digest(cols, ("key_hash", "mntns", "pid", "ppid", "uid", "kind",
+                         "aux1", "aux2")) == POPPED
+    # and every event's time is its emission's
+    assert delays_of(cols["ts"]).max() < 2_000_000
+
+
+def test_the_numpy_source_delays_alike():
+    kw = dict(seed=5, vocab=500, prob_delayed_event=0.1,
+              occasional_delay=3.0)
+    a = PySyntheticSource(**kw).generate(100_000)
+    d = delays_of(a.cols["ts"])
+    share = np.count_nonzero(d) / 100_000
+    assert abs(share - 0.1) < 5 * (0.1 * 0.9 / 100_000) ** 0.5
+    assert 0 < d.max() <= DELAY_NS
+    b = PySyntheticSource(**kw).generate(100_000)
+    np.testing.assert_array_equal(a.cols["key_hash"], b.cols["key_hash"])
+    np.testing.assert_array_equal(d > 0, delays_of(b.cols["ts"]) > 0)
+    # at the defaults: the stream it always was, the clock's column aside
+    plain = PySyntheticSource(seed=5, vocab=500).generate(4096)
+    off = PySyntheticSource(seed=5, vocab=500, prob_delayed_event=0.0,
+                            occasional_delay=3.0).generate(4096)
+    for c in ("key_hash", "mntns", "pid", "uid", "aux1", "aux2"):
+        np.testing.assert_array_equal(plain.cols[c], off.cols[c])
+    assert delays_of(plain.cols["ts"]).max() == 0
+
+
 def test_batch_mask_and_comm():
     from inspektor_gadget_tpu.sources import EventBatch
 

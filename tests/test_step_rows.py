@@ -109,11 +109,11 @@ def leaves(tree) -> list:
 
 
 def state_of(inst) -> dict:
-    """Every leaf the dispatch writes: the bundle, the window CMS and
-    HLL, the class sketches."""
+    """Every leaf the dispatch writes: the bundle, the window planes, the
+    class sketches."""
     with inst._bundle_mu:
         return {"bundle": leaves(inst._merged_locked()),
-                "window": leaves((inst._wcms, inst._win_hll)),
+                "window": leaves(inst._wplanes),
                 "classes": leaves([s for _, s in inst._inv_classes])}
 
 

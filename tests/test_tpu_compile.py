@@ -154,27 +154,48 @@ def test_bundle_digest_compiles(one_chip):
 
 
 def test_seal_window_read_compiles_as_one_program(one_chip):
-    """What a seal's capture dispatches on the window planes (the current
-    slot and the candidates' estimates for its finish, the ring advanced
-    and a fresh HLL for the next window): one program, at the history
-    plane's defaults; and the snapshot of the bundle beside it."""
-    from inspektor_gadget_tpu.operators.tpusketch import (_seal_snapshot,
-                                                          _wcms_window_step)
-    from inspektor_gadget_tpu.ops.hll import hll_init
-    from inspektor_gadget_tpu.ops.window import wcms_init
-    wcms = _on(one_chip, jax.eval_shape(
-        lambda: wcms_init(n_slots=8, depth=4, log2_width=12)))
-    hll = _on(one_chip, jax.eval_shape(lambda: hll_init(GEOMETRY["hll_p"])))
+    """What a seal's capture dispatches on the window planes (the window's
+    ring slot and the candidates' estimates for its finish, its HLL,
+    entropy and count planes): one program, at the history plane's
+    defaults, and the opening of the next window another (on the
+    processing clock both are one program); and the snapshot of the
+    bundle beside them."""
+    from inspektor_gadget_tpu.operators.tpusketch import _seal_snapshot
+    from inspektor_gadget_tpu.ops.window import (window_capture, window_close,
+                                                 window_open,
+                                                 window_planes_init)
+    planes = _on(one_chip, jax.eval_shape(lambda: window_planes_init(
+        3, 8, 4, 12, GEOMETRY["hll_p"], GEOMETRY["entropy_log2_width"])))
     cand = jax.ShapeDtypeStruct((GEOMETRY["k"],), jnp.uint32,
                                 sharding=one_chip)
-    table, counts, ring, fresh = jax.eval_shape(
-        _wcms_window_step, wcms, cand, hll)
+    age = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    table, counts, hll, ent, count = jax.eval_shape(
+        window_capture, planes, cand, age)
     assert table.shape == (4, 1 << 12) and counts.shape == (GEOMETRY["k"],)
-    assert ring.slots.shape == wcms.slots.shape
-    assert fresh.registers.shape == hll.registers.shape
-    jax.jit(_wcms_window_step).lower(wcms, cand, hll).compile()
+    assert hll.shape == (1 << GEOMETRY["hll_p"],) and count.shape == ()
+    assert ent.shape == (1 << GEOMETRY["entropy_log2_width"],)
+    jax.jit(window_capture).lower(planes, cand, age).compile()
+    jax.jit(window_open).lower(planes).compile()
+    jax.jit(window_close).lower(planes, cand).compile()
     bundle = _on(one_chip, jax.eval_shape(lambda: bundle_init(**GEOMETRY)))
     jax.jit(_seal_snapshot).lower(bundle).compile()
+
+
+@pytest.mark.parametrize("age", ["scalar", "lane"])
+def test_the_window_program_compiles_at_the_pad(one_chip, age):
+    """The window planes' one step a batch at the smoke's batch: a scalar
+    age on the processing clock, a uint8 age lane on event time (three
+    planes: 3 s of lateness over 10 s windows)."""
+    from inspektor_gadget_tpu.ops.window import (window_planes_init,
+                                                 window_planes_step)
+    planes = _on(one_chip, jax.eval_shape(lambda: window_planes_init(
+        3 if age == "lane" else 1, 8, 4, 12, GEOMETRY["hll_p"],
+        GEOMETRY["entropy_log2_width"])))
+    ages = (_lane(one_chip, jnp.uint8) if age == "lane"
+            else jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    lane = _lane(one_chip)
+    jax.jit(window_planes_step, donate_argnums=0).lower(
+        planes, lane, lane, lane, lane, ages).compile()
 
 
 @pytest.mark.parametrize("slots", [64, 1024])
