@@ -36,6 +36,10 @@ _tm_filtered = counter("ig_source_events_filtered_total",
                        "events removed by kind/mntns filters", ("gadget",))
 _tm_dropped = counter("ig_source_events_dropped_total",
                       "upstream capture-ring drops", ("gadget",))
+_tm_forgiven = counter("ig_source_forgiven_events_total",
+                       "events the synthetic producer owed by its schedule "
+                       "and skipped (a catch-up past 10 ms of events)",
+                       ("gadget",))
 _tm_queue = gauge("ig_source_queue_events",
                   "events in the last pop (pinned at batch-size under "
                   "backlog)", ("gadget",))
@@ -332,6 +336,7 @@ class SourceTraceGadget:
         self._m_events = _tm_events.labels(gadget=g)
         self._m_filtered = _tm_filtered.labels(gadget=g)
         self._m_dropped = _tm_dropped.labels(gadget=g)
+        self._m_forgiven = _tm_forgiven.labels(gadget=g)
         self._m_queue = _tm_queue.labels(gadget=g)
         self._m_rows = _tm_rows.labels(gadget=g)
 
@@ -546,6 +551,11 @@ class SourceTraceGadget:
                         if batch.drops > prev_drops:
                             self._m_dropped.inc(batch.drops - prev_drops)
                             src._tm_drops_seen = batch.drops
+                        prev_forgiven = getattr(src, "_tm_forgiven_seen", 0)
+                        if batch.forgiven > prev_forgiven:
+                            self._m_forgiven.inc(batch.forgiven
+                                                 - prev_forgiven)
+                            src._tm_forgiven_seen = batch.forgiven
                         self._apply_kind_filter(batch)
                         self._apply_filter(batch)
                         if batch.count != popped:

@@ -208,7 +208,8 @@ uint64_t ig_source_create_cfg(uint32_t kind, const char* cfg,
 // counters). Any output pointer may be null. Returns entries written.
 int64_t ig_sources_stats(uint64_t* ids, uint32_t* kinds, uint64_t* produced,
                          uint64_t* consumed, uint64_t* drops,
-                         uint64_t* filtered, uint64_t* ring_len,
+                         uint64_t* forgiven, uint64_t* filtered,
+                         uint64_t* ring_len,
                          uint64_t* ring_cap, uint64_t* cpu_ns, int64_t cap) {
   if (cap <= 0) return -1;
   std::lock_guard<std::mutex> g(g_mu);  // also blocks concurrent destroy
@@ -224,6 +225,7 @@ int64_t ig_sources_stats(uint64_t* ids, uint32_t* kinds, uint64_t* produced,
     // the reads
     if (consumed) consumed[n] = s->consumed();
     if (drops) drops[n] = s->drops();
+    if (forgiven) forgiven[n] = s->forgiven();
     if (filtered) filtered[n] = s->filtered();
     if (ring_len) ring_len[n] = s->ring_len();
     if (ring_cap) ring_cap[n] = s->ring_capacity();
@@ -455,6 +457,11 @@ int64_t ig_source_pop_folded2(uint64_t h, int64_t n, uint32_t* keys,
 uint64_t ig_source_drops(uint64_t h) {
   Source* s = lookup(h);
   return s ? s->drops() : 0;
+}
+
+uint64_t ig_source_forgiven(uint64_t h) {
+  Source* s = lookup(h);
+  return s ? s->forgiven() : 0;
 }
 
 uint64_t ig_source_produced(uint64_t h) {

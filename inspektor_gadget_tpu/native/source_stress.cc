@@ -67,11 +67,12 @@ int main() {
     });
     // stats thread (top/self enumeration)
     ts.emplace_back([&stop] {
-      uint64_t ids[64], prod[64], cons[64], drops[64], filt[64], rl[64],
-          rc[64], cpu[64];
+      uint64_t ids[64], prod[64], cons[64], drops[64], forg[64], filt[64],
+          rl[64], rc[64], cpu[64];
       uint32_t kk[64];
       while (!stop.load())
-        ig_sources_stats(ids, kk, prod, cons, drops, filt, rl, rc, cpu, 64);
+        ig_sources_stats(ids, kk, prod, cons, drops, forg, filt, rl, rc, cpu,
+                         64);
     });
     std::this_thread::sleep_for(std::chrono::milliseconds(1200));
     stop.store(true);

@@ -19,7 +19,9 @@
 #include <sys/stat.h>
 #include <unistd.h>
 
+#include <atomic>
 #include <cerrno>
+#include <chrono>
 #include <cmath>
 #include <cstdio>
 #include <cstdlib>
@@ -128,6 +130,9 @@ class Source {
 
   size_t pop(Event* out, size_t n) { return ring_.pop(out, n); }
   uint64_t drops() const { return ring_.drops(); }
+  // events a paced producer owed by its schedule and never emitted (a
+  // catch-up past its cap); 0 for every source that keeps no schedule
+  virtual uint64_t forgiven() const { return 0; }
   uint64_t produced() const { return ring_.produced(); }
   uint64_t filtered() const {
     return filtered_.load(std::memory_order_relaxed);
@@ -322,17 +327,35 @@ class SyntheticSource : public Source {
     return n;
   }
 
+  uint64_t forgiven() const override {
+    return forgiven_.load(std::memory_order_relaxed);
+  }
+
  protected:
+  // Paced producer on a schedule: by `now` it owes floor(rate x (now - t0))
+  // events, less those already emitted or forgiven. A wake emits what is
+  // owed, at most 10 ms of events (a stall is caught up by that much, never
+  // by seconds of backlog), counts the rest as forgiven, and sleeps until
+  // the next 1 ms tick of the schedule. A producer that keeps up delivers
+  // the rate whatever its emit time and oversleep; one that cannot reach
+  // the rate runs flat out.
   void run() override {
-    // Paced producer: emit in 1ms chunks at the requested rate.
-    const double per_ms = rate_ / 1000.0;
-    double carry = 0;
+    using clock = std::chrono::steady_clock;
+    const auto t0 = clock::now();
+    const double rate = rate_ > 0.0 ? rate_ : 0.0;
+    const uint64_t cap = (uint64_t)std::ceil(rate / 100.0);
+    uint64_t settled = 0;  // emitted + forgiven
     while (running_.load(std::memory_order_relaxed)) {
-      carry += per_ms;
-      size_t n = (size_t)carry;
-      carry -= (double)n;
-      for (size_t i = 0; i < n; i++) emit(make_event());
-      std::this_thread::sleep_for(std::chrono::milliseconds(1));
+      const std::chrono::duration<double> since = clock::now() - t0;
+      const uint64_t due = (uint64_t)(rate * since.count());
+      const uint64_t owed = due > settled ? due - settled : 0;
+      const uint64_t n = owed < cap ? owed : cap;
+      if (owed > n) forgiven_.fetch_add(owed - n, std::memory_order_relaxed);
+      settled += owed;
+      for (uint64_t i = 0; i < n; i++) emit(make_event());
+      const auto tick = std::chrono::duration_cast<std::chrono::milliseconds>(
+                            clock::now() - t0) + std::chrono::milliseconds(1);
+      std::this_thread::sleep_until(t0 + tick);
     }
   }
 
@@ -388,6 +411,7 @@ class SyntheticSource : public Source {
   uint32_t containers_;
   double prob_delayed_;
   uint64_t max_delay_ns_;
+  std::atomic<uint64_t> forgiven_{0};
   std::vector<double> alias_prob_;
   std::vector<uint32_t> alias_idx_;
   std::vector<std::string> names_;

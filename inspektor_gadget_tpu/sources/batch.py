@@ -33,6 +33,7 @@ class EventBatch:
     count: int                 # valid rows (rest is padding)
     seq: int = 0               # first event's sequence number
     drops: int = 0             # cumulative upstream drops at pop time
+    forgiven: int = 0          # cumulative events the producer skipped
     comm: np.ndarray | None = None  # (capacity, 8) uint8 display prefixes
     # pipeline-health watermarks (epoch seconds; 0.0 = unstamped): one
     # stamp per BATCH, never per event — host lag = pop_ts − oldest_ts,

@@ -158,6 +158,8 @@ def _load_and_bind():
     lib.ig_source_pop_folded2.restype = i64
     lib.ig_source_drops.argtypes = [u64]
     lib.ig_source_drops.restype = u64
+    lib.ig_source_forgiven.argtypes = [u64]
+    lib.ig_source_forgiven.restype = u64
     lib.ig_source_produced.argtypes = [u64]
     lib.ig_source_produced.restype = u64
     lib.ig_synth_generate.argtypes = [u64, i64, p64, p64, p32, p32]
@@ -170,7 +172,7 @@ def _load_and_bind():
         u64, p64, i64, ctypes.c_char_p, i64,
         ctypes.POINTER(ctypes.c_int32)]
     lib.ig_vocab_lookup_batch.restype = i64
-    lib.ig_sources_stats.argtypes = [p64, p32] + [p64] * 7 + [i64]
+    lib.ig_sources_stats.argtypes = [p64, p32] + [p64] * 8 + [i64]
     lib.ig_sources_stats.restype = i64
     lib.ig_fanotify_supported.argtypes = []
     lib.ig_fanotify_supported.restype = ctypes.c_int
@@ -278,18 +280,20 @@ def sources_stats(cap: int = 256) -> list[dict]:
     """Enumerate every live native capture source with self-stats (the
     top/ebpf contract: reference pkg/gadgets/top/ebpf/tracer.go:55-418 —
     per-program runtime + counters; here per-source capture-thread CPU
-    time, ring occupancy/capacity, produced/consumed/drops/filtered)."""
+    time, ring occupancy/capacity, produced/consumed/drops/filtered, and
+    the events a paced producer forgave behind its schedule)."""
     lib = _load()
     if lib is None:
         return []
     ids = np.zeros(cap, np.uint64)
     kinds = np.zeros(cap, np.uint32)
-    cols = [np.zeros(cap, np.uint64) for _ in range(7)]
+    cols = [np.zeros(cap, np.uint64) for _ in range(8)]
     n = lib.ig_sources_stats(
         _p64(ids), _p32(kinds), *[_p64(c) for c in cols], cap)
     if n <= 0:
         return []
-    produced, consumed, drops, filtered, ring_len, ring_cap, cpu_ns = cols
+    (produced, consumed, drops, forgiven, filtered, ring_len, ring_cap,
+     cpu_ns) = cols
     out = []
     for i in range(int(n)):
         k = int(kinds[i])
@@ -300,6 +304,7 @@ def sources_stats(cap: int = 256) -> list[dict]:
             "produced": int(produced[i]),
             "consumed": int(consumed[i]),
             "drops": int(drops[i]),
+            "forgiven": int(forgiven[i]),
             "filtered": int(filtered[i]),
             "ring_len": int(ring_len[i]),
             "ring_cap": int(ring_cap[i]),
@@ -392,6 +397,7 @@ class NativeCapture:
         b.seq = self._seq
         self._seq += int(got)
         b.drops = int(self._lib.ig_source_drops(self._h))
+        b.forgiven = int(self._lib.ig_source_forgiven(self._h))
         # batch-grain watermarks: one clock read + one vectorized min —
         # the native ts column is CLOCK_REALTIME ns, comparable with
         # time.time() epoch seconds
@@ -480,6 +486,11 @@ class NativeCapture:
 
     def drops(self) -> int:
         return int(self._lib.ig_source_drops(self._h))
+
+    def forgiven(self) -> int:
+        """Events the synthetic producer owed by its schedule and skipped:
+        a catch-up past 10 ms of events in one wake (0 for other kinds)."""
+        return int(self._lib.ig_source_forgiven(self._h))
 
     def produced(self) -> int:
         return int(self._lib.ig_source_produced(self._h))

@@ -246,6 +246,134 @@ def test_the_numpy_source_delays_alike():
     assert delays_of(plain.cols["ts"]).max() == 0
 
 
+# -- the producer's schedule: floor(rate x elapsed) owed, at most 10 ms of
+# events a wake, the rest forgiven ------------------------------------------
+
+def drained(rate: float, seconds: float, ring_pow2: int = 20) -> dict:
+    """Run the producer for `seconds` behind a consumer that drains it, and
+    read its counters once it has stopped. `stalled_s`: the emission gaps
+    (each event's `ts` is stamped as it is emitted) in excess of 8 ms, summed
+    -- the time a busy host held the producer off past what one wake's cap
+    (10 ms), less a tick and a wake's own emission, catches up."""
+    src = NativeCapture(SRC_SYNTH_EXEC, seed=11, rate=rate, vocab=500,
+                        ring_pow2=ring_pow2, batch_size=65536)
+    stalled_ns, last_ts = 0, None
+
+    def pop() -> int:
+        nonlocal stalled_ns, last_ts
+        b = src.pop()
+        if b.count:
+            ts = b.cols["ts"][:b.count].astype(np.int64)
+            if last_ts is not None:
+                ts = np.concatenate([[last_ts], ts])
+            stalled_ns += int(np.maximum(np.diff(ts) - 8_000_000, 0).sum())
+            last_ts = int(ts[-1])
+        return b.count
+
+    before = time.monotonic()
+    src.start()
+    popped = 0
+    while time.monotonic() - before < seconds:
+        got = pop()
+        popped += got
+        if not got:
+            time.sleep(0.0005)
+    stopping = time.monotonic()
+    src.stop()
+    joined = time.monotonic()
+    while got := pop():
+        popped += got
+    out = {"popped": popped, "produced": src.produced(), "drops": src.drops(),
+           "forgiven": src.forgiven(), "stalled_s": stalled_ns / 1e9,
+           "started": before, "stopping": stopping, "joined": joined}
+    src.close()
+    return out
+
+
+@needs_native
+def test_a_producer_that_keeps_up_delivers_its_rate():
+    rate = 200_000
+    r = drained(rate, 1.0)
+    delivered = r["popped"] / (r["stopping"] - r["started"])
+    # a wake catches up 10 ms of events: only a host that held the thread
+    # off longer than that makes it forgive, and then no more than the
+    # stall owed (on a quiet host, nothing at all)
+    assert r["forgiven"] <= rate * r["stalled_s"]
+    if r["stalled_s"] == 0:
+        assert r["forgiven"] == 0
+    forgiven_per_s = r["forgiven"] / (r["stopping"] - r["started"])
+    assert 0.97 * rate <= delivered + forgiven_per_s <= 1.03 * rate
+    assert r["drops"] == 0 and r["popped"] == r["produced"]
+
+
+@needs_native
+def test_a_rate_out_of_reach_is_forgiven_and_the_ring_still_counts_drops():
+    rate, cap = 1e8, 1e8 / 100       # far past one producer thread
+    r = drained(rate, 1.0, ring_pow2=12)
+    emitted = r["produced"] + r["drops"]
+    settled = emitted + r["forgiven"]
+    # every push either landed in the ring (and was popped) or was a drop
+    assert r["popped"] == r["produced"]
+    assert r["drops"] > 0 and r["forgiven"] > 0
+    # never ahead of the schedule
+    assert settled <= rate * (r["joined"] - r["started"])
+    # and behind it by no more than one wake: a wake settles what is owed
+    # when it starts, then emits its cap flat out, over which the schedule
+    # runs on by rate x (cap / speed); the last wake before the stop began
+    # at most that long (plus its 1 ms tick) before it
+    speed = emitted / (r["joined"] - r["started"])
+    wake_s = cap / speed + 1e-3
+    assert settled >= rate * (r["stopping"] - r["started"] - 2 * wake_s)
+    # flat out: most of what was owed was forgiven
+    assert r["forgiven"] > emitted
+
+
+@needs_native
+def test_the_gadget_counts_what_the_producer_forgave():
+    import inspektor_gadget_tpu.all_gadgets  # noqa: F401
+    from inspektor_gadget_tpu import telemetry
+    from inspektor_gadget_tpu.gadgets import GadgetContext, get
+    from inspektor_gadget_tpu.runtime.local import LocalRuntime
+
+    key = 'ig_source_forgiven_events_total{gadget="trace/exec"}'
+
+    def total() -> float:
+        return telemetry.snapshot().get(key, 0.0)
+
+    def run(rate: str) -> float:
+        desc = get("trace", "exec")
+        params = desc.params().to_params()
+        for k, v in {"source": "synthetic", "rate": rate, "vocab": "500",
+                     "batch-size": "65536"}.items():
+            params.set(k, v)
+        before = total()
+        result = LocalRuntime().run_gadget(
+            GadgetContext(desc, gadget_params=params, timeout=0.5))
+        assert not result.errors()
+        return total() - before
+
+    # at 1e8 a wake owes more than its cap from the second wake on: one
+    # producer thread cannot emit 1e6 events a millisecond
+    assert run("1e8") > 0
+
+
+@needs_native
+def test_the_source_stats_carry_forgiven_beside_drops():
+    from inspektor_gadget_tpu.sources import sources_stats
+
+    src = NativeCapture(SRC_SYNTH_EXEC, seed=3, rate=1e8, vocab=50,
+                        ring_pow2=10)
+    src.start()
+    try:
+        time.sleep(0.3)
+        src.stop()
+        s = [x for x in sources_stats() if x["id"] == src._h][0]
+        assert s["forgiven"] == src.forgiven() > 0
+        assert s["drops"] == src.drops() > 0
+    finally:
+        src.close()
+
+
 def test_batch_mask_and_comm():
     from inspektor_gadget_tpu.sources import EventBatch
 
